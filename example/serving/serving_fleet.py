@@ -24,9 +24,7 @@ Run::
     python example/serving/serving_fleet.py --smoke    # CI: 2 replicas
 """
 import argparse
-import os
 import signal
-import tempfile
 import threading
 import time
 
@@ -50,7 +48,6 @@ def main():
     phase_s = args.seconds or (1.5 if args.smoke else 6.0)
     in_units = 16
 
-    cache_dir = os.path.join(tempfile.gettempdir(), "mxtpu-fleet-demo")
     spec = {"models": [{"name": "dense",
                         "builder": "mxnet_tpu.serving.replica:demo_dense",
                         "kwargs": {"units": 4, "in_units": in_units,
@@ -60,7 +57,6 @@ def main():
 
     fleet = serving.ServingFleet(
         spec, replicas=replicas,
-        env={"MXNET_COMPILE_CACHE_DIR": cache_dir},
         router_kwargs={"probe_ms": 100},
         supervisor_kwargs={"restart_backoff_ms": 100})
     t0 = time.perf_counter()

@@ -5,14 +5,18 @@ pieces — dependency engine (reference src/engine/threaded_engine.cc),
 pooled storage (src/storage/pooled_storage_manager.h), recordio
 (dmlc-core recordio + python/mxnet/recordio.py), threaded prefetch
 (src/io/iter_prefetcher.h) — behind a plain C ABI.  This module loads the
-shared object (building it on first use when a toolchain is present) and
+shared object (building it from ``src/`` on first use — ``mxnet_tpu/lib``
+is not tracked by git, so every fresh checkout builds its own) and
 exposes typed wrappers.  Every consumer has a pure-Python fallback so the
 framework still works without a C++ toolchain; `lib() is None` is the
-feature probe (surfaced via mx.runtime.Features 'NATIVE_RUNTIME').
+feature probe (surfaced via mx.runtime.Features 'NATIVE_RUNTIME'), and
+`build_error` then holds what `make` said.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -20,6 +24,8 @@ import threading
 _LIB = None
 _TRIED = False
 _LOCK = threading.Lock()
+#: stderr of a failed `make -C src` (None: no build ran, or it succeeded)
+build_error = None
 
 # MXNET_TPU_CORE_SO points the loader at an alternate build (TSAN/ASAN);
 # when set, the override is authoritative: no rebuild-on-stale either
@@ -145,30 +151,62 @@ def native_imdecode(payload, resize_short=0):
     return arr.reshape(h.value, w.value, c.value)
 
 
-def _try_build():
-    if not os.path.isdir(_SRC_DIR):
-        return False
-    try:
-        subprocess.run(["make", "-C", _SRC_DIR], check=True,
-                       stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
-                       timeout=120)
-        return os.path.exists(_LIB_PATH)
-    except Exception:
-        return False
+def _src_digest():
+    """sha256 over the sources the .so is built from.  Staleness compares
+    this with the digest recorded at build time: file mtimes say nothing
+    in a fresh copy of the tree."""
+    h = hashlib.sha256()
+    mx_dir = os.path.join(_SRC_DIR, "mxtpu")
+    names = [os.path.join(_SRC_DIR, "Makefile")] + sorted(
+        os.path.join(mx_dir, n) for n in os.listdir(mx_dir)
+        if n.endswith((".cc", ".h")))
+    for path in names:
+        h.update(os.path.basename(path).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
 
 
 def _stale():
-    """True when any C++ source is newer than the built .so."""
+    """True when the built .so does not come from the current sources."""
+    if not os.path.isdir(os.path.join(_SRC_DIR, "mxtpu")):
+        return False          # no sources shipped: use what is there
     if not os.path.exists(_LIB_PATH):
         return True
-    so_mtime = os.path.getmtime(_LIB_PATH)
-    mx_dir = os.path.join(_SRC_DIR, "mxtpu")
-    if not os.path.isdir(mx_dir):
-        return False
-    for name in os.listdir(mx_dir):
-        if name.endswith((".cc", ".h")):
-            if os.path.getmtime(os.path.join(mx_dir, name)) > so_mtime:
-                return True
+    try:
+        with open(_LIB_PATH + ".src") as f:
+            return f.read().strip() != _src_digest()
+    except OSError:
+        return True
+
+
+def _try_build():
+    """`make -B -C src` under a file lock (replicas and test children of
+    a fresh checkout all get here at once); on failure keep stderr in
+    `build_error`, log it and leave the pure-Python fallbacks in charge."""
+    global build_error
+    import fcntl
+    os.makedirs(os.path.dirname(_LIB_PATH), exist_ok=True)
+    with open(_LIB_PATH + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if not _stale():
+            return True       # another process built it while we waited
+        try:
+            proc = subprocess.run(["make", "-B", "-C", _SRC_DIR],
+                                  stdout=subprocess.DEVNULL,
+                                  stderr=subprocess.PIPE, text=True,
+                                  timeout=300)
+            err = proc.stderr if proc.returncode else None
+        except (OSError, subprocess.TimeoutExpired) as e:
+            err = "%s: %s" % (type(e).__name__, e)
+        if err is None and os.path.exists(_LIB_PATH):
+            with open(_LIB_PATH + ".src", "w") as f:
+                f.write(_src_digest())
+            return True
+    build_error = err or "make succeeded but wrote no %s" % _LIB_PATH
+    logging.getLogger(__name__).warning(
+        "native runtime build failed; using the pure-Python paths:\n%s",
+        build_error[-2000:])
     return False
 
 
@@ -183,13 +221,10 @@ def lib():
         _TRIED = True
         if os.environ.get("MXNET_TPU_DISABLE_NATIVE", "") == "1":
             return None
-        if _LIB_OVERRIDE is None and _stale():
-            _try_build()  # never rebuild over an explicit override
+        if _LIB_OVERRIDE is None and _stale() and not _try_build():
+            return None       # never load a .so of other sources
         if os.path.exists(_LIB_PATH):
-            try:
-                _LIB = _declare(ctypes.CDLL(_LIB_PATH))
-            except Exception:
-                _LIB = None
+            _LIB = _declare(ctypes.CDLL(_LIB_PATH))
         return _LIB
 
 

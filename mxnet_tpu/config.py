@@ -176,9 +176,10 @@ register("MXNET_SHARDED_FLASH", str, "", "honored",
          "= always the single-device dispatch",
          "ops.attention._active_sharding")
 register("MXNET_SPLASH_ATTENTION", str, "", "honored",
-         "''/'1' = causal sharded attention may use the TPU splash "
-         "kernel (probe-and-latch, compiled Pallas lane only); '0'/'off' "
-         "= always this repo's flash kernel", "ops.attention._splash_ok")
+         "''/'1' = causal sharded attention uses the TPU splash "
+         "kernel on the compiled Pallas lane where sequence and "
+         "head_dim are multiples of 128; '0'/'off' = always this "
+         "repo's flash kernel", "ops.attention._splash_ok")
 register("MXNET_KV_TIMEOUT", float, 300.0, "honored",
          "dist kvstore socket timeout in seconds (send/recv/connect on a "
          "server shard stream); also the reconnect deadline after a "
@@ -306,12 +307,6 @@ register("MXNET_SLO_TENANT_WEIGHTS", str, "", "honored",
          "SLO admission: weighted-fair-queueing tenant weights as "
          "'tenant=weight,...' (e.g. 'free=1,pro=4'); unlisted tenants "
          "weigh 1", "serving.autoscale.SLOPolicy")
-register("MXNET_COMPILE_CACHE_DIR", str, "", "honored",
-         "persistent XLA compile cache directory (jax compilation "
-         "cache): registry per-bucket precompile writes it, so a "
-         "restarted/rolled-out replica re-serves in seconds instead of "
-         "paying cold compiles; shared across replicas on one host",
-         "serving.registry.maybe_enable_compile_cache")
 register("MXNET_SERVING_REPLICA_ID", str, "", "honored",
          "replica label stamped on ServingMetrics snapshots and the "
          "Prometheus export (the fleet supervisor sets it per replica "
@@ -439,9 +434,10 @@ register("MXNET_GEN_DISAGG_MIN_PROMPT", int, 32, "honored",
          "unless the fleet has both a prefill and a decode pool)",
          "serving.Router")
 register("MXNET_PAGED_ATTENTION", str, "", "honored",
-         "paged-attention dispatch: '' auto (Pallas kernel on TPU, XLA "
-         "gather reference on CPU), '0' forces the reference, "
-         "'interpret' forces the Pallas kernel in interpreter mode",
+         "paged-attention dispatch: '' auto (Pallas kernel on TPU for "
+         "head_dim a multiple of 128, XLA gather reference elsewhere), "
+         "'0' forces the reference, 'interpret' runs the Pallas kernel "
+         "in the TPU interpreter",
          "ops.pallas.paged_attention")
 register("MXNET_RNN_SCAN_UNROLL", int, 5, "honored",
          "RNN time-scan unroll factor (read per call; any seq_len "
@@ -452,16 +448,17 @@ register("MXNET_RNN_WAVEFRONT", bool, True, "honored",
 register("MXNET_RNN_FUSED_CELL", str, "", "honored",
          "persistent fused-cell LSTM kernel: one Pallas launch owns the "
          "whole time loop (recurrent weights latched in VMEM, gates + "
-         "state update fused, custom VJP).  '' auto (probe on "
-         "accelerator backends, scan on CPU), '0' forces the scan/"
+         "state update fused, custom VJP).  '' auto (the kernel on a "
+         "TPU backend, scan elsewhere), '0' forces the scan/"
          "wavefront paths, 'interpret' forces the kernel in interpreter "
          "mode (CPU test lane)", "ops.pallas.fused_cell.rnn_mode")
 register("MXNET_DECODE_FUSED", str, "", "honored",
          "persistent fused decode-step kernel for the LLM engine: one "
          "Pallas launch per layer group (qkv + KV append + paged "
          "attention + FFN epilogue chain) instead of the per-op XLA "
-         "tower.  '' auto (accelerator backends), '0' off, 'interpret' "
-         "CPU test lane", "ops.pallas.fused_cell.decode_mode")
+         "tower.  'interpret' = the CPU test lane; anything else = "
+         "the tower (the v5e's compiler refuses the cell)",
+         "ops.pallas.fused_cell.decode_mode")
 register("MXNET_DECODE_LAYER_GROUP", int, 0, "honored",
          "decoder layers per fused decode-step kernel launch (0 = all "
          "layers in ONE group — one launch per token per engine step)",

@@ -8,6 +8,7 @@ for ``mx.tpu(i)`` so reference scripts run unmodified.
 """
 from __future__ import annotations
 
+import glob
 import threading
 
 import jax
@@ -36,15 +37,23 @@ class Context:
     # -- resolution to a PJRT device -------------------------------------
     @property
     def jax_device(self):
-        """Resolve to a jax.Device. ``tpu`` falls back to the default JAX
-        backend when no TPU platform is present (e.g. CPU test meshes)."""
+        """Resolve to a jax.Device.
+
+        ``tpu(i)`` is device ``i`` of JAX's default backend, and an ``i``
+        the backend does not have is an error.  On a host with no
+        accelerator the default backend is the CPU, so ``tpu(i)`` names
+        CPU device ``i`` there: that much CPU resolution stays because
+        tier-1 runs the whole suite, ``mx.tpu()``/``mx.gpu()`` contexts
+        included, on the forced-CPU mesh of ``tests/conftest.py``.  A
+        backend that fails to initialise raises; nothing falls to CPU."""
         if self.device_type == "tpu":
-            try:
-                devs = jax.devices()  # default backend (tpu when present)
-            except RuntimeError:
-                devs = jax.devices("cpu")
-        else:
-            devs = jax.devices("cpu")
+            devs = jax.devices()
+            if not 0 <= self.device_id < len(devs):
+                raise ValueError(
+                    "%r: the %s backend has %d device(s)"
+                    % (self, devs[0].platform, len(devs)))
+            return devs[self.device_id]
+        devs = jax.devices("cpu")
         return devs[self.device_id % len(devs)]
 
     # -- comparison / hashing --------------------------------------------
@@ -114,11 +123,7 @@ current_device = current_context
 
 def num_tpus():
     """Number of accelerator devices visible (reference: mx.context.num_gpus)."""
-    try:
-        devs = jax.devices()
-    except RuntimeError:
-        return 0
-    return sum(1 for d in devs if d.platform != "cpu")
+    return sum(1 for d in jax.devices() if d.platform != "cpu")
 
 
 num_gpus = num_tpus
@@ -126,6 +131,53 @@ num_gpus = num_tpus
 
 def device_count():
     return len(jax.devices())
+
+
+def host_chip_count():
+    """TPU chips this host hands to a process, counted without
+    initialising a JAX backend so a launcher that must stay off the
+    device can call it: the device nodes libtpu opens, ``/dev/accel*``
+    or one ``/dev/vfio/<group>`` per chip.  0 on a host with no TPU.
+    (The PCI bus is not the count: the one-chip v5e machine is a slice
+    of a four-chip host, shows four Google devices there and one
+    ``/dev/vfio/3``; PR 21.)"""
+    return len(glob.glob("/dev/accel*")
+               + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def must_place_children(env):
+    """Whether a launcher whose children inherit ``env`` has to show
+    each of them its own chip: not where they are held to the CPU
+    (``JAX_PLATFORMS=cpu``, the tier-1 lane), not where ``env`` already
+    says ``TPU_VISIBLE_CHIPS`` (the operator placed them: theirs wins),
+    and not on a host with no TPU."""
+    return not (env.get("JAX_PLATFORMS", "").startswith("cpu")
+                or "TPU_VISIBLE_CHIPS" in env or not host_chip_count())
+
+
+def chip_visibility_env(chip, port):
+    """Environment that shows a child process ONE chip of this host.
+
+    A TPU belongs to one process at a time, and a process takes every
+    chip it can see: the first child of a multi-process launch (fleet
+    replicas, kvstore workers) would hold them all.  These are libtpu's
+    own variables (the set jax's multi-process tests stamp); the parent
+    that builds them must itself stay off JAX.  ``port`` is a free port
+    the caller reserved for the child's TPU runtime, so two launchers on
+    one host do not meet.  A ``chip`` the host does not have is an
+    error here, in the launcher, not a crash loop in the child."""
+    chip, n = int(chip), host_chip_count()
+    if not 0 <= chip < n:
+        raise RuntimeError(
+            "no chip %d: this host has %d TPU chip(s), and a chip "
+            "belongs to one process at a time" % (chip, n))
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_ADDRESSES": "localhost:%d" % port,
+            "TPU_PROCESS_PORT": str(port),
+            "CLOUD_TPU_TASK_ID": "0",
+            "ALLOW_MULTIPLE_LIBTPU_LOAD": "1"}
 
 
 def tpu_memory_info(device_id=0):

@@ -58,7 +58,8 @@ __all__ = ["DecoderConfig", "CausalLM", "full_forward", "make_decode_step",
            "make_verify_step", "make_token_combine",
            "fn_cache_stats", "decode_launch_stats",
            "verify_launch_stats", "decode_collective_stats", "tp_plan",
-           "TPPlan", "decoder_tiny", "decoder_tiny_lm", "decoder_draft"]
+           "TPPlan", "causal_lm", "decoder_tiny", "decoder_tiny_lm",
+           "decoder_draft"]
 
 
 # ---------------------------------------------------------------------------
@@ -408,34 +409,27 @@ class TPPlan:
         sharded, every other operand/result replicated; pages donated so
         the cache stays in place across steps."""
         from jax.sharding import PartitionSpec as P
-        from ..parallel.pipeline import (shard_map,
-                                         _shard_map_compat_kwargs)
         rep = P()
         in_specs = ((self.param_specs(), self.kv_in_spec, self.kv_in_spec)
                     + (rep,) * n_rest)
         out_specs = (self.kv_in_spec, self.kv_in_spec) + (rep,) * n_out_rest
-        smapped = shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                            out_specs=out_specs,
-                            **_shard_map_compat_kwargs())
+        smapped = jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                                out_specs=out_specs, check_vma=False)
         return jax.jit(smapped, donate_argnums=(1, 2))
 
 
 def tp_plan(cfg, sharding, quant=None, kv_int8=False):
     """Resolve (cfg, ShardingConfig) to a :class:`TPPlan`, or None when
-    the engine should serve replicated: no config, tp absent/1, a mesh
-    that does not fit this host, geometry tp does not divide (the GQA
+    no tensor parallelism was asked for (no config, tp absent or 1).
+
+    A config that asks for tp > 1 and cannot have it is an error, never
+    a single-chip engine under a TP label: a mesh that does not fit this
+    host (``axis_size`` raises), geometry tp does not divide (the GQA
     ``kv_heads % tp`` constraint and friends), or rules that do not
-    resolve to the Megatron column/row layout.  Every fallback except
-    "no tp requested" warns loudly — silently serving replicated when
-    the operator asked for TP would look like a perf bug."""
+    resolve to the Megatron column/row layout all raise ValueError."""
     if sharding is None:
         return None
-    try:
-        tp = int(sharding.axis_size("tp"))
-    except ValueError as e:  # mesh does not fit this host
-        warnings.warn("decoder: sharding mesh unavailable (%s); serving "
-                      "REPLICATED" % e, stacklevel=2)
-        return None
+    tp = int(sharding.axis_size("tp"))
     if tp <= 1:
         return None
     bad = [s for s, n in (("num_heads=%d" % cfg.num_heads, cfg.num_heads),
@@ -444,11 +438,9 @@ def tp_plan(cfg, sharding, quant=None, kv_int8=False):
                           ("hidden_size=%d" % cfg.hidden_size,
                            cfg.hidden_size)) if n % tp != 0]
     if bad:
-        warnings.warn(
-            "decoder: tp=%d does not divide %s; serving REPLICATED "
-            "(pick tp dividing the head/FFN geometry)" % (tp, ", ".join(bad)),
-            stacklevel=2)
-        return None
+        raise ValueError(
+            "decoder: tp=%d does not divide %s (pick tp dividing the "
+            "head/FFN geometry)" % (tp, ", ".join(bad)))
     plan = TPPlan(sharding, cfg, quant=quant, kv_int8=kv_int8)
     shapes = plan._layer_shapes()
     want = {"wq": ("tp",), "wk": ("tp",), "wv": ("tp",), "bq": ("tp",),
@@ -457,12 +449,10 @@ def tp_plan(cfg, sharding, quant=None, kv_int8=False):
     off = [k for k, w in want.items()
            if tuple(plan.leaf_spec(k, shapes[k])) != w]
     if off:
-        warnings.warn(
+        raise ValueError(
             "decoder: sharding rules do not resolve the Megatron "
             "column/row layout for %s (use ShardingConfig."
-            "for_transformer); serving REPLICATED" % ", ".join(sorted(off)),
-            stacklevel=2)
-        return None
+            "for_transformer)" % ", ".join(sorted(off)))
     return plan
 
 
@@ -1098,6 +1088,18 @@ def decoder_tiny(vocab_size=128, **kw):
     kw.setdefault("num_kv_heads", 2)
     kw.setdefault("max_length", 128)
     return CausalLM(vocab_size, **kw)
+
+
+def causal_lm(seed=0, **kw):
+    """Initialized, deterministic CausalLM of any size (``kw`` are
+    :class:`CausalLM`'s) — the importable builder a replica spec names
+    to serve a full-width model with random weights
+    (``mxnet_tpu.models.decoder:causal_lm``; ``chip_smoke.py``)."""
+    import mxnet_tpu as mx
+    mx.random.seed(int(seed))
+    net = CausalLM(**kw)
+    net.initialize(mx.init.Xavier())
+    return net
 
 
 def decoder_tiny_lm(seed=0, vocab_size=128, **kw):

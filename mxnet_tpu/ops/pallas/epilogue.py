@@ -22,12 +22,11 @@ stores a full-size mask for backward.  ``bias_gelu`` saves only (x, b)
 and recomputes u = x + b in backward (one add versus an activation-sized
 residual).
 
-Dispatch mirrors ops/attention.flash_attention: a Pallas kernel on any
-accelerator backend that passes a one-time probe, the identical jnp
-composition (which XLA provably fuses into one loop — it is a pure
-elementwise chain) on CPU or when ``MXNET_EPILOGUE_KERNEL=0``;
-``MXNET_EPILOGUE_KERNEL=interpret`` forces Pallas interpret mode (CPU
-test lane).  Both paths share the hash mask, so they are
+Dispatch mirrors ops/attention.flash_attention: a Pallas kernel on a TPU
+backend, the identical jnp composition (which XLA provably fuses into one
+loop — it is a pure elementwise chain) elsewhere, under GSPMD, or when
+``MXNET_EPILOGUE_KERNEL=0``; ``MXNET_EPILOGUE_KERNEL=interpret`` forces
+Pallas interpret mode (CPU test lane).  Both paths share the hash mask, so they are
 gradient-consistent and testable against each other.
 """
 from __future__ import annotations
@@ -41,7 +40,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import hash_keep_bits, _CompilerParams
+from . import gspmd_config, kernel_mode
+from .flash_attention import hash_keep_bits
 
 _SQRT_HALF = math.sqrt(0.5)
 _INV_SQRT_2PI = 1.0 / math.sqrt(2.0 * math.pi)
@@ -63,38 +63,17 @@ def fuse_epilogue_enabled():
         "0", "false", "False", "off")
 
 
-# ---------------------------------------------------------------------------
-# kernel dispatch (same probe-and-latch shape as ops.attention)
-# ---------------------------------------------------------------------------
-_probe_result = None
-
-
-def _probe_pallas():
-    global _probe_result
-    if _probe_result is None:
-        try:
-            x = jnp.zeros((8, 128), jnp.float32)
-            b = jnp.zeros((128,), jnp.float32)
-            jax.block_until_ready(_bias_gelu_fwd_pallas(x, b, False))
-            _probe_result = True
-        except Exception:  # pragma: no cover - depends on platform
-            _probe_result = False
-    return _probe_result
-
-
 def _mode():
-    """'compiled' | 'interpret' | None (jnp path)."""
-    flag = os.environ.get("MXNET_EPILOGUE_KERNEL", "").lower()
-    if flag in ("0", "off", "false"):
+    """'compiled' | 'interpret' | None (jnp path).
+
+    Under GSPMD the compiled lane takes the jnp chain, which XLA
+    partitions and fuses: "Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map" (BERT-base at dp=4
+    on the four-chip v5e host, PR 21)."""
+    mode = kernel_mode("MXNET_EPILOGUE_KERNEL")
+    if mode == "compiled" and gspmd_config() is not None:
         return None
-    if flag == "interpret":
-        return "interpret"
-    try:
-        if jax.default_backend() != "cpu" and _probe_pallas():
-            return "compiled"
-    except Exception:  # pragma: no cover
-        pass
-    return None
+    return mode
 
 
 def _pick_rows(R, C, dtype):
@@ -107,14 +86,41 @@ def _pick_rows(R, C, dtype):
     return br
 
 
-def _gelu_f32(u):
-    return 0.5 * u * (1.0 + jax.lax.erf(u * _SQRT_HALF))
+_ERF_ALPHA = (-2.72614225801306e-10, 2.77068142495902e-08,
+              -2.10102402082508e-06, -5.69250639462346e-05,
+              -7.34990630326855e-04, -2.95459980854025e-03,
+              -1.60960333262415e-02)
+_ERF_BETA = (-1.45660718464996e-05, -2.13374055278905e-04,
+             -1.68282697438203e-03, -7.37332916720468e-03,
+             -1.42647390514189e-02)
 
 
-def _dgelu_f32(u):
+def _erf_kernel(x):
+    """float32 erf from multiplies, adds and one divide, for use INSIDE
+    the Pallas kernels: jax 0.9.0's Mosaic lowering has no rule for
+    ``lax.erf`` ("Unimplemented primitive in Pallas TPU lowering for
+    KernelType.TC: erf", v5e, PR 21).  The clamped rational approximation
+    x*P(x^2)/Q(x^2) that Eigen and XLA use for float32; within 5e-7 of
+    ``lax.erf`` everywhere (3.2e-7 of the true value)."""
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    p = jnp.float32(_ERF_ALPHA[0])
+    for c in _ERF_ALPHA[1:]:
+        p = p * x2 + jnp.float32(c)
+    q = jnp.float32(_ERF_BETA[0])
+    for c in _ERF_BETA[1:]:
+        q = q * x2 + jnp.float32(c)
+    return x * p / q
+
+
+def _gelu_f32(u, erf=jax.lax.erf):
+    return 0.5 * u * (1.0 + erf(u * _SQRT_HALF))
+
+
+def _dgelu_f32(u, erf=jax.lax.erf):
     # d/du [u * Phi(u)] = Phi(u) + u * phi(u)
     phi = jnp.exp(-0.5 * u * u) * _INV_SQRT_2PI
-    return 0.5 * (1.0 + jax.lax.erf(u * _SQRT_HALF)) + u * phi
+    return 0.5 * (1.0 + erf(u * _SQRT_HALF)) + u * phi
 
 
 def _keep_scale_rows(seed, i0, shape, rate):
@@ -134,13 +140,13 @@ def _keep_scale_rows(seed, i0, shape, rate):
 # ---------------------------------------------------------------------------
 def _bg_fwd_kernel(x_ref, b_ref, o_ref):
     u = x_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
-    o_ref[...] = _gelu_f32(u).astype(o_ref.dtype)
+    o_ref[...] = _gelu_f32(u, _erf_kernel).astype(o_ref.dtype)
 
 
 def _bg_bwd_kernel(x_ref, g_ref, b_ref, dx_ref):
     u = x_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
     dx_ref[...] = (g_ref[...].astype(jnp.float32)
-                   * _dgelu_f32(u)).astype(dx_ref.dtype)
+                   * _dgelu_f32(u, _erf_kernel)).astype(dx_ref.dtype)
 
 
 def _rowblock_call(kernel, arrays, bias, out_dtype, interpret):
@@ -156,7 +162,8 @@ def _rowblock_call(kernel, arrays, bias, out_dtype, interpret):
                                                             lambda i: (0,))],
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((R, C), out_dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*arrays, bias)
 
@@ -241,7 +248,8 @@ def _bdr_call(kernel, arrays, bias_like, seed, out_dtype, rate, interpret):
         in_specs=in_specs,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((R, C), out_dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*ops, seed)
 

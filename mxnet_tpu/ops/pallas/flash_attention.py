@@ -32,11 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed across jax versions (TPUCompilerParams in 0.4/0.5, CompilerParams
-# from 0.6); resolve once so every pallas_call below works on either
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 # Large-negative sentinel instead of -inf: masked scores underflow to exactly
 # 0 after the softmax shift (every row of a causal / banded self-attention has
 # at least one unmasked key, so running (max, sum) state self-corrects), which
@@ -249,7 +244,7 @@ def _fwd_call(q, k, v, seed, kvlen, causal, window, scale, dropout,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, seed, kvlen)
@@ -397,7 +392,7 @@ def _bwd_call(q, k, v, do, lse, delta, seed, kvlen, causal, window, scale,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, L, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta, seed, kvlen)
@@ -430,7 +425,7 @@ def _bwd_call(q, k, v, do, lse, delta, seed, kvlen, causal, window, scale,
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta, seed, kvlen)

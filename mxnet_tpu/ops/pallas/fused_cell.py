@@ -1,6 +1,6 @@
 """Persistent fused-cell Pallas kernels for latency-bound serial loops.
 
-PHASES.json adjudication (ROUND5_NOTES §2): the LSTM word-LM step is
+benchmark/PHASES.json adjudication: the LSTM word-LM step is
 LATENCY-bound at 4% of the compute roofline — ~70 serial small-cell
 iterations whose per-iteration dispatch/launch overhead, not flops or
 bytes, sets the throughput band.  The scan/wavefront paths in
@@ -40,12 +40,13 @@ Two persistent kernels, one pattern:
   stream through blocked specs.  One decode step becomes one launch per
   layer group instead of a tower of per-op XLA dispatches.
 
-Dispatch is the repo's probe-and-latch shape (flash/epilogue/paged):
-``MXNET_RNN_FUSED_CELL`` / ``MXNET_DECODE_FUSED`` — ``''`` auto-probes
-(Pallas on non-CPU backends), ``0``/``off`` forces the scan / per-op XLA
-paths, ``interpret`` forces the Pallas kernel in interpreter mode (the
-CPU test lane).  LSTM is covered first; GRU/vanilla RNN and the reverse
-direction of bidirectional stacks fall back to the scan path.
+Dispatch is the repo's gate grammar (flash/epilogue/paged):
+``MXNET_RNN_FUSED_CELL`` / ``MXNET_DECODE_FUSED`` — ``''`` auto (the
+LSTM cell on a TPU backend; the decode cell nowhere yet, see
+:func:`decode_mode`), ``0``/``off`` forces the scan / per-op XLA paths,
+``interpret`` forces the Pallas kernel in interpreter mode (the CPU test
+lane).  LSTM is covered first; GRU/vanilla RNN and the reverse
+direction of bidirectional stacks take the scan path.
 
 :func:`count_launches` is the audit tool for the dispatch-count claims:
 a deterministic, load-independent jaxpr walk counting the primitives
@@ -57,21 +58,18 @@ not timings, so no opperf-style flake risk.
 from __future__ import annotations
 
 import functools
-import math
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _CompilerParams
+from . import kernel_mode
+from .epilogue import _erf_kernel, _gelu_f32
 
 __all__ = ["lstm_sequence", "decode_layer_group", "decode_attn_phase",
            "decode_ffn_phase", "rnn_mode", "decode_mode",
            "count_launches", "trace_counts", "last_path"]
-
-_SQRT_HALF = math.sqrt(0.5)
 
 # per-op trace counters (bench/tests assert the fused path is actually in
 # the compiled program, the PR-2 epilogue convention)
@@ -82,58 +80,37 @@ last_path = None
 
 
 # ---------------------------------------------------------------------------
-# dispatch gates (probe-and-latch, one per consumer)
+# dispatch gates (one per consumer)
 # ---------------------------------------------------------------------------
-_rnn_probe = None
-_decode_probe = None
-
-
-def _probe_rnn():
-    global _rnn_probe
-    if _rnn_probe is None:
-        try:
-            gx = jnp.zeros((4, 8, 512), jnp.float32)
-            h0 = jnp.zeros((8, 128), jnp.float32)
-            w = jnp.zeros((128, 512), jnp.float32)
-            b = jnp.zeros((512,), jnp.float32)
-            out, _, _ = _lstm_seq_fwd_pallas(gx, h0, h0, w, b, False)
-            jax.block_until_ready(out)
-            _rnn_probe = True
-        except Exception:  # pragma: no cover - depends on platform
-            _rnn_probe = False
-    return _rnn_probe
-
-
-def _env_mode(var, probe):
-    """Shared gate grammar: '' auto, '0'/'off' disabled, 'interpret'."""
-    flag = os.environ.get(var, "").lower()
-    if flag in ("0", "off", "false"):
-        return None
-    if flag == "interpret":
-        return "interpret"
-    try:
-        if jax.default_backend() != "cpu" and probe():
-            return "compiled"
-    except Exception:  # pragma: no cover
-        pass
-    return None
-
-
 def rnn_mode():
     """'compiled' | 'interpret' | None — the fused LSTM cell gate
     (``MXNET_RNN_FUSED_CELL``)."""
-    return _env_mode("MXNET_RNN_FUSED_CELL", _probe_rnn)
+    return kernel_mode("MXNET_RNN_FUSED_CELL")
 
 
 def decode_mode():
-    """'compiled' | 'interpret' | None — the fused decode-step gate
-    (``MXNET_DECODE_FUSED``).  The probe is deferred to the first real
-    build (the kernel is shape-specialized per model); on non-CPU
-    backends the engine falls back to the per-op path if the first
-    compile fails."""
-    def _probe():
-        return True
-    return _env_mode("MXNET_DECODE_FUSED", _probe)
+    """'interpret' | None — the fused decode-step gate
+    (``MXNET_DECODE_FUSED``).
+
+    The cell is not selected on a TPU backend: the v5e's compiler
+    refuses it at every geometry tried (128 units with head_dim 32, and
+    GPT-2-small's 768 units with head_dim 64; PR 21).  Three refusals
+    were met in turn.  Two were repaired: jax 0.9.0's Mosaic lowering
+    has no rule for ``lax.erf`` (the compiled cell now uses
+    ``epilogue._erf_kernel``), and it rejects a ``(1, C)`` block of a
+    stacked ``(Lg, C)`` vector (they ride as ``(Lg, 1, C)``).  The third
+    is in the kernel's body and is what stands: "Mosaic failed to
+    compile TPU kernel: infer-vector-layout: unsupported shape cast" on
+    the head split, ``tpu.reshape (vector<8x768xf32>) ->
+    vector<8x12x1x64xf32>``.  What lies behind it was never reached; by
+    arithmetic alone (not the compiler's word) the kernel's VMEM plan at
+    GPT-2-small width with 8 slots x 1024 context, one layer's whole K
+    and V pool in and out beside that layer's fp32 weights,
+    double-buffered, is about 246 MiB against a 16 MiB scoped limit.
+    The engine runs the per-op step there; ``interpret`` keeps the cell
+    as the CPU oracle until ROADMAP S1/D4 decide its future."""
+    mode = kernel_mode("MXNET_DECODE_FUSED")
+    return mode if mode == "interpret" else None
 
 
 # ---------------------------------------------------------------------------
@@ -185,7 +162,7 @@ def _lstm_seq_fwd_pallas(gates_x, h0, c0, w_h2h_t, b_h2h, interpret):
                    jax.ShapeDtypeStruct((T, B, H), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((B, H), jnp.float32),
                         pltpu.VMEM((B, H), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(gates_x, h0, c0, w_h2h_t, b_h2h)
@@ -260,7 +237,7 @@ def _lstm_seq_bwd_pallas(gates_x, h_prev, c_prev, cseq, dout, dcseq,
                    jax.ShapeDtypeStruct((B, H), gates_x.dtype)],
         scratch_shapes=[pltpu.VMEM((B, H), jnp.float32),
                         pltpu.VMEM((B, H), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(gates_x, h_prev, c_prev, cseq, dout, dcseq, w_h2h_t, b_h2h)
@@ -328,8 +305,12 @@ def lstm_sequence(gates_x, h0, c0, w_h2h_t, b_h2h, mode=None):
 # ---------------------------------------------------------------------------
 # persistent decode-step kernel (one launch per layer group)
 # ---------------------------------------------------------------------------
-def _gelu_erf(u):
-    return 0.5 * u * (1.0 + jax.lax.erf(u * _SQRT_HALF))
+def _erf_for(mode):
+    """The erf the per-op step's bias_gelu uses on the same lane, so the
+    cell stays bit-comparable with it: ``lax.erf`` under the interpreter
+    (the CPU oracle), the in-kernel rational where Mosaic compiles the
+    cell, which has no rule for ``lax.erf`` (``epilogue._erf_kernel``)."""
+    return _erf_kernel if mode == "compiled" else jax.lax.erf
 
 
 def _ln_f32(x, gamma, beta, eps=1e-5):
@@ -344,7 +325,7 @@ def _decode_group_kernel(x_ref, kp_ref, vp_ref,
                          ln1g_ref, ln1b_ref, ln2g_ref, ln2b_ref,
                          meta_ref, pt_ref, len_ref,
                          kp_out, vp_out, x_out,
-                         x_scr, *, cfg_tuple):
+                         x_scr, *, cfg_tuple, erf):
     """One grid step = one decoder layer.  The activation carries in
     VMEM scratch; this layer's weights and page slab stream in via
     blocked specs; meta (wp/ws rows) sits in SMEM for the scalar page
@@ -420,9 +401,9 @@ def _decode_group_kernel(x_ref, kp_ref, vp_ref,
          + bo_ref[0].astype(jnp.float32))
     x = _ln_f32(x + o, ln1g_ref[0].astype(jnp.float32),
                 ln1b_ref[0].astype(jnp.float32))
-    h1 = _gelu_erf(jnp.dot(x, w1_ref[0].astype(jnp.float32).T,
+    h1 = _gelu_f32(jnp.dot(x, w1_ref[0].astype(jnp.float32).T,
                            preferred_element_type=jnp.float32)
-                   + b1_ref[0].astype(jnp.float32))
+                   + b1_ref[0].astype(jnp.float32), erf)
     f = (jnp.dot(h1, w2_ref[0].astype(jnp.float32).T,
                  preferred_element_type=jnp.float32)
          + b2_ref[0].astype(jnp.float32))
@@ -467,7 +448,11 @@ def decode_layer_group(x, kp, vp, stacked, meta, page_tables, lengths,
 
     worder = ("wq", "bq", "wk", "bk", "wv", "bv", "wo", "bo",
               "w1", "b1", "w2", "b2", "ln1g", "ln1b", "ln2g", "ln2b")
-    w_arrays = [stacked[k] for k in worder]
+    # a stacked per-layer vector rides as (Lg, 1, C): Mosaic refuses a
+    # (1, C) block of an (Lg, C) array (second-to-last block dim neither
+    # a multiple of 8 nor the array's; v5e, PR 21)
+    w_arrays = [stacked[k][:, None] if stacked[k].ndim == 2 else stacked[k]
+                for k in worder]
     page_spec = pl.BlockSpec((1, KVH, P, S, D),
                              lambda l: (l, 0, 0, 0, 0))
     in_specs = ([pl.BlockSpec((B, C), lambda l: (0, 0)),
@@ -476,7 +461,8 @@ def decode_layer_group(x, kp, vp, stacked, meta, page_tables, lengths,
                 + [pl.BlockSpec(memory_space=pltpu.SMEM),
                    pl.BlockSpec((B, pps), lambda l: (0, 0)),
                    pl.BlockSpec((B, 1), lambda l: (0, 0))])
-    kernel = functools.partial(_decode_group_kernel, cfg_tuple=cfg_tuple)
+    kernel = functools.partial(_decode_group_kernel, cfg_tuple=cfg_tuple,
+                               erf=_erf_for(mode))
     kp2, vp2, x_out = pl.pallas_call(
         kernel,
         grid=(Lg,),
@@ -488,7 +474,7 @@ def decode_layer_group(x, kp, vp, stacked, meta, page_tables, lengths,
                    jax.ShapeDtypeStruct((B, C), x.dtype)],
         scratch_shapes=[pltpu.VMEM((B, C), jnp.float32)],
         input_output_aliases={1: 0, 2: 1},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=(mode == "interpret"),
     )(x, kp, vp, *w_arrays, meta, page_tables, lengths)
@@ -614,11 +600,11 @@ def decode_attn_phase(x, kp, vp, lp, meta, page_tables, lengths, cfg,
     return kp2, vp2, o_part
 
 
-def _decode_ffn_phase_kernel(x_ref, w1_ref, b1_ref, w2_ref, f_out):
+def _decode_ffn_phase_kernel(x_ref, w1_ref, b1_ref, w2_ref, f_out, *, erf):
     x = x_ref[...].astype(jnp.float32)
-    h = _gelu_erf(jnp.dot(x, w1_ref[...].astype(jnp.float32).T,
+    h = _gelu_f32(jnp.dot(x, w1_ref[...].astype(jnp.float32).T,
                           preferred_element_type=jnp.float32)
-                  + b1_ref[...].astype(jnp.float32))
+                  + b1_ref[...].astype(jnp.float32), erf)
     f_out[...] = jnp.dot(h, w2_ref[...].astype(jnp.float32).T,
                          preferred_element_type=jnp.float32)
 
@@ -634,7 +620,7 @@ def decode_ffn_phase(x, w1, b1, w2, mode):
     B, C = x.shape
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     f_out = pl.pallas_call(
-        _decode_ffn_phase_kernel,
+        functools.partial(_decode_ffn_phase_kernel, erf=_erf_for(mode)),
         in_specs=[vmem, vmem, vmem, vmem],
         out_specs=vmem,
         out_shape=jax.ShapeDtypeStruct((B, C), jnp.float32),

@@ -10,14 +10,14 @@ and attention gathers through the table.  Allocation/eviction become
 O(1) free-list ops (``serving/kvcache.py``) and admission control is
 exact page accounting instead of worst-case reservation.
 
-Two backends behind one call, the repo's probe-and-latch dispatch shape
-(ops/attention.py, ops/pallas/epilogue.py):
+Two backends behind one call (the ops/attention.py, ops/pallas/epilogue.py
+dispatch shape):
 
 - **TPU**: ``jax.experimental.pallas.ops.tpu.paged_attention`` — the
   Pallas GQA kernel (SNIPPETS [3] shards this very kernel along KV
   heads for the multi-chip tier).  The kernel applies no softmax scale,
   so queries are pre-scaled here.
-- **CPU / fallback**: an XLA gather-based reference — pages are gathered
+- **CPU**: an XLA gather-based reference — pages are gathered
   back into a contiguous ``(B, KVH, pages_per_seq * page_size, D)``
   view and attention runs as masked f32 softmax.  The whole decode
   engine is therefore tier-1 testable on CPU, and the reference IS the
@@ -26,17 +26,20 @@ Two backends behind one call, the repo's probe-and-latch dispatch shape
   match a full-cache decode bit for bit under greedy decoding.
 
 ``MXNET_PAGED_ATTENTION`` — ``0``/``off`` forces the reference,
-``interpret`` forces the Pallas kernel in interpreter mode (CPU test
-lane for the kernel wrapper itself), default auto-probes like the flash
-and epilogue kernels do.
+``interpret`` runs the Pallas kernel through the TPU interpreter
+(``pltpu.force_tpu_interpret_mode`` — the CPU test lane for the kernel
+and its wrapper), default selects the kernel on a TPU backend for heads
+whose ``head_dim`` is a multiple of the 128 lanes (the compiler refuses
+the rest).  A kernel selected here that fails to compile fails the call.
 """
 from __future__ import annotations
 
-import os
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from . import kernel_mode
 
 __all__ = ["paged_attention", "paged_attention_reference", "copy_page",
            "QPages", "gather_pages_deq", "last_path"]
@@ -71,43 +74,11 @@ class QPages(NamedTuple):
 # Tests assert on this to guarantee the kernel is actually exercised.
 last_path = None
 
-_probe_result = None
-_fallback_warned = False
-
-
-def _probe_pallas():
-    """One-time capability probe on tiny shapes (latched): a non-TPU
-    accelerator pays the failed Mosaic compile exactly once."""
-    global _probe_result
-    if _probe_result is None:
-        try:
-            from jax.experimental.pallas.ops.tpu.paged_attention import (
-                paged_attention as kernel)
-            q = jnp.zeros((1, 2, 128), jnp.float32)
-            kv = jnp.zeros((1, 8, 16, 128), jnp.float32)
-            lengths = jnp.ones((1,), jnp.int32)
-            pages = jnp.zeros((1, 8), jnp.int32)
-            jax.block_until_ready(
-                kernel(q, kv, kv, lengths, pages, pages_per_compute_block=4))
-            _probe_result = True
-        except Exception:  # pragma: no cover - depends on platform
-            _probe_result = False
-    return _probe_result
 
 
 def _mode():
     """'compiled' | 'interpret' | None (XLA reference)."""
-    flag = os.environ.get("MXNET_PAGED_ATTENTION", "").lower()
-    if flag in ("0", "off", "false"):
-        return None
-    if flag == "interpret":
-        return "interpret"
-    try:
-        if jax.default_backend() != "cpu" and _probe_pallas():
-            return "compiled"
-    except Exception:  # pragma: no cover
-        pass
-    return None
+    return kernel_mode("MXNET_PAGED_ATTENTION")
 
 
 def _pages_per_block(pages_per_seq):
@@ -233,7 +204,7 @@ def paged_attention(q, k_pages, v_pages, lengths, page_indices, scale=None):
     is exactly the global one and the kernel needs no sharding
     awareness at all; attention is embarrassingly parallel over heads.
     """
-    global last_path, _fallback_warned
+    global last_path
     if isinstance(k_pages, QPages):
         # int8 KV pages: dequant-at-read through the gather reference —
         # the contiguous fp view is exactly what a full-cache decoder
@@ -246,32 +217,36 @@ def paged_attention(q, k_pages, v_pages, lengths, page_indices, scale=None):
         last_path = "xla"
         return attend_ctx(q, k_ctx, v_ctx, lengths, s)
     mode = _mode()
+    if mode == "compiled" and q.shape[-1] % 128:
+        # jax's kernel blocks its (.., 1) softmax carries by head_dim, and
+        # Mosaic refuses a 64-wide block of a 1-wide array: "the last two
+        # dimensions of your block shape [must be] divisible by 8 and 128
+        # respectively, or be equal to the respective dimensions of the
+        # overall array" (v5e, PR 21).  Such heads read through the gather.
+        mode = None
     if mode is not None:
-        try:
-            from jax.experimental.pallas.ops.tpu.paged_attention import (
-                paged_attention as kernel)
-            d = q.shape[-1]
-            s = scale if scale is not None else 1.0 / (d ** 0.5)
-            # the TPU kernel masks length-0 rows itself but divides by a
-            # zero denominator; clamp to 1 (reads the scratch page, the
-            # caller discards inactive rows either way)
-            safe_len = jnp.maximum(lengths.astype(jnp.int32), 1)
+        import contextlib
+        from jax.experimental.pallas import tpu as pltpu
+        from jax.experimental.pallas.ops.tpu.paged_attention import (
+            paged_attention as kernel)
+        d = q.shape[-1]
+        s = scale if scale is not None else 1.0 / (d ** 0.5)
+        # the TPU kernel masks length-0 rows itself but divides by a
+        # zero denominator; clamp to 1 (reads the scratch page, the
+        # caller discards inactive rows either way)
+        safe_len = jnp.maximum(lengths.astype(jnp.int32), 1)
+        # jax's kernel takes no interpret argument: the TPU interpreter
+        # is switched on around the call instead
+        interp = (pltpu.force_tpu_interpret_mode() if mode == "interpret"
+                  else contextlib.nullcontext())
+        with interp:
             out = kernel(
                 (q * jnp.asarray(s, q.dtype)), k_pages, v_pages,
                 safe_len, page_indices.astype(jnp.int32),
                 pages_per_compute_block=_pages_per_block(
                     page_indices.shape[1]))
-            last_path = ("pallas" if mode == "compiled"
-                         else "pallas-interpret")
-            return out
-        except Exception as e:  # pragma: no cover - platform dependent
-            if not _fallback_warned:
-                import logging
-                logging.getLogger(__name__).warning(
-                    "paged_attention: Pallas kernel failed (%s: %s); using "
-                    "the XLA gather reference for this process",
-                    type(e).__name__, e)
-                _fallback_warned = True
+        last_path = "pallas" if mode == "compiled" else "pallas-interpret"
+        return out
     last_path = "xla"
     return paged_attention_reference(q, k_pages, v_pages, lengths,
                                      page_indices, scale=scale)

@@ -25,21 +25,21 @@ The Pallas kernel (whole-array VMEM, the ``fused_cell.decode_ffn_phase``
 shape) fuses unpack + dequant + matmul into one launch; the XLA
 reference (:func:`quant_matmul_reference`) computes the identical
 formula op-for-op, which makes ``MXNET_QUANT_MATMUL=interpret`` a
-bit-exactness oracle for the kernel on CPU.  Dispatch is the repo's
-probe-and-latch grammar: ``''`` auto (Pallas on non-CPU backends),
-``0``/``off`` forces the XLA reference, ``interpret`` forces the kernel
-in interpreter mode.
+bit-exactness oracle for the kernel on CPU.  Dispatch is the repo's gate
+grammar: ``''`` auto (Pallas on a TPU backend), ``0``/``off`` forces the
+XLA reference, ``interpret`` forces the kernel in interpreter mode.
 """
 from __future__ import annotations
 
 import math
-import os
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import kernel_mode
 
 __all__ = ["QuantW8", "QuantW4", "quantize_w8", "quantize_w4",
            "dequantize_weight", "quant_matmul", "quant_matmul_reference",
@@ -54,8 +54,6 @@ _INT4_MAX = 7.0
 trace_counts = {"quant_matmul": 0}
 # "pallas" | "pallas-interpret" | "xla" — which backend last latched
 last_path = None
-
-_fallback_warned = False
 
 
 class QuantW8(NamedTuple):
@@ -78,20 +76,8 @@ def is_quantized(w):
 
 def quant_mode():
     """'compiled' | 'interpret' | None — the fused dequant-matmul gate
-    (``MXNET_QUANT_MATMUL``).  Like ``decode_mode`` the probe is
-    deferred: the kernel is shape-specialized per GEMM, so the first
-    real call on a non-CPU backend latches the fallback on failure."""
-    flag = os.environ.get("MXNET_QUANT_MATMUL", "").lower()
-    if flag in ("0", "off", "false"):
-        return None
-    if flag == "interpret":
-        return "interpret"
-    try:
-        if jax.default_backend() != "cpu":
-            return "compiled"
-    except Exception:  # pragma: no cover
-        pass
-    return None
+    (``MXNET_QUANT_MATMUL``)."""
+    return kernel_mode("MXNET_QUANT_MATMUL")
 
 
 # ---------------------------------------------------------------------------
@@ -219,29 +205,25 @@ def quant_matmul(x, qw):
     """``x @ dequant(qw).T`` with the integer weight dequantized inside
     the kernel.  ``x``: (..., I) any float dtype; returns (..., O) f32.
 
-    Dispatch: Pallas (compiled or interpret per ``MXNET_QUANT_MATMUL``)
-    with a warn-once latch down to the XLA reference — decode keeps
-    serving on any backend the kernel can't compile for."""
-    global last_path, _fallback_warned
+    Dispatch: Pallas (compiled or interpret per ``MXNET_QUANT_MATMUL``),
+    else the XLA reference.  A selected kernel that fails to compile
+    fails the call."""
+    global last_path
     i = (qw.q.shape[1] if isinstance(qw, QuantW8) else 2 * qw.q.shape[1])
     o = qw.q.shape[0]
     lead = x.shape[:-1]
     xf = x.reshape(-1, i).astype(jnp.float32)
     mode = quant_mode()
+    if mode == "compiled" and isinstance(qw, QuantW4):
+        # the int4 kernel's nibble interleave (stack + reshape on the
+        # lane axis) did not come back from the v5e's compiler in 35
+        # minutes at (3072, 768) and cost the run its chip (PR 21):
+        # int4 weights dequantize in XLA until the unpack is rewritten
+        mode = None
     if mode is not None:
-        try:
-            y = _pallas_qmm(xf, qw, interpret=(mode == "interpret"))
-            trace_counts["quant_matmul"] += 1
-            last_path = ("pallas" if mode == "compiled"
-                         else "pallas-interpret")
-            return y.reshape(lead + (o,))
-        except Exception as e:  # pragma: no cover - platform dependent
-            if not _fallback_warned:
-                import logging
-                logging.getLogger(__name__).warning(
-                    "quant_matmul: Pallas kernel failed (%s: %s); using "
-                    "the XLA dequant reference for this process",
-                    type(e).__name__, e)
-                _fallback_warned = True
+        y = _pallas_qmm(xf, qw, interpret=(mode == "interpret"))
+        trace_counts["quant_matmul"] += 1
+        last_path = "pallas" if mode == "compiled" else "pallas-interpret"
+        return y.reshape(lead + (o,))
     last_path = "xla"
     return quant_matmul_reference(xf, qw).reshape(lead + (o,))

@@ -249,8 +249,8 @@ def rnn_forward(x, params, h0, c0, mode, state_size, num_layers=1,
     Returns (out (T, B, H*D), hT (L*D, B, H), cT or None).
 
     ``fused``: the persistent fused-cell kernel gate for the LSTM time
-    loop — "auto" resolves MXNET_RNN_FUSED_CELL (probe-and-latch: Pallas
-    on accelerator backends, off on CPU), None/False disables,
+    loop — "auto" resolves MXNET_RNN_FUSED_CELL (Pallas on a TPU
+    backend, off elsewhere), None/False disables,
     'compiled'/'interpret' force.  Callers that jit-trace this function
     (npx.rnn, bench A/B arms) resolve the gate OUTSIDE and pass the
     value through so their trace caches key on it.

@@ -11,7 +11,6 @@ Components:
 - make_mesh / MeshConfig: mesh construction helpers
 - functionalize(net): HybridBlock → pure (params, x) -> out function
 - DataParallelTrainer: whole-training-step compilation with dp sharding
-- sharded train step builders used by __graft_entry__.dryrun_multichip
 """
 from __future__ import annotations
 
@@ -332,7 +331,6 @@ class DataParallelTrainer:
         `fold_in(key, axis_index(dp))` — with dropout > 0 the trajectory
         intentionally differs from the replicated run (same rule as the
         sharded flash kernel's in-kernel dropout)."""
-        from .pipeline import shard_map, _shard_map_compat_kwargs
         fn = self._fn
         loss_fn = self.loss_fn
         kind, hp = self._opt_kind, self._hp
@@ -477,11 +475,11 @@ class DataParallelTrainer:
         else:
             slot_spec_tree = {k: (sspec[k],) * 2 for k in grad_names}
         state_spec = {"params": pspec, "slots": slot_spec_tree, "t": P()}
-        smapped = shard_map(
+        smapped = jax.shard_map(
             body, mesh=mesh,
             in_specs=(state_spec, P(dp_ax), P(dp_ax), P(), P()),
             out_specs=(state_spec, P()),
-            **_shard_map_compat_kwargs())
+            check_vma=False)
 
         repl = NamedSharding(mesh, P())
         data_sh = NamedSharding(mesh, P(dp_ax))

@@ -16,11 +16,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["MoELayer"]
@@ -117,18 +113,11 @@ class MoELayer:
             y = back[idx_e, idx_c] * gate[:, None]
             return jnp.where(keep[:, None], y, 0.0)
 
-        import inspect
-        kw = {}
-        sig = inspect.signature(shard_map).parameters
-        if "check_vma" in sig:
-            kw["check_vma"] = False
-        elif "check_rep" in sig:
-            kw["check_rep"] = False
         return shard_map(
             local, mesh=self.mesh,
             in_specs=(P(), P(axis), P(axis), P(axis)),
             out_specs=P(axis),
-            **kw,
+            check_vma=False,
         )(params["router"],
           params["w_in"].reshape(self.n_shards, e_local, self.d_model,
                                  self.d_hidden),

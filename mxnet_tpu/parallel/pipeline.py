@@ -22,26 +22,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-try:  # jax>=0.8 top-level, older under experimental
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 __all__ = ["PipelineRunner", "pipeline_apply"]
-
-
-def _shard_map_compat_kwargs():
-    """shard_map's replication-check kwarg was renamed across jax
-    versions (check_rep → check_vma); resolve once for every caller."""
-    import inspect as _inspect
-    sigp = _inspect.signature(shard_map).parameters
-    if "check_vma" in sigp:
-        return {"check_vma": False}
-    if "check_rep" in sigp:
-        return {"check_rep": False}
-    return {}
 
 
 class PipelineRunner:
@@ -130,12 +114,11 @@ class PipelineRunner:
                 outputs = lax.psum(outputs, axis)
             return outputs
 
-        kw = _shard_map_compat_kwargs()
         out = shard_map(
             per_stage, mesh=self.mesh,
             in_specs=(P(axis), P()),  # params sharded by stage
             out_specs=P(),
-            **kw,
+            check_vma=False,
         )(stacked, mb)
         return out.reshape(B, *out.shape[2:])
 
@@ -313,11 +296,10 @@ class PipelineTrainer:
             aux_final = jax.tree.map(lambda a: a[None], aux_final)
             return outputs, aux_final
 
-        kw = _shard_map_compat_kwargs()
         out, stage_aux = shard_map(
             per_stage, mesh=self.mesh,
-            in_specs=(P(axis), P()), out_specs=(P(), P(axis)), **kw)(
-            params["stages"], mb)
+            in_specs=(P(axis), P()), out_specs=(P(), P(axis)),
+            check_vma=False)(params["stages"], mb)
         out = out.reshape(B, *out.shape[2:])
         epi_aux = {}
         if self._epi_fn is not None:

@@ -17,11 +17,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-try:  # jax >= 0.6 top-level export vs the jax 0.4/0.5 experimental home
-    from jax import shard_map
-except ImportError:  # pragma: no cover - depends on jax version
-    from jax.experimental.shard_map import shard_map
 
 
 def _flash_block(q, k_blk, v_blk, o, m, l, scale, q_start, k_start,

@@ -851,9 +851,28 @@ def reshard_plan(old_cfg, new_cfg, shapes, lost_devices=()):
 COLLECTIVE_CLASSES = ("all-reduce", "all-gather", "reduce-scatter",
                       "collective-permute", "all-to-all")
 
-_COLLECTIVE_RE = re.compile(
-    r"=\s+[^=\s]*\s*(all-reduce|all-gather|reduce-scatter|"
-    r"collective-permute|all-to-all)(?:-start)?\(")
+_OPCODE_RE = re.compile(r"\s*([a-z][a-z0-9\-]*)\(")
+
+
+def _hlo_opcode(line):
+    """Opcode of one HLO instruction line (``%name = <type> opcode(...)``),
+    or None.  The result type is skipped structurally, not by pattern: a
+    combined collective has a TUPLE type with spaces in it
+    (``(f32[32]{0}, f32[]) all-reduce(...)``)."""
+    _, sep, rhs = line.partition(" = ")
+    if not sep:
+        return None
+    if rhs.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rhs):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                rhs = rhs[i + 1:]
+                break
+    else:
+        rhs = rhs.partition(" ")[2]
+    m = _OPCODE_RE.match(rhs)
+    return m.group(1) if m else None
 
 
 def collective_census(compiled):
@@ -864,7 +883,8 @@ def collective_census(compiled):
     once.  Deterministic and load-independent — safe to gate CI on,
     exactly like the decode-launch census (fused_cell.count_launches):
     the counts depend only on the program and partitioner, never on
-    machine load.
+    machine load.  Text in which no instruction parses at all is an
+    error, not a census of zeros.
     """
     if hasattr(compiled, "compile"):        # Lowered -> Compiled
         compiled = compiled.compile()
@@ -873,12 +893,19 @@ def collective_census(compiled):
     else:
         text = str(compiled)
     counts = {c: 0 for c in COLLECTIVE_CLASSES}
+    parsed = 0
     for line in text.splitlines():
-        if "-done(" in line:
+        op = _hlo_opcode(line)
+        if op is None:
             continue
-        m = _COLLECTIVE_RE.search(line)
-        if m:
-            counts[m.group(1)] += 1
+        parsed += 1
+        if op.endswith("-start"):
+            op = op[:-len("-start")]
+        if op in counts:
+            counts[op] += 1
+    if not parsed:
+        raise ValueError("collective_census: no HLO instruction found in "
+                         "the program text (format changed?)")
     counts["total"] = sum(counts[c] for c in COLLECTIVE_CLASSES)
     return counts
 

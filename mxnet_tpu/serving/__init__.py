@@ -28,9 +28,10 @@ fleet"):
   replica processes with restart budgets and crash-loop backoff.
 - ``ServingFleet`` / ``rollout`` (``fleet.py``) — the two composed,
   plus zero-downtime rolling model rollout with canary abort/rollback.
-- ``maybe_enable_compile_cache`` (``registry.py``) — persistent XLA
-  compile cache (``MXNET_COMPILE_CACHE_DIR``) so replica restarts and
-  rollouts re-serve in seconds instead of compile-minutes.
+- the replica entry point turns on JAX's persistent compile cache
+  (``runtime.enable_compile_cache``: ``JAX_COMPILATION_CACHE_DIR`` or
+  the in-checkout ``.jax_cache``) so replica restarts and rollouts
+  re-serve in seconds instead of compile-minutes.
 
 LLM tier (continuous-batching decode serving — see README "LLM
 serving"):
@@ -85,8 +86,7 @@ from .errors import (BadRequestError, DeadlineExceededError,
 from .metrics import LatencyHistogram, ModelMetrics, ServingMetrics
 from .autoscale import Autoscaler, SLOPolicy
 from .registry import (ModelRegistry, ServedModel, default_buckets,
-                       load_model_spec, maybe_enable_compile_cache,
-                       resolve_builder)
+                       load_model_spec, resolve_builder)
 from .batcher import DynamicBatcher
 from .kvcache import (PageAllocator, PrefixCache, pack_session,
                       unpack_session)
@@ -105,7 +105,7 @@ __all__ = [
     "Autoscaler", "SLOPolicy",
     "ServingMetrics", "ModelMetrics", "LatencyHistogram",
     "ModelRegistry", "ServedModel", "default_buckets",
-    "load_model_spec", "maybe_enable_compile_cache", "resolve_builder",
+    "load_model_spec", "resolve_builder",
     "DynamicBatcher", "PageAllocator", "PrefixCache", "pack_session",
     "unpack_session", "DecodeEngine",
     "ModelServer", "ServingClient",

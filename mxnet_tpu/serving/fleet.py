@@ -17,8 +17,7 @@ Rollout protocol (``fleet.rollout`` / module-level :func:`rollout`):
    in-flight requests finish; the warmup compiles compete with
    nothing), admin-**load** the new version — the registry warms every
    batch bucket BEFORE flipping the latest pointer, reading the
-   persistent compile cache when ``MXNET_COMPILE_CACHE_DIR`` is set —
-   then **undrain**.  Traffic on the replica never sees a gap: old
+   replica's persistent compile cache — then **undrain**.  Traffic on the replica never sees a gap: old
    version until the flip, new version after, both fully compiled.
 4. The first replica is the **canary**: after its flip it is probed on
    the new version; if the probe error rate exceeds

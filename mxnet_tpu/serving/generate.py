@@ -232,7 +232,13 @@ class DecodeEngine:
     persistent fused-cell kernel (``ops/pallas/fused_cell``): one
     Pallas launch per ``MXNET_DECODE_LAYER_GROUP`` decoder layers
     (default: all in one group) instead of the per-op XLA tower.  The
-    static launch census lands in ``stats()["launches"]`` and the
+    cell is the CPU oracle only (``interpret``): the v5e's compiler
+    refuses it, so a TPU engine runs the tower
+    (``fused_cell.decode_mode``).  ``stats()["decode_fused"]`` names
+    the program that was chosen (``None`` is the per-op tower), and that
+    program is the one that runs —
+    a compile failure fails the step, there is no second program behind
+    it.  The static launch census lands in ``stats()["launches"]`` and the
     metrics ``generate`` snapshot; the per-geometry decode/prefill
     program cache is LRU-bounded by ``MXNET_GEN_FN_CACHE`` with
     compile/evict gauges next to it.
@@ -308,10 +314,11 @@ class DecodeEngine:
         # TPPlan BEFORE building any program — params go column/row-
         # parallel, KV pages split along KV heads, and every decode/
         # prefill/verify builder below gets the config so its program
-        # runs per-shard under shard_map.  A config that cannot shard
-        # this geometry resolves to None (decoder.tp_plan warns loudly)
-        # and the engine serves replicated.  PageAllocator bookkeeping is
-        # host-side and shard-agnostic either way.
+        # runs per-shard under shard_map.  A config that asks for tp > 1
+        # and cannot shard this geometry is an error (decoder.tp_plan
+        # raises); one with no tp axis resolves to None and the engine
+        # serves one chip.  PageAllocator bookkeeping is host-side and
+        # shard-agnostic either way.
         self._tp_plan = _decoder.tp_plan(
             cfg, sharding, quant=self.quant,
             kv_int8=self.kv_dtype == "int8")
@@ -350,7 +357,6 @@ class DecodeEngine:
             self._decode_fn = _decoder.make_decode_step(
                 cfg, self.page_size, sharding=self.sharding,
                 quant=self.quant, kv_dtype=self.kv_dtype)
-        self._decode_fn_unfused = None   # lazy fallback (compile fail)
         self._prefill_fn = _decoder.make_prefill_chunk(
             cfg, self.page_size, self.prefill_chunk,
             sharding=self.sharding, quant=self.quant,
@@ -1295,35 +1301,6 @@ class DecodeEngine:
             return pages
         return self._tp_plan.place_kv(pages)
 
-    def _run_decode_fn(self, *args):
-        """Dispatch one decode step; if the fused persistent kernel
-        fails its FIRST real compile (non-TPU accelerator, VMEM
-        overflow on a huge model), latch the per-op XLA path for the
-        process and re-issue — same probe-and-fallback contract as the
-        flash/epilogue/paged kernels."""
-        if self._decode_fn_unfused is not None:
-            return self._decode_fn_unfused(*args)
-        try:
-            return self._decode_fn(*args)
-        except Exception:
-            if self.decode_fused_mode is None:
-                raise
-            _log.exception(
-                "fused decode kernel failed; falling back to the "
-                "per-op decode step for this engine")
-            self.decode_fused_mode = None
-            self._decode_fn_unfused = _decoder.make_decode_step(
-                self.cfg, self.page_size, sharding=self.sharding,
-                quant=self.quant, kv_dtype=self.kv_dtype)
-            self.launch_stats = _decoder.decode_launch_stats(
-                self.params, self.cfg, self.page_size, self.slots,
-                self.pages_per_seq, self.alloc.total_pages, fused=False,
-                sharding=self.sharding, quant=self.quant,
-                kv_dtype=self.kv_dtype)
-            self.metrics.observe_decode_launches(self.name,
-                                                 self.launch_stats)
-            return self._decode_fn_unfused(*args)
-
     def _ensure_pages(self, slot, tokens_ahead):
         """Grow the slot's page list to cover ``tokens_ahead`` more cache
         positions; preempts the youngest other sequence on exhaustion.
@@ -1502,7 +1479,7 @@ class DecodeEngine:
         # staging buffers are reused next step: uploads must copy
         # (jnp.array), never alias (jnp.asarray aliases host memory on
         # CPU and the dispatch reads it after we mutate)
-        self._kp, self._vp, next_tokens, _ = self._run_decode_fn(
+        self._kp, self._vp, next_tokens, _ = self._decode_fn(
             self.params, self._kp, self._vp, jnp.array(tokens),
             jnp.array(positions), self._tables_device(),
             self._active_device(active))
@@ -1628,7 +1605,7 @@ class DecodeEngine:
             self.metrics.observe_host_gap(
                 self.name,
                 0.0 if depth0 else max(0.0, t0 - self._t_force_end))
-        self._kp, self._vp, out, _ = self._run_decode_fn(
+        self._kp, self._vp, out, _ = self._decode_fn(
             self.params, self._kp, self._vp, tokens, jnp.array(sp),
             self._tables_device(), self._active_device(sa))
         fl = _Flight("plain", out, t0, [(s, s.admit_seq) for s in live],
@@ -1969,7 +1946,7 @@ class DecodeEngine:
                 sp[s.idx] = s.pos
                 sa[s.idx] = True
             t0 = time.perf_counter()
-            self._kp, self._vp, out, _ = self._run_decode_fn(
+            self._kp, self._vp, out, _ = self._decode_fn(
                 self.params, self._kp, self._vp, jnp.array(st),
                 jnp.array(sp), self._tables_device(),
                 self._active_device(sa))
@@ -2305,16 +2282,16 @@ class DecodeEngine:
     def warmup(self):
         """Compile the prefill + decode programs now (dummy inputs
         against the scratch page) so the first client request never pays
-        XLA compile; with ``MXNET_COMPILE_CACHE_DIR`` set these become
-        cache reads on replica restart, like the registry's bucket
-        warmup."""
+        XLA compile; with the persistent compile cache on
+        (``runtime.enable_compile_cache``) these become cache reads on
+        replica restart, like the registry's bucket warmup."""
         import jax
         zrow = jnp.zeros(self.pages_per_seq, jnp.int32)
         self._kp, self._vp, tok, _ = self._prefill_fn(
             self.params, self._kp, self._vp,
             jnp.zeros(self.prefill_chunk, jnp.int32), jnp.int32(0),
             jnp.int32(1), zrow)
-        self._kp, self._vp, toks, _ = self._run_decode_fn(
+        self._kp, self._vp, toks, _ = self._decode_fn(
             self.params, self._kp, self._vp,
             jnp.zeros(self.slots, jnp.int32),
             jnp.zeros(self.slots, jnp.int32),

@@ -30,49 +30,10 @@ import time
 
 import numpy as onp
 
-from .. import config as _config
 from .errors import BadRequestError, ModelNotFoundError
 
 __all__ = ["ServedModel", "ModelRegistry", "default_buckets",
-           "maybe_enable_compile_cache", "resolve_builder",
-           "load_model_spec"]
-
-# process-wide latch: the jax compilation-cache dir is global state, set
-# at most once per process (first registry wins; later calls are no-ops)
-_COMPILE_CACHE = {"lock": threading.Lock(), "dir": None}
-
-
-def maybe_enable_compile_cache(path=None):
-    """Point XLA's persistent compilation cache at ``path`` (default:
-    ``MXNET_COMPILE_CACHE_DIR``); returns the active cache dir or None.
-
-    This is the replica cold-start cut: the registry's per-bucket warmup
-    compiles write the cache, so a restarted (supervisor) or rolled-out
-    (fleet.rollout) replica re-serves in seconds — its warmup becomes N
-    cache reads instead of N cold XLA compiles.  Thresholds are zeroed so
-    even small bucket programs persist (serving cares about the p99 of a
-    restart, not about cache-entry economics)."""
-    if path is None:
-        path = _config.get("MXNET_COMPILE_CACHE_DIR") or None
-    if not path:
-        return _COMPILE_CACHE["dir"]
-    with _COMPILE_CACHE["lock"]:
-        if _COMPILE_CACHE["dir"] is not None:
-            return _COMPILE_CACHE["dir"]
-        import jax
-        try:
-            jax.config.update("jax_compilation_cache_dir", str(path))
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-        except Exception:  # older jax spelling
-            from jax.experimental.compilation_cache import (
-                compilation_cache as cc)
-            cc.set_cache_dir(str(path))
-        _COMPILE_CACHE["dir"] = str(path)
-        return _COMPILE_CACHE["dir"]
-
+           "resolve_builder", "load_model_spec"]
 
 def resolve_builder(path):
     """``"package.module:callable"`` → the callable.
@@ -228,9 +189,6 @@ class ModelRegistry:
     """Thread-safe multi-model, multi-version registry."""
 
     def __init__(self):
-        # MXNET_COMPILE_CACHE_DIR: warmup compiles persist across process
-        # restarts (no-op when the knob is unset)
-        maybe_enable_compile_cache()
         self._lock = threading.RLock()
         self._models = {}   # name -> {version: ServedModel}
         self._latest = {}   # name -> version

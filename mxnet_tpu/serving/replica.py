@@ -3,8 +3,8 @@
 ``python -m mxnet_tpu.serving.replica --spec spec.json --port P --id r0``
 
 boots one fleet replica: enable the persistent XLA compile cache
-(``MXNET_COMPILE_CACHE_DIR`` — a restarted replica's per-bucket warmup
-becomes cache reads, so it re-serves in seconds instead of
+(``runtime.enable_compile_cache`` — a restarted replica's per-bucket
+warmup becomes cache reads, so it re-serves in seconds instead of
 compile-minutes), load every model in the spec (warm-before-publish),
 start an admin-enabled ModelServer on the given port, and then sit in a
 watchdog loop until SIGTERM (graceful: drain the batcher, then exit 0).
@@ -179,14 +179,13 @@ def main(argv=None):
         os.environ["MXNET_SERVING_REPLICA_ID"] = args.id
 
     from . import ModelServer
-    from .registry import (ModelRegistry, load_model_spec,
-                           maybe_enable_compile_cache)
-    from .. import faults
+    from .registry import ModelRegistry, load_model_spec
+    from .. import faults, runtime
 
     with open(args.spec) as f:
         spec = json.load(f)
 
-    cache = maybe_enable_compile_cache()
+    cache = runtime.enable_compile_cache()
     registry = ModelRegistry()
     t0 = time.monotonic()
     generators = []  # (name, model, DecodeEngine kwargs)
@@ -199,7 +198,6 @@ def main(argv=None):
                                dict(mspec["generate"])))
         else:
             load_model_spec(registry, mspec)
-    warm_s = time.monotonic() - t0
 
     server = ModelServer(
         registry, host=args.host, port=args.port, admin=True,
@@ -210,9 +208,13 @@ def main(argv=None):
         genkw["sharding"] = resolve_sharding(genkw.get("sharding"))
         genkw.update(resolve_quant(genkw.pop("quant", None)))
         server.attach_engine(name, DecodeEngine(model, name=name, **genkw))
+    warm_s = time.monotonic() - t0      # models built, every program warm
     server.start()
-    print("REPLICA_READY id=%s port=%d warm_s=%.2f cache=%s"
-          % (args.id, server.port, warm_s, cache or "off"), flush=True)
+    import jax
+    devs = jax.devices()
+    print("REPLICA_READY id=%s port=%d warm_s=%.2f cache=%s devices=%s:%s"
+          % (args.id, server.port, warm_s, cache, devs[0].platform,
+             ",".join(str(d.id) for d in devs)), flush=True)
 
     stop = threading.Event()
 

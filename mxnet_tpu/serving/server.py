@@ -312,8 +312,8 @@ class ModelServer:
         - ``POST /v1/admin/load`` — body is a model spec
           (``registry.load_model_spec``): build the model from its
           importable builder, warm EVERY batch bucket (XLA precompile —
-          reads the persistent compile cache when
-          ``MXNET_COMPILE_CACHE_DIR`` is set), THEN flip the registry's
+          reads the replica's persistent compile cache), THEN flip the
+          registry's
           latest pointer.  Traffic keeps flowing to the old version for
           the whole warmup — this is the zero-downtime swap primitive
           ``fleet.rollout`` drives one replica at a time.

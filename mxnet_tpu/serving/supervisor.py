@@ -20,8 +20,9 @@ Restart discipline (the crash-loop brake):
   ``MXNET_FLEET_RESTART_BACKOFF_MS``; a replica that stays healthy for
   a while resets its streak.
 
-Cold-start is bounded by the persistent XLA compile cache
-(``MXNET_COMPILE_CACHE_DIR``): the first replica's per-bucket warmup
+Cold-start is bounded by the persistent XLA compile cache the replica
+entry point turns on (``runtime.enable_compile_cache``): the first
+replica's per-bucket warmup
 pays the compiles, every later boot (including restarts and rollout
 re-warms) reads them back in seconds.
 """
@@ -40,6 +41,7 @@ import time
 
 from .. import config as _config
 from .. import profiler
+from ..context import chip_visibility_env, must_place_children
 
 __all__ = ["ReplicaProcess", "ReplicaSupervisor"]
 
@@ -132,6 +134,7 @@ class ReplicaSupervisor:
         self.replicas = [ReplicaProcess("r%d" % i, host, p)
                          for i, p in enumerate(ports)]
         self._next_idx = self.n   # rid counter for autoscale add_replica
+        self._chips = {}          # rid -> (chip, its env) (see _chip_env)
         self._spec_path = None
         self._lock = threading.Lock()
         self._stop = threading.Event()
@@ -142,6 +145,8 @@ class ReplicaSupervisor:
         return [r.addr for r in self.replicas]
 
     def start(self, wait_ready=True):
+        for r in self.replicas:
+            self._env(r)    # every replica has a chip, or nothing starts
         fd, self._spec_path = tempfile.mkstemp(prefix="mxtpu-fleet-",
                                                suffix=".json")
         with os.fdopen(fd, "w") as f:
@@ -156,10 +161,52 @@ class ReplicaSupervisor:
         self._monitor.start()
         return self.addresses()
 
-    def _spawn(self, r):
+    def _chip_env(self, r, env):
+        """One process per chip: a model replica is shown the lowest chip
+        no sibling holds (``context.chip_visibility_env``), or its first
+        child would take every chip of the host and the second would
+        fail or hang.  A replica the host has no chip left for is
+        refused here (``start``/``add_replica`` raise before a process
+        exists).  Nothing is stamped where
+        ``context.must_place_children`` says so (CPU lane, no TPU, the
+        operator's own ``TPU_VISIBLE_CHIPS``), or for the only replica
+        of a multi-chip mesh (the host's chips are its mesh).
+        Several replicas of which one wants more than one chip is
+        refused: that partition of a host has not run on a chip yet."""
+        if not must_place_children(env):
+            return {}
+        need = 1
+        for s in env.get("MXNET_MESH_SHAPE", "").split(","):
+            need *= int(s) if s.strip() else 1
+        if need > 1:
+            if len(self.replicas) > 1:
+                raise RuntimeError(
+                    "replica %s wants a %d-chip mesh beside %d other "
+                    "replica(s): sharing one host between multi-chip "
+                    "replicas is not supported (one replica per host, or "
+                    "one chip per replica)"
+                    % (r.rid, need, len(self.replicas) - 1))
+            return {}
+        with self._lock:
+            if r.rid not in self._chips:
+                used = {chip for chip, _ in self._chips.values()}
+                chip = next(i for i in range(len(used) + 1)
+                            if i not in used)
+                # a restart keeps its chip and its runtime port
+                self._chips[r.rid] = (chip, chip_visibility_env(
+                    chip, _reserve_ports(1, self.host)[0]))
+            return dict(self._chips[r.rid][1])
+
+    def _env(self, r):
         env = dict(os.environ)
         env.update(self.env)
         env.update(self.env_by_rid.get(r.rid, {}))
+        if self.command_builder is None:
+            env.update(self._chip_env(r, env))
+        return env
+
+    def _spawn(self, r):
+        env = self._env(r)
         env["MXNET_SERVING_REPLICA_ID"] = r.rid
         # the package must be importable from a bare `python -m`
         pkg_root = os.path.dirname(os.path.dirname(
@@ -248,7 +295,13 @@ class ReplicaSupervisor:
                 self.env_by_rid[rid] = dict(env)
             self.replicas.append(r)
         if spawn and self._spec_path is not None:
-            self._spawn(r)
+            try:
+                self._spawn(r)
+            except RuntimeError:    # no chip left: the fleet is unchanged
+                with self._lock:
+                    self.replicas.remove(r)
+                    self.env_by_rid.pop(rid, None)
+                raise
         profiler.record_event_stat("fleet.replica_spawn")
         return r
 
@@ -263,6 +316,7 @@ class ReplicaSupervisor:
                 return None
             self.replicas.remove(r)
             self.env_by_rid.pop(rid, None)
+            self._chips.pop(rid, None)
         r.state = "stopped"
         if r.alive():
             r.proc.send_signal(signal.SIGTERM)

@@ -471,10 +471,13 @@ def test_rollout_canary_p99_regression_aborts():
 _CACHE_SCRIPT = r"""
 import json, os, sys, time
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["MXNET_COMPILE_CACHE_DIR"] = sys.argv[1]
-from mxnet_tpu import serving
+os.environ["JAX_COMPILATION_CACHE_DIR"] = sys.argv[1]
+# a CPU compile of the demo model is faster than JAX's 1 s floor
+os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+from mxnet_tpu import runtime, serving
 from mxnet_tpu.serving.replica import demo_dense
-reg = serving.ModelRegistry()   # enables the cache (env knob)
+assert runtime.enable_compile_cache() == sys.argv[1]   # as replica main does
+reg = serving.ModelRegistry()
 t0 = time.monotonic()
 served = reg.load("m", demo_dense(seed=0), item_shape=(16,),
                   max_batch_size=4)  # warmup=True: compile every bucket
@@ -486,7 +489,7 @@ print(json.dumps({"warm_s": time.monotonic() - t0,
 
 
 def test_compile_cache_warm_restart(tmp_path):
-    """Two replica boots against one MXNET_COMPILE_CACHE_DIR: the first
+    """Two replica boots against one JAX_COMPILATION_CACHE_DIR: the first
     writes per-bucket executables, the second's warmup is pure cache
     reads — zero NEW cache entries (every compile was a hit)."""
     cache = str(tmp_path / "xla-cache")

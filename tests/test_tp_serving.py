@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import os
 import sys
-import warnings
 
 import numpy as onp
 import pytest
@@ -99,22 +98,18 @@ def test_tp_plan_none_without_tp_axis(eight_devices, lm):
     assert decoder.tp_plan(lm.config, dp_only) is None
 
 
-def test_tp_plan_gqa_divisibility_loud_fallback(eight_devices, lm):
-    """kv_heads=2 cannot split 8 ways: the plan must refuse LOUDLY and
-    the engine must serve replicated (correct, not silently sharded)."""
+def test_tp_plan_refuses_what_it_cannot_shard(eight_devices, lm):
+    """tp > 1 asked for and not possible is an error, never a one-chip
+    engine under a TP label: kv_heads=2 cannot split 8 ways, and rules
+    that are not the Megatron column/row layout are not a TP plan."""
     bad = tp_config(mesh_shape=(1, 8))
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        assert decoder.tp_plan(lm.config, bad) is None
-    assert any("tp" in str(x.message) for x in w), \
-        [str(x.message) for x in w]
-    eng = make_engine(lm, sharding=bad)
-    try:
-        assert eng.tp == 1 and eng.sharding is None
-        out = eng.submit([1, 2, 3], max_new_tokens=4).result(timeout=120)
-        assert len(out["tokens"]) == 4
-    finally:
-        assert eng.stop()
+    with pytest.raises(ValueError, match="tp=8 does not divide"):
+        decoder.tp_plan(lm.config, bad)
+    with pytest.raises(ValueError, match="tp=8 does not divide"):
+        make_engine(lm, sharding=bad)
+    no_rules = ShardingConfig(mesh_shape=(4, 2), axis_names=("dp", "tp"))
+    with pytest.raises(ValueError, match="Megatron"):
+        decoder.tp_plan(lm.config, no_rules)
 
 
 # ---------------------------------------------------------------------------

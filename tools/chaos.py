@@ -1319,7 +1319,6 @@ def scenario_ramp(args):
         steady-state p99;
     (5) every decision is auditable after the fact: /v1/stats carries
         the autoscale counters + decision ring."""
-    import tempfile
     import threading
 
     import numpy as onp
@@ -1328,10 +1327,9 @@ def scenario_ramp(args):
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ["MXNET_GEN_ASYNC"] = "1"
     os.environ["MXNET_SLO_TENANT_WEIGHTS"] = "free=1,pro=4"
-    # the replica cold-start cut: a scaled-up replica re-serves from
-    # the persistent compile cache instead of cold XLA compiles
-    cache_dir = tempfile.mkdtemp(prefix="chaos-ramp-cache-")
-    os.environ["MXNET_COMPILE_CACHE_DIR"] = cache_dir
+    # the replica cold-start cut needs nothing here: every replica's
+    # entry point turns on the persistent compile cache, so a scaled-up
+    # replica re-serves from cache instead of cold XLA compiles
 
     from mxnet_tpu import serving
     from mxnet_tpu.serving.errors import (DeadlineInfeasibleError,
@@ -1359,7 +1357,7 @@ def scenario_ramp(args):
                    "cooldown_s": 2.0, "interval_ms": 250.0,
                    "ema_alpha": 0.5})
     print("chaos-ramp: starting 1 replica under a chip budget of %d "
-          "(compiling decode programs, cache=%s)" % (budget, cache_dir))
+          "(compiling decode programs)" % (budget,))
     fleet.start()
     ok = True
     stop = threading.Event()

@@ -28,6 +28,10 @@ import socket
 import subprocess
 import sys
 
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from mxnet_tpu.context import (chip_visibility_env,  # noqa: E402
+                               must_place_children)
+
 
 def _reserve_ports(n):
     """Base port with n CONSECUTIVE bindable ports (server shard i listens
@@ -107,6 +111,15 @@ def main():
     if not args.command:
         ap.error("no command given")
 
+    # one process per chip: worker i sees chip i, or the first worker
+    # would take every chip of the host; more workers than chips is
+    # refused here, before anything starts
+    chip_env = [{}] * args.num_workers
+    if must_place_children(os.environ):
+        base = _reserve_ports(args.num_workers)
+        chip_env = [chip_visibility_env(wid, base + wid)
+                    for wid in range(args.num_workers)]
+
     # a lost bind race (another process grabbed a probed port between the
     # probe and the server's bind) is detectable — the server dies before
     # accepting — and retryable with a fresh range
@@ -126,9 +139,12 @@ def main():
             # servers first (workers block connecting until they're up)
             for sid in range(args.num_servers):
                 env = dict(base_env)
+                # a server aggregates on the host: held off the chips,
+                # which belong to the workers
                 env.update({"DMLC_ROLE": "server",
                             "DMLC_SERVER_ID": str(sid),
-                            "DMLC_SERVER_PORT": str(port + sid)})
+                            "DMLC_SERVER_PORT": str(port + sid),
+                            "JAX_PLATFORMS": "cpu"})
                 procs.append(subprocess.Popen(
                     [sys.executable, "-c",
                      "import mxnet_tpu as mx;"
@@ -147,6 +163,7 @@ def main():
                 env = dict(base_env)
                 env.update({"DMLC_ROLE": "worker",
                             "DMLC_WORKER_ID": str(wid)})
+                env.update(chip_env[wid])
                 workers.append(subprocess.Popen(args.command, env=env))
             rc = 0
             for w in workers:
