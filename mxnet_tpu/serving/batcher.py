@@ -194,7 +194,6 @@ class DynamicBatcher:
                 self._workers[model] = t
                 t.start()
             self._cond.notify_all()
-        self.metrics.observe_queue_depth(model, depth + 1)
         return req.future
 
     def queue_depth(self, model):

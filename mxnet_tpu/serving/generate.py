@@ -86,6 +86,7 @@ future.  Fault sites: ``decode.step`` (one decode iteration),
 from __future__ import annotations
 
 import collections
+import itertools
 import logging
 import threading
 import time
@@ -107,21 +108,29 @@ from .errors import (BadRequestError, DeadlineExceededError, QueueFullError,
                      ServerClosedError, ServingError, SessionResetError)
 from .kvcache import (CacheOOM, PageAllocator, PrefixCache, pack_session,
                       pages_for, unpack_session)
-from .metrics import ServingMetrics
+from .metrics import ModelMetrics, ServingMetrics
+from ..profiler import span
 
-__all__ = ["DecodeEngine"]
+__all__ = ["DecodeEngine", "next_rid"]
 
 _log = logging.getLogger(__name__)
+
+#: one identifier per request of this process, on every span of the request
+next_rid = itertools.count(1).__next__
+
+# a request's phases on the engine's clock (_Request.mark)
+_QUEUE, _PREFILL, _DECODE = range(3)
 
 
 class _Request:
     __slots__ = ("prompt", "max_new", "deadline", "future", "session",
                  "resume", "t_enqueue", "prefix", "ttft_recorded",
                  "prompt_tokens", "started", "tier", "tenant", "rank",
-                 "vstart")
+                 "vstart", "rid", "t_mark", "phases")
 
     def __init__(self, prompt, max_new, deadline, session, resume,
-                 tier="latency", tenant=None, rank=0, vstart=0.0):
+                 tier="latency", tenant=None, rank=0, vstart=0.0, rid=None):
+        self.rid = next_rid() if rid is None else rid
         self.prompt = list(prompt)
         self.prompt_tokens = len(self.prompt)  # as submitted (reporting)
         self.max_new = int(max_new)
@@ -129,7 +138,8 @@ class _Request:
         self.session = session
         self.resume = bool(resume)
         self.future = Future()
-        self.t_enqueue = time.perf_counter()
+        self.t_enqueue = self.t_mark = time.perf_counter()
+        self.phases = [0.0, 0.0, 0.0]     # seconds queued, in prefill, decode
         self.prefix = []                  # tokens emitted before a preempt
         self.ttft_recorded = False
         self.started = False              # future already marked running
@@ -144,6 +154,13 @@ class _Request:
 
     def expired(self, now):
         return self.deadline is not None and now > self.deadline
+
+    def mark(self, phase, now):
+        """The phase the request was in since the last mark ends at
+        ``now``.  Every second since submit goes to one of the three, so
+        they add up to the request's total, preemptions included."""
+        self.phases[phase] += now - self.t_mark
+        self.t_mark = now
 
 
 class _Slot:
@@ -410,6 +427,12 @@ class DecodeEngine:
         self._seq = 0                 # admission counter (owner ids)
         self._prefill_rr = 0
         self.steps = 0
+        # the current step's seconds by part and its prefill launches,
+        # plain floats of the worker thread, handed to the metrics in one
+        # call at the step's end (_step, _lap, _device_wait)
+        self._parts = dict.fromkeys(ModelMetrics.STEP_PARTS, 0.0)
+        self._inner_s = 0.0
+        self._prefill_launches = 0
 
         # prefix caching + session migration + role specialization
         self.role = str(role if role is not None
@@ -511,12 +534,17 @@ class DecodeEngine:
         return True
 
     def submit(self, prompt, max_new_tokens=16, *, deadline_ms=None,
-               session=None, resume=False, tier=None, tenant=None):
+               session=None, resume=False, tier=None, tenant=None,
+               rid=None):
         """Enqueue one generation; returns a Future resolving to
         ``{"tokens", "finish_reason", "session", "prompt_tokens",
-        "completion_tokens"}``.  Shed/deadline/reset failures rethrow
-        typed at ``future.result()`` (or synchronously at submit for
-        admission-time refusals), matching the batcher's contract.
+        "completion_tokens", "timing_ms"}``.  Shed/deadline/reset
+        failures rethrow typed at ``future.result()`` (or synchronously
+        at submit for admission-time refusals), matching the batcher's
+        contract.  ``timing_ms`` is the request on the engine's clock:
+        ``queue_wait`` + ``prefill`` + ``decode`` = ``total``.  ``rid``
+        (default: :func:`next_rid`) is on every span of the request; a
+        caller that opened a span of its own passes the one it used.
 
         ``tier``/``tenant`` drive SLO-aware admission (see
         :class:`~.autoscale.SLOPolicy`): latency-tier requests queue
@@ -593,7 +621,7 @@ class DecodeEngine:
                     "expired); restart generation" % (session,))
             req = _Request(prompt, max_new, deadline, session, resume,
                            tier=tier, tenant=tenant, rank=rank,
-                           vstart=vstart)
+                           vstart=vstart, rid=rid)
             # priority insertion: latency tier ahead of bulk, weighted-
             # fair tags within a tier (all-default traffic appends)
             i = len(self._queue)
@@ -613,8 +641,10 @@ class DecodeEngine:
 
     # -- worker -----------------------------------------------------------
     def _run(self):
+        # the worker is always inside engine.wait_for_work or engine.step:
+        # a device-idle gap in a trace finds one of the two over it
         while True:
-            with self._cond:
+            with span("engine.wait_for_work"), self._cond:
                 while (not self._stopping and not self._queue
                        and not self._ops and not self._pipe
                        and not any(s.active for s in self._slots)):
@@ -633,31 +663,71 @@ class DecodeEngine:
                 time.sleep(0.01)
 
     def _step(self):
+        t0 = t = time.perf_counter()
+        parts = self._parts
+        parts["device_wait"] = parts["retire_host"] = self._inner_s = 0.0
+        self._prefill_launches = 0
+        with span("engine.step", step=self.steps):
+            with span("engine.ops"):
+                if self._pipe:
+                    with self._cond:
+                        ops = bool(self._ops)
+                    if ops:
+                        # worker ops (session imports/exports) read or
+                        # rewrite the page pools and tables; run them
+                        # against retired, fully materialized state
+                        self._flush_pipe()
+                self._drain_ops()
+            with span("engine.expire"):
+                self._expire_queued(t0)
+                with self._cond:
+                    self._expire_sessions_locked()
+            t = self._lap("ops", t)
+            with span("engine.admit") as sp:
+                sp.set_metadata(admitted=self._admit())
+            t = self._lap("admit", t)
+            with span("engine.prefill") as sp:
+                self._prefill_phase()
+                sp.set_metadata(launches=self._prefill_launches)
+            t = self._lap("prefill_host", t)
+            with span("engine.decode"):
+                self._decode()
+            t = self._lap("launch", t)
+            with span("engine.account"):
+                kv = self.alloc.stats()
+                self.metrics.observe_kv_cache(
+                    self.name, kv["used_pages"], kv["total_pages"],
+                    kv["shared_pages"], kv["leaked_pages"],
+                    tokens_resident=self._tokens_resident(),
+                    bytes_per_token=kv.get("kv_bytes_per_token", 0.0))
+                self.metrics.observe_fn_cache(self.name,
+                                              _decoder.fn_cache_stats())
+            t = self._lap("account", t)
+            self.steps += 1
+            self.metrics.observe_engine_step(self.name, t - t0, parts,
+                                             self._prefill_launches)
+
+    def _lap(self, part, t_from):
+        """End one top-level part of the step: its wall since ``t_from``
+        less the blocking reads and the retire bookkeeping that ran
+        inside it, which are parts of their own (``device_wait``,
+        ``retire_host``).  The parts are contiguous, so they add up to
+        the step's wall.  Returns the time now."""
         now = time.perf_counter()
-        if self._pipe:
-            with self._cond:
-                ops = bool(self._ops)
-            if ops:
-                # worker ops (session imports/exports) read or rewrite
-                # the page pools and tables; run them against retired,
-                # fully materialized state
-                self._flush_pipe()
-        self._drain_ops()
-        self._expire_queued(now)
-        with self._cond:
-            self._expire_sessions_locked()
-        self._admit()
-        self._prefill_phase()
-        self._decode()
-        kv = self.alloc.stats()
-        self.metrics.observe_kv_cache(
-            self.name, kv["used_pages"], kv["total_pages"],
-            kv["shared_pages"], kv["leaked_pages"],
-            tokens_resident=self._tokens_resident(),
-            bytes_per_token=kv.get("kv_bytes_per_token", 0.0))
-        self.metrics.observe_fn_cache(self.name,
-                                      _decoder.fn_cache_stats())
-        self.steps += 1
+        parts = self._parts
+        inner = parts["device_wait"] + parts["retire_host"]
+        parts[part] = now - t_from - (inner - self._inner_s)
+        self._inner_s = inner
+        return now
+
+    def _device_wait(self, read, value, name="engine.device_wait", **args):
+        """``read(value)``, a blocking read of a device result, under a
+        span; its wall is the step's ``device_wait``."""
+        t0 = time.perf_counter()
+        with span(name, **args):
+            out = read(value)
+        self._parts["device_wait"] += time.perf_counter() - t0
+        return out
 
     def _drain_ops(self):
         """Run queued worker-thread ops (session imports/exports).  Only
@@ -1042,29 +1112,37 @@ class DecodeEngine:
         return None
 
     def _admit(self):
+        """Fill free slots from the queue's head; returns how many
+        requests took a slot."""
+        admitted = 0
         if self.static_batching:
             # batch-level scheduling (the A/B baseline): a new batch
             # forms only once the previous one fully drained, then fills
             # every slot it can in one go
             with self._cond:
                 if any(s.active for s in self._slots):
-                    return
+                    return admitted
         while True:
             with self._cond:
                 if not self._queue:
-                    return
+                    return admitted
                 slot = self._free_slot()
                 if slot is None:
-                    return
+                    return admitted
                 req = self._queue[0]
                 sess = (self._sessions.get(req.session)
                         if req.session is not None else None)
                 if sess is not None and sess.busy:
-                    return  # head-of-line: continuation waits for its turn
+                    # head-of-line: continuation waits for its turn
+                    return admitted
                 self._queue.popleft()
             self.slo.on_dispatch(req.vstart)
-            if not self._activate(slot, req, sess):
-                return
+            with span("request.admit", rid=req.rid, slot=slot.idx,
+                      waited_ms=(time.perf_counter() - req.t_mark) * 1e3):
+                go_on = self._activate(slot, req, sess)
+            admitted += slot.req is req
+            if not go_on:
+                return admitted
 
     def _activate(self, slot, req, sess):
         """Place ``req`` into ``slot``; returns False when admission must
@@ -1160,6 +1238,7 @@ class DecodeEngine:
         slot.flight = 0
         slot.predraft = None
         slot.t_last = time.perf_counter()
+        req.mark(_QUEUE, slot.t_last)
         slot.admit_seq = self._seq
         slot.cacheable = (self.prefix_cache is not None
                           and (sess is None or replaying))
@@ -1355,10 +1434,14 @@ class DecodeEngine:
         if slot.state == "decode" and slot.pending is not None:
             recompute.append(slot.pending)
         new = _Request(recompute, req.max_new, req.deadline, req.session,
-                       False)
+                       False, rid=req.rid)
         new.future = req.future
         new.started = req.started
         new.t_enqueue = req.t_enqueue
+        # back to waiting from now on; what it spent so far stays counted
+        req.mark(_PREFILL if slot.state == "prefill" else _DECODE,
+                 new.t_mark)
+        new.phases = req.phases
         new.prefix = req.prefix + slot.generated
         new.ttft_recorded = req.ttft_recorded
         new.prompt_tokens = req.prompt_tokens
@@ -1396,13 +1479,17 @@ class DecodeEngine:
         n = min(self.prefill_chunk, len(slot.prompt) - slot.done)
         if not self._ensure_pages(slot, n):
             return
-        chunk = slot.prompt[slot.done:slot.done + n]
-        padded = onp.zeros(self.prefill_chunk, onp.int32)
-        padded[:n] = chunk
-        row = jnp.asarray(self._tables[slot.idx])
-        self._kp, self._vp, next_tok, _ = self._prefill_fn(
-            self.params, self._kp, self._vp, jnp.asarray(padded),
-            jnp.int32(slot.pos), jnp.int32(n), row)
+        rid = slot.req.rid
+        with span("engine.prefill_launch", rid=rid, slot=slot.idx,
+                  tokens=n, pos=slot.pos):
+            chunk = slot.prompt[slot.done:slot.done + n]
+            padded = onp.zeros(self.prefill_chunk, onp.int32)
+            padded[:n] = chunk
+            row = jnp.asarray(self._tables[slot.idx])
+            self._kp, self._vp, next_tok, _ = self._prefill_fn(
+                self.params, self._kp, self._vp, jnp.asarray(padded),
+                jnp.int32(slot.pos), jnp.int32(n), row)
+        self._prefill_launches += 1
         slot.history.extend(chunk)
         slot.pos += n
         slot.done += n
@@ -1419,8 +1506,10 @@ class DecodeEngine:
             # never read (and a hitter forks it copy-on-write anyway).
             self.prefix_cache.insert(list(slot.history),
                                      self.alloc.pages(slot.owner))
-        tok = int(next_tok)
+        tok = self._device_wait(int, next_tok, "engine.first_token_read",
+                                rid=rid)
         now = time.perf_counter()
+        slot.req.mark(_PREFILL, now)
         if not slot.req.ttft_recorded:
             self.metrics.observe_ttft(self.name, now - slot.req.t_enqueue)
             slot.req.ttft_recorded = True
@@ -1483,7 +1572,7 @@ class DecodeEngine:
             self.params, self._kp, self._vp, jnp.array(tokens),
             jnp.array(positions), self._tables_device(),
             self._active_device(active))
-        next_tokens = onp.asarray(next_tokens)
+        next_tokens = self._device_wait(onp.asarray, next_tokens)
         now = time.perf_counter()
         self._t_force_end = now
         for s in live:
@@ -1566,48 +1655,49 @@ class DecodeEngine:
         live = [s for s in live if s.state == "decode"]
         if not live:
             return False
-        st = self._stage_tokens
-        sp = self._stage_positions
-        sa = self._stage_active
-        carry = self._stage_carry
-        st.fill(0)
-        sp.fill(0)
-        sa.fill(False)
-        carry.fill(False)
-        chain = False
-        for s in live:
-            sp[s.idx] = s.pos + s.flight
-            sa[s.idx] = True
-            if s.flight > 0:
-                carry[s.idx] = True  # input is the in-flight step's output
-                chain = True
+        with span("engine.decode_launch", lanes=len(live), depth=depth0):
+            st = self._stage_tokens
+            sp = self._stage_positions
+            sa = self._stage_active
+            carry = self._stage_carry
+            st.fill(0)
+            sp.fill(0)
+            sa.fill(False)
+            carry.fill(False)
+            chain = False
+            for s in live:
+                sp[s.idx] = s.pos + s.flight
+                sa[s.idx] = True
+                if s.flight > 0:
+                    carry[s.idx] = True  # input is the in-flight step's output
+                    chain = True
+                else:
+                    st[s.idx] = s.pending
+            # reused staging buffers: upload must COPY (jnp.array) — the
+            # dispatch reads host memory asynchronously and we refill these
+            # arrays before it completes
+            if chain and onp.array_equal(carry, sa):
+                # steady state: every live lane chains, so the combine is
+                # the identity — feed the in-flight output straight in.
+                # Inactive lanes see that step's garbage rows, which the
+                # active mask already quarantines (scratch-page writes,
+                # outputs nobody retires).
+                tokens = self._pipe[-1].out
+            elif chain:
+                tokens = _decoder.make_token_combine(self.slots)(
+                    self._pipe[-1].out, jnp.array(st), jnp.array(carry))
             else:
-                st[s.idx] = s.pending
-        # reused staging buffers: upload must COPY (jnp.array) — the
-        # dispatch reads host memory asynchronously and we refill these
-        # arrays before it completes
-        if chain and onp.array_equal(carry, sa):
-            # steady state: every live lane chains, so the combine is
-            # the identity — feed the in-flight output straight in.
-            # Inactive lanes see that step's garbage rows, which the
-            # active mask already quarantines (scratch-page writes,
-            # outputs nobody retires).
-            tokens = self._pipe[-1].out
-        elif chain:
-            tokens = _decoder.make_token_combine(self.slots)(
-                self._pipe[-1].out, jnp.array(st), jnp.array(carry))
-        else:
-            tokens = jnp.array(st)
-        t0 = time.perf_counter()
-        if self._t_force_end is not None:
-            # with lanes in flight the host gap is hidden (0 by
-            # construction); an empty pipe exposes it like sync mode
-            self.metrics.observe_host_gap(
-                self.name,
-                0.0 if depth0 else max(0.0, t0 - self._t_force_end))
-        self._kp, self._vp, out, _ = self._decode_fn(
-            self.params, self._kp, self._vp, tokens, jnp.array(sp),
-            self._tables_device(), self._active_device(sa))
+                tokens = jnp.array(st)
+            t0 = time.perf_counter()
+            if self._t_force_end is not None:
+                # with lanes in flight the host gap is hidden (0 by
+                # construction); an empty pipe exposes it like sync mode
+                self.metrics.observe_host_gap(
+                    self.name,
+                    0.0 if depth0 else max(0.0, t0 - self._t_force_end))
+            self._kp, self._vp, out, _ = self._decode_fn(
+                self.params, self._kp, self._vp, tokens, jnp.array(sp),
+                self._tables_device(), self._active_device(sa))
         fl = _Flight("plain", out, t0, [(s, s.admit_seq) for s in live],
                      set(s.owner for s in live))
         for s in live:
@@ -1628,12 +1718,21 @@ class DecodeEngine:
             if not self._pipe:
                 return
             fl = self._pipe.popleft()
+        parts = self._parts
+        t0, waited = time.perf_counter(), parts["device_wait"]
+        with span("engine.retire", lanes=len(fl.lanes)):
+            self._retire_flight(fl)
+        # the bookkeeping alone: the blocking read is device_wait's
+        parts["retire_host"] += (time.perf_counter() - t0
+                                 - (parts["device_wait"] - waited))
+
+    def _retire_flight(self, fl):
         try:
             faults.check("engine.retire")
         except Exception as e:
             self._retire_poisoned(fl, e)
             return
-        toks = jax.device_get(fl.out)
+        toks = self._device_wait(jax.device_get, fl.out)
         now = time.perf_counter()
         self._t_force_end = now
         self.metrics.count(self.name, "deferred_reads_total")
@@ -1769,7 +1868,7 @@ class DecodeEngine:
         except Exception as e:
             self._retire_poisoned(fl, e)
             return None
-        out = jax.device_get(fl.out)
+        out = self._device_wait(jax.device_get, fl.out)
         now = time.perf_counter()
         self._t_force_end = now
         self.metrics.count(self.name, "deferred_reads_total")
@@ -2118,7 +2217,7 @@ class DecodeEngine:
             self.params, self._kp, self._vp, jnp.asarray(tokens),
             jnp.asarray(positions), jnp.asarray(n_valid),
             self._tables_device(), jnp.asarray(active))
-        out = onp.asarray(out)
+        out = self._device_wait(onp.asarray, out)
         now = time.perf_counter()
         self.metrics.observe_verify(self.name, now - t0)
         self.metrics.count(self.name, "spec_verify_steps_total")
@@ -2218,8 +2317,13 @@ class DecodeEngine:
 
     def _finish(self, slot, reason):
         req = slot.req
+        with span("request.finish", rid=req.rid, reason=reason):
+            self._finish_request(slot, req, reason)
+
+    def _finish_request(self, slot, req, reason):
         tokens = req.prefix + slot.generated
         now = time.perf_counter()
+        req.mark(_PREFILL if slot.state == "prefill" else _DECODE, now)
         if req.session is not None:
             if self.role == "prefill" and self._handoff(slot, req):
                 pass  # pages shipped to the store for a decode replica
@@ -2243,7 +2347,8 @@ class DecodeEngine:
             self._free_owner(slot.owner)
             self._spec_release(slot.owner, slot.owner)
         self.metrics.count(self.name, "sequences_completed_total")
-        self.metrics.observe_generate_done(self.name, now - req.t_enqueue)
+        total = now - req.t_enqueue
+        self.metrics.observe_generate_done(self.name, total, *req.phases)
         self.slo.observe_served(1)  # feeds the drain-rate estimator
         self._clear(slot)
         req.future.set_result({
@@ -2252,6 +2357,9 @@ class DecodeEngine:
             "session": req.session,
             "prompt_tokens": req.prompt_tokens,
             "completion_tokens": len(tokens),
+            "timing_ms": {k: round(v * 1e3, 3) for k, v in zip(
+                ("queue_wait", "prefill", "decode", "total"),
+                req.phases + [total])},
         })
         with self._cond:
             self._cond.notify_all()

@@ -1,16 +1,13 @@
 """Serving observability: per-model counters + latency histograms.
 
-Three surfaces over one set of measurements:
-- ``ServingMetrics.snapshot()`` — a JSON-able dict (the scrapeable stats
-  endpoint): counters, p50/p95/p99 for queue-wait / device / end-to-end
-  latency, and the batch-occupancy ratio (items served / bucket slots
-  dispatched — how full the padded XLA programs actually run).
-- ``mxnet_tpu.profiler`` aggregate table: each dispatched batch feeds
-  ``record_op_stat("serving::<model>", device_s)`` when
-  ``set_config(aggregate_stats=True)`` is active, so serving shows up in
-  ``profiler.dumps(format='table')`` next to operator dispatches.
-- chrome-trace counters: queue depth and batch occupancy ride
-  ``profiler.record_counter`` while a trace is recording.
+One always-on registry, ``ServingMetrics.snapshot()`` — a JSON-able dict
+(the scrapeable stats endpoint, ``/v1/stats``; ``/metrics`` renders the same
+numbers as Prometheus text): counters, p50/p95/p99 for queue-wait / device /
+end-to-end latency, the batch-occupancy ratio (items served / bucket slots
+dispatched — how full the padded XLA programs actually run), and for a
+decode engine the phases of a request and of an engine step.  What happens
+*when* is the trace's business, not this module's: the engine and the HTTP
+handler write ``profiler.span`` events onto the device trace's clock.
 """
 from __future__ import annotations
 
@@ -18,7 +15,6 @@ import threading
 import time
 
 from .. import config as _config
-from .. import profiler
 
 #: ring-buffer size per histogram — recent-window percentiles, O(1) memory
 _RESERVOIR = 2048
@@ -99,14 +95,30 @@ class ModelMetrics:
                 # page-store refusals (PR 20): the engine kept the
                 # session local instead of shipping it — degrade paths
                 # are counted, never silent
-                "store_rejected_total", "store_over_budget_total")
+                "store_rejected_total", "store_over_budget_total",
+                # the scheduler's work (PR 25): engine steps taken and
+                # prefill-chunk programs launched in them
+                "engine_steps_total", "prefill_launches_total")
+
+    #: parts of an engine step, host wall seconds summed over the window
+    #: (DecodeEngine._step): ``ops`` worker ops + expiry, ``admit``,
+    #: ``prefill_host`` the prefill phase less its blocking read,
+    #: ``launch`` forming and dispatching the decode batch,
+    #: ``device_wait`` every blocking read of a device result,
+    #: ``retire_host`` a retired step's bookkeeping, ``account`` the
+    #: gauges at the step's end.  They add up to ``step``.
+    STEP_PARTS = ("ops", "admit", "prefill_host", "launch", "device_wait",
+                  "retire_host", "account")
 
     def __init__(self):
         self.counters = dict.fromkeys(self.COUNTERS, 0)
-        self.queue_wait = LatencyHistogram()   # submit -> dispatch
+        self.queue_wait = LatencyHistogram()   # submit -> dispatch/admit
         self.device = LatencyHistogram()       # model execution per batch
         self.total = LatencyHistogram()        # submit -> response
         self.batch_size = LatencyHistogram()   # items per dispatched batch
+        # the HTTP handler's own wall of a generate request: body read to
+        # response written, less the engine's submit -> finish
+        self.http_self = LatencyHistogram()
         # generation-path histograms (empty unless a DecodeEngine serves
         # this model): TTFT = submit -> first generated token; inter-token
         # = gap between consecutive tokens of one sequence; decode_step =
@@ -114,6 +126,16 @@ class ModelMetrics:
         self.ttft = LatencyHistogram()
         self.inter_token = LatencyHistogram()
         self.decode_step = LatencyHistogram()
+        # a finished request on the engine's clock, beside queue_wait:
+        # admission -> first token, first token -> finish (a preempted
+        # request's repeated phases are summed, so the three add up to
+        # its ``total``)
+        self.request_prefill = LatencyHistogram()
+        self.request_decode = LatencyHistogram()
+        # the scheduler: host wall of one engine step, and the window's
+        # seconds in each of its parts
+        self.engine_step = LatencyHistogram()
+        self.phase_s = dict.fromkeys(("step",) + self.STEP_PARTS, 0.0)
         # speculative decoding: tokens EMITTED per decode step (a wide
         # verify can land several — this is where >1 token/step shows),
         # plus the draft/verify latency split
@@ -151,6 +173,7 @@ class ModelMetrics:
             "device": self.device.snapshot(),
             "total": self.total.snapshot(),
             "batch_size": self.batch_size.snapshot(),
+            "http_self": self.http_self.snapshot(),
         }
         steps = self.counters["decode_steps_total"]
         if steps or self.counters["sequences_total"]:
@@ -160,6 +183,15 @@ class ModelMetrics:
                 "ttft": self.ttft.snapshot(),
                 "inter_token": self.inter_token.snapshot(),
                 "decode_step": self.decode_step.snapshot(),
+                "request_prefill": self.request_prefill.snapshot(),
+                "request_decode": self.request_decode.snapshot(),
+                "engine_step": self.engine_step.snapshot(),
+                # host_self: what the step would still take with an
+                # infinitely fast device
+                "phase_s": dict(
+                    {k: round(v, 6) for k, v in self.phase_s.items()},
+                    host_self=round(self.phase_s["step"]
+                                    - self.phase_s["device_wait"], 6)),
                 "tokens_per_s": round(self.tokens_per_s, 2),
                 # fraction of dispatched decode-slot work that produced a
                 # real token — the continuous-batching win over static
@@ -231,12 +263,6 @@ class ServingMetrics:
         with self._lock:
             self._model(name).counters[counter] += n
 
-    def observe_queue_depth(self, name, depth):
-        # chrome-trace counter only — depth is an instantaneous gauge,
-        # the snapshot reports it live from the batcher instead
-        profiler.record_counter("serving::%s::queue_depth" % name,
-                                depth=depth)
-
     def observe_batch(self, name, batch, bucket, device_s):
         """One dispatched batch: ``batch`` real items padded up to
         ``bucket`` slots, executed in ``device_s`` seconds."""
@@ -247,12 +273,6 @@ class ServingMetrics:
             m.counters["bucket_slots_total"] += bucket
             m.device.observe(device_s)
             m.batch_size.observe(float(batch))
-        # profiler hooks outside the lock: the aggregate table is the
-        # MXAggregateProfileStatsPrint analog, the counter the trace view
-        if profiler._AGG["enabled"]:
-            profiler.record_op_stat("serving::%s" % name, device_s)
-        profiler.record_counter("serving::%s::batch" % name,
-                                batch=batch, bucket=bucket)
 
     def observe_request(self, name, queue_wait_s, total_s):
         with self._lock:
@@ -262,19 +282,41 @@ class ServingMetrics:
             m.total.observe(total_s)
 
     # -- generation (continuous-batching decode engine) -------------------
-    def observe_generate_done(self, name, total_s):
-        """One completed generation (queue-wait is folded into TTFT, so
-        only the end-to-end latency histogram is fed here)."""
+    def observe_generate_done(self, name, total_s, queue_wait_s,
+                              prefill_s, decode_s):
+        """One completed generation on the engine's clock: submit to
+        finish, and its three phases (waiting for a slot, admission to
+        first token, first token to finish), which add up to it."""
         with self._lock:
             m = self._model(name)
             m.counters["responses_total"] += 1
             m.total.observe(total_s)
+            m.queue_wait.observe(queue_wait_s)
+            m.request_prefill.observe(prefill_s)
+            m.request_decode.observe(decode_s)
+
+    def observe_http_self(self, name, self_s):
+        """The HTTP handler's own share of one generate request."""
+        with self._lock:
+            self._model(name).http_self.observe(self_s)
+
+    def observe_engine_step(self, name, wall_s, parts, prefill_launches):
+        """One engine step: its host wall, the seconds of each of
+        ``ModelMetrics.STEP_PARTS`` in it, and the prefill-chunk programs
+        it launched."""
+        with self._lock:
+            m = self._model(name)
+            m.counters["engine_steps_total"] += 1
+            m.counters["prefill_launches_total"] += prefill_launches
+            m.engine_step.observe(wall_s)
+            phase_s = m.phase_s
+            phase_s["step"] += wall_s
+            for part in m.STEP_PARTS:
+                phase_s[part] += parts[part]
 
     def observe_ttft(self, name, ttft_s):
         with self._lock:
             self._model(name).ttft.observe(ttft_s)
-        profiler.record_counter("serving::%s::ttft" % name,
-                                ttft_ms=ttft_s * 1e3)
 
     def observe_inter_token(self, name, gap_s):
         with self._lock:
@@ -294,11 +336,6 @@ class ServingMetrics:
             rate = new_tokens / max(wall_s, 1e-9)
             m.tokens_per_s = (rate if m.tokens_per_s == 0.0
                               else 0.9 * m.tokens_per_s + 0.1 * rate)
-        if profiler._AGG["enabled"]:
-            profiler.record_op_stat("serving::%s::decode_step" % name,
-                                    device_s)
-        profiler.record_counter("serving::%s::decode" % name,
-                                active=active, tokens=new_tokens)
 
     def observe_host_gap(self, name, gap_s):
         """Device-idle gap before one decode launch: wall time since the
@@ -323,9 +360,6 @@ class ServingMetrics:
         """Wall time of one whole-batch wide verify launch."""
         with self._lock:
             self._model(name).verify_step.observe(verify_s)
-        if profiler._AGG["enabled"]:
-            profiler.record_op_stat("serving::%s::verify_step" % name,
-                                    verify_s)
 
     def observe_decode_launches(self, name, stats):
         """Static launch census of the engine's decode step (see
@@ -334,9 +368,6 @@ class ServingMetrics:
         path; tests and bench rows assert on it."""
         with self._lock:
             self._model(name).decode_launches = dict(stats)
-        profiler.record_counter(
-            "serving::%s::decode_launches" % name,
-            launches=stats.get("launches_per_step", 0))
 
     def observe_decode_collectives(self, name, stats):
         """Static per-step collective census of a tensor-parallel
@@ -346,10 +377,6 @@ class ServingMetrics:
         not of traffic."""
         with self._lock:
             self._model(name).decode_collectives = dict(stats)
-        cols = stats.get("collectives") or {}
-        profiler.record_counter(
-            "serving::%s::decode_collectives" % name,
-            all_reduce=cols.get("all-reduce", 0))
 
     def observe_fn_cache(self, name, stats):
         """Decode/prefill program-cache gauges ({size, cap, compiles,
@@ -372,8 +399,6 @@ class ServingMetrics:
                 kv["tokens_resident"] = int(tokens_resident)
             if bytes_per_token is not None:
                 kv["bytes_per_token"] = float(bytes_per_token)
-        profiler.record_counter("serving::%s::kv_cache" % name,
-                                used_pages=used_pages)
 
     def snapshot(self):
         """Scrapeable stats: {model: {counters, batch_occupancy,

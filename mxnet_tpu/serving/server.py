@@ -31,6 +31,7 @@ from __future__ import annotations
 import json
 import re
 import threading
+import time
 from concurrent.futures import TimeoutError as FutureTimeoutError
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
@@ -39,7 +40,9 @@ import numpy as onp
 from .batcher import DynamicBatcher
 from .errors import (BadRequestError, DeadlineExceededError,
                      ModelNotFoundError, ServingError)
+from .generate import next_rid
 from .registry import ModelRegistry
+from ..profiler import span
 
 __all__ = ["ModelServer"]
 
@@ -143,10 +146,20 @@ class ModelServer:
                     self._reply_error(e)
 
             def do_POST(self):
+                t0 = time.perf_counter()
                 try:
                     n = int(self.headers.get("Content-Length", 0))
                     raw = self.rfile.read(n) if n else b""
-                    self._reply(*server._handle_post(self.path, raw))
+                    status, payload = server._handle_post(self.path, raw)
+                    self._reply(status, payload)
+                    timing = payload.get("timing_ms")
+                    if timing:
+                        # a generation: this thread's own share of it,
+                        # body read to response written less the
+                        # engine's submit -> finish
+                        server.metrics.observe_http_self(
+                            payload["model"], time.perf_counter() - t0
+                            - timing["total"] / 1e3)
                 except ServingError as e:
                     self._reply_error(e)
                 except Exception as e:
@@ -220,8 +233,10 @@ class ModelServer:
             return self._handle_admin(path, raw_body)
         m = _GENERATE_RE.match(path)
         if m or path == "/v1/generate":
-            return self._handle_generate(m.group("name") if m else None,
-                                         raw_body)
+            rid = next_rid()
+            with span("http.generate", rid=rid):
+                return self._handle_generate(
+                    m.group("name") if m else None, raw_body, rid)
         m = _PREDICT_RE.match(path)
         if not m:
             raise ModelNotFoundError("no route %r" % (path,))
@@ -257,7 +272,7 @@ class ModelServer:
         return 200, {"predictions": preds, "model": name,
                      "version": served.version}
 
-    def _handle_generate(self, name, raw_body):
+    def _handle_generate(self, name, raw_body, rid):
         """``POST /v1/models/<name>:generate`` (or ``/v1/generate`` with
         ``"model"`` in the body): autoregressive generation through the
         model's continuous-batching decode engine.
@@ -268,7 +283,8 @@ class ModelServer:
         session as the router ``affinity_key`` so the fleet returns to
         the replica that holds them); ``resume=true`` makes a missing
         session a typed 409 ``session_reset`` instead of a silent
-        fresh start."""
+        fresh start.  ``rid`` names the request on its spans, the
+        caller's ``http.generate`` and the engine's."""
         try:
             body = json.loads(raw_body.decode() or "{}")
         except ValueError as e:
@@ -295,11 +311,13 @@ class ModelServer:
             session=body.get("session"),
             resume=resume,
             tier=body.get("tier"),
-            tenant=body.get("tenant"))
+            tenant=body.get("tenant"),
+            rid=rid)
         timeout = (float(deadline_ms) / 1e3 + 1.0 if deadline_ms is not None
                    else self.request_timeout_s)
         try:
-            result = future.result(timeout=timeout)
+            with span("http.wait_engine"):
+                result = future.result(timeout=timeout)
         except FutureTimeoutError:
             raise DeadlineExceededError("no response within %.1fs" % timeout)
         result = dict(result)
@@ -416,7 +434,7 @@ class ModelServer:
             if occ is not None:
                 lines.append("mxtpu_serving_batch_occupancy{%s} %g"
                              % (labels, occ))
-            for hist in ("queue_wait", "device", "total"):
+            for hist in ("queue_wait", "device", "total", "http_self"):
                 h = stats.get(hist) or {}
                 for k, v in sorted(h.items()):
                     if k == "count":
@@ -427,12 +445,16 @@ class ModelServer:
             if gen:
                 for hist in ("ttft", "inter_token", "decode_step",
                              "tokens_per_step", "host_gap_us",
-                             "dispatch_depth"):
+                             "dispatch_depth", "request_prefill",
+                             "request_decode", "engine_step"):
                     for k, v in sorted((gen.get(hist) or {}).items()):
                         if k == "count":
                             continue
                         lines.append("mxtpu_serving_%s_%s{%s} %g"
                                      % (hist, k, labels, v))
+                for k, v in sorted((gen.get("phase_s") or {}).items()):
+                    lines.append("mxtpu_serving_engine_phase_seconds"
+                                 "{%s,phase=\"%s\"} %g" % (labels, k, v))
                 # (kv_tokens_resident / kv_bytes_per_token ride the
                 # kv_cache loop below — one sample per name)
                 for gauge in ("tokens_per_s", "decode_occupancy",
