@@ -76,6 +76,8 @@ class Sizes:
                            hidden_size=128, num_heads=4, max_length=128)
             self.prompts, self.new_tokens = (9, 16, 23, 30, 37, 48), 8
             self.check_prompt, self.check_decode = 21, 6
+            self.pool = dict(page_size=4, slots=4, pages_per_seq=8,
+                             chunk=8, width=3)
             self.lenet_iters = 12
             self.bert, self.bert_batch, self.bert_len = "bert_tiny", 4, 16
             self.bert_steps = 4
@@ -85,6 +87,10 @@ class Sizes:
             self.prompts, self.new_tokens = (128, 200, 256, 320, 384,
                                              512), 64
             self.check_prompt, self.check_decode = 200, 24
+            # the benchmark's serving cells (chipbench/configs/
+            # gpt2-small-serve.json): 32 slots x 1024 positions
+            self.pool = dict(page_size=16, slots=32, pages_per_seq=64,
+                             chunk=256, width=4)
             self.lenet_iters = 30
             self.bert, self.bert_batch, self.bert_len = "bert_base", 32, 128
             self.bert_steps = 6
@@ -156,9 +162,8 @@ def paged_logits(engine, prompt, n_decode):
         decode = decoder.make_decode_step(cfg, S, sharding=engine.sharding)
     check(decoder.fn_cache_stats()["compiles"] == compiles,
           "the logits check built a program the engine did not")
-    shape = (cfg.num_layers, cfg.num_kv_heads, engine.alloc.total_pages, S,
-             cfg.head_dim)
-    kp, vp = jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32)
+    kp, vp = (decoder.fresh_pool(cfg, engine.alloc.total_pages, S)
+              for _ in range(2))
     plan = decoder.tp_plan(cfg, engine.sharding)
     if plan is not None:
         kp, vp = plan.place_kv(kp), plan.place_kv(vp)
@@ -242,6 +247,82 @@ def describe_engine(engine, st):
     log("engine: last_path: paged_attention=%s bias_gelu=%s fused_cell=%s"
         % (paged_attention.last_path, epilogue.last_path,
            fused_cell.last_path))
+
+
+def pool_leg(sz, lm):
+    """The decode-step, prefill-chunk and verify programs compiled at the
+    benchmark's serving geometry: each takes the K and V pools as they
+    lie and hands them back in the same buffers.  Fails if a compiled
+    program holds an operation whose result is a whole pool other than
+    the in-place update (a relayout or a copy of the pool: two thirds of
+    the device's time before PR 26), or if a pool is not aliased input
+    to output.  Prints the bytes one pool takes on the device."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import profiler
+    from mxnet_tpu.models import decoder
+    g = sz.pool
+    cfg, S, B, pps = lm.config, g["page_size"], g["slots"], g["pages_per_seq"]
+    total = B * pps + 1
+    log("== pool: %d pages of %d, %d slots, chunk %d, verify width %d"
+        % (total, S, B, g["chunk"], g["width"]))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), lm.jax_params())
+    pool = jax.eval_shape(lambda: decoder.fresh_pool(cfg, total, S))
+    active = jax.ShapeDtypeStruct((B,), jnp.bool_)
+    programs = {
+        "decode": (decoder.make_decode_step(cfg, S),
+                   (i32(B), i32(B), i32(B, pps), active)),
+        "prefill": (decoder.make_prefill_chunk(cfg, S, g["chunk"]),
+                    (i32(g["chunk"]), i32(), i32(), i32(pps))),
+        "verify": (decoder.make_verify_step(cfg, S, g["width"]),
+                   (i32(B, g["width"]), i32(B), i32(B), i32(B, pps),
+                    active)),
+    }
+    dims = ",".join(str(d) for d in pool.shape)
+    whole = re.compile(r"^\s*(?:ROOT )?%?(\S+) = f32\[" + dims
+                       + r"\]\S* ([\w\-]+)\((.*)$")
+    in_place = ("parameter", "get-tuple-element", "bitcast",
+                "dynamic-update-slice")
+    for name, (fn, rest) in programs.items():
+        text = fn.inner.lower(params, pool, pool, *rest).compile().as_text()
+        # a computation's name -> the operation at its root
+        roots, current = {}, None
+        for line in text.splitlines():
+            m = re.match(r"^%?(\S+) \(.*\) -> .* \{$", line)
+            if m:
+                current = m.group(1)
+            m = re.match(r"^\s*ROOT %?\S+ = \S+ ([\w\-]+)\(", line)
+            if m and current:
+                roots[current] = m.group(1)
+        bad = []
+        for line in text.splitlines():
+            m = whole.match(line)
+            if not m or m.group(2) in in_place:
+                continue
+            called = re.search(r"calls=%?([\w.\-]+)", m.group(3))
+            if (m.group(2) == "fusion" and called
+                    and roots.get(called.group(1)) == "dynamic-update-slice"):
+                continue
+            bad.append("%s = %s" % (m.group(1), m.group(2)))
+        check(not bad, "pool: %s holds whole-pool operations that are not "
+              "the in-place update: %s" % (name, ", ".join(bad[:6])))
+        alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+        check(alias and "{0}:" in alias.group(1) and "{1}:" in alias.group(1),
+              "pool: %s does not alias both pools input to output (%s)"
+              % (name, alias.group(1) if alias else None))
+        log("pool: %s: no whole-pool operation but the in-place update; "
+            "K and V aliased input to output" % name)
+    dev = jax.devices()[0]
+    before = profiler.device_memory_stats(dev)["bytes_in_use"]
+    held = jax.block_until_ready(decoder.fresh_pool(cfg, total, S))
+    log("pool: one f32%s pool takes %d bytes on the device (%d unpadded)"
+        % (list(held.shape), profiler.device_memory_stats(dev)[
+            "bytes_in_use"] - before, held.size * 4))
 
 
 def check_decode_program(sz, st):
@@ -592,6 +673,7 @@ def run(args):
         finally:
             cache_line("after " + name)
 
+    leg("pool", pool_leg, sz, lm)
     served = leg("serving", serving_leg, sz, lm)
     leg("lenet", lenet_leg, sz)
     leg("bert", bert_leg, sz)

@@ -30,6 +30,7 @@ hot-swap path replaces the whole model, never mutates weights in place.
 from __future__ import annotations
 
 import collections
+import functools
 import threading
 import warnings
 from typing import NamedTuple
@@ -55,7 +56,9 @@ warnings.filterwarnings(
 
 __all__ = ["DecoderConfig", "CausalLM", "full_forward", "make_decode_step",
            "make_decode_step_fused", "make_prefill_chunk",
-           "make_verify_step", "make_token_combine",
+           "make_verify_step", "make_token_combine", "PoolProgram",
+           "pool_shape", "fresh_pool", "rows_from_pages", "pages_from_rows",
+           "fork_page", "put_pages",
            "fn_cache_stats", "decode_launch_stats",
            "verify_launch_stats", "decode_collective_stats", "tp_plan",
            "TPPlan", "causal_lm", "decoder_tiny", "decoder_tiny_lm",
@@ -210,17 +213,189 @@ def _layer_tail(x, att_merged, lp, axis=None):
 
 
 # ---------------------------------------------------------------------------
-# KV page access — fp arrays or int8 QPages behind one set of helpers
+# the KV pool: one row per token, written in place
 # ---------------------------------------------------------------------------
-def _kv_append(pages, li, wp, ws, val):
-    """Scatter new tokens into layer ``li``'s pages.
+# The step programs hold K and V as ``(L, P, S, KVH * D)``: layer, page,
+# slot in the page, then ONE row of all KV heads per token.  A row is a
+# whole number of 128-lane tiles wherever ``KVH * D`` is a multiple of
+# 128, so the device's own default layout of that shape is the plain
+# row-major one with no padding: the pool enters and leaves a program
+# as it lies, a token's K is one contiguous row to write, and a page one
+# contiguous slab to gather.  The pages form ``(L, KVH, P, S, D)`` is
+# what the paged-attention op, the fused decode cell and the wire format
+# of a migrated session speak; at head_dim 64 a TPU lays THAT shape out
+# with the page axis on the lanes, which no scatter or gather can use,
+# and XLA then relays the whole pool out on the way into and out of
+# every launch (PERF.md, PR 26: two thirds of the device's time).  A
+# layout pinned on the program (``jax.experimental.layout.Format``)
+# would keep the pages form, but jax 0.9.0's persistent compilation
+# cache hands back an executable that has lost the pin (same finding).
+def pool_shape(cfg, total_pages, page_size):
+    """Shape of one pool in rows form: (L, P, S, KVH * D)."""
+    return (cfg.num_layers, int(total_pages), int(page_size),
+            cfg.num_kv_heads * cfg.head_dim)
+
+
+def fresh_pool(cfg, total_pages, page_size, kv_dtype="float32"):
+    """A zeroed pool in rows form: a float32 array, or an int8
+    :class:`~..ops.pallas.paged_attention.QPages` (codes in rows form,
+    per-(layer, head, page) scales).  Scales start at ONE so untouched
+    pages (the scratch page, inactive slots) dequantize to exact zeros,
+    like the float pool."""
+    shape = pool_shape(cfg, total_pages, page_size)
+    if str(kv_dtype) == "int8":
+        return _paged.QPages(
+            q=jnp.zeros(shape, jnp.int8),
+            s=jnp.ones((cfg.num_layers, cfg.num_kv_heads, shape[1]),
+                       jnp.float32))
+    return jnp.zeros(shape, jnp.float32)
+
+
+def _codes(pool):
+    """The (L, ..) array of a pool that holds the tokens."""
+    return pool.q if isinstance(pool, _paged.QPages) else pool
+
+
+def _with_codes(pool, codes):
+    if isinstance(pool, _paged.QPages):
+        return _paged.QPages(q=codes, s=pool.s)
+    return codes
+
+
+@jax.jit
+def rows_from_pages(pool):
+    """Pages form (L, KVH, P, S, D) -> rows form (L, P, S, KVH * D);
+    int8 scales pass through."""
+    a = _codes(pool)
+    L, kvh, P, S, d = a.shape
+    return _with_codes(
+        pool, a.transpose(0, 2, 3, 1, 4).reshape(L, P, S, kvh * d))
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def pages_from_rows(pool, num_kv_heads):
+    """Rows form -> pages form (the inverse of :func:`rows_from_pages`)."""
+    a = _codes(pool)
+    L, P, S, width = a.shape
+    a = a.reshape(L, P, S, num_kv_heads, width // num_kv_heads)
+    return _with_codes(pool, a.transpose(0, 3, 1, 2, 4))
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def fork_page(pool, src, dst):
+    """Copy page ``src`` over page ``dst`` in every layer of a rows-form
+    pool, in place: the device half of a copy-on-write fork."""
+    return _paged.copy_page(pool, src, dst)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def put_pages(pool, idx, blob):
+    """Write ``blob`` (a rows-form pool of ``len(idx)`` pages) over the
+    pages ``idx`` of ``pool``, in place: a migrated session's import."""
+    def write(i, codes):
+        page = jax.lax.dynamic_slice_in_dim(_codes(blob), i, 1, axis=1)
+        return jax.lax.dynamic_update_slice(codes, page, (0, idx[i], 0, 0))
+    codes = jax.lax.fori_loop(0, idx.shape[0], write, _codes(pool))
+    if isinstance(pool, _paged.QPages):
+        return _paged.QPages(q=codes, s=pool.s.at[:, :, idx].set(blob.s))
+    return codes
+
+
+class PoolProgram:
+    """What the ``make_*`` factories return: the jitted step program
+    ``inner(params, k_pool, v_pool, *rest) -> (k_pool, v_pool, *out)``
+    (pools donated, input aliased to output) behind one call that takes
+    the pools in either form and hands them back in rows form.  The
+    engine holds rows form, which is what ``inner`` takes
+    (``takes_rows``; the fused decode cell still takes pages form), so
+    its launches convert nothing.  A caller that built a pages-form pool
+    by hand (the benchmark's reference check, older tests) has it
+    converted on the way into its first call, and feeds back what it
+    was handed from then on: the same executable either way."""
+
+    def __init__(self, inner, num_kv_heads, takes_rows=True):
+        self.inner = inner
+        self.num_kv_heads = int(num_kv_heads)
+        self.takes_rows = bool(takes_rows)
+
+    def _as(self, pool, rows):
+        if (_codes(pool).ndim == 4) == rows:
+            return pool
+        # waited for, so one conversion's pool-sized temporary is gone
+        # before the next conversion or the program asks for memory
+        return jax.block_until_ready(
+            rows_from_pages(pool) if rows
+            else pages_from_rows(pool, self.num_kv_heads))
+
+    def __call__(self, params, k_pool, v_pool, *rest):
+        k_pool, v_pool, *out = self.inner(
+            params, self._as(k_pool, self.takes_rows),
+            self._as(v_pool, self.takes_rows), *rest)
+        return (self._as(k_pool, True), self._as(v_pool, True), *out)
+
+
+def _write_rows(pool, li, wp, ws, rows):
+    """``pool[li, wp[t], ws[t], :] = rows[t]`` for every token ``t`` in
+    order (a later duplicate wins).  Each token's page is read, its row
+    laid over it, and the page written back whole with one
+    ``dynamic_update_slice`` of (1, 1, S, KVH * D): whole tiles for
+    float rows and int8 codes alike, in place in a donated pool.
+    wp/ws: any shape; rows: ``wp.shape + (KVH, D)``."""
+    S, width = pool.shape[2:]
+    wp, ws = wp.reshape(-1), ws.reshape(-1)
+    rows = rows.reshape(-1, 1, 1, 1, width)
+    slot = jnp.arange(S, dtype=jnp.int32)[None, None, :, None]
+
+    def write(t, pool):
+        at = (li, wp[t], 0, 0)
+        old = jax.lax.dynamic_slice(pool, at, (1, 1, S, width))
+        return jax.lax.dynamic_update_slice(
+            pool, jnp.where(slot == ws[t], rows[t], old), at)
+    return jax.lax.fori_loop(0, wp.shape[0], write, pool)
+
+
+def _write_chunk(pool, li, page_row, pos0, n_valid, rows):
+    """The same write for a prefill chunk, a page at a time: ``rows``
+    (T, KVH, D) are consecutive positions ``pos0 ..`` of the sequence
+    whose page table is ``page_row``, the first ``n_valid`` of them
+    real.  Each page the chunk touches is read, the chunk's valid rows
+    laid over it, and written back whole: T/S + 1 updates of a page
+    instead of T of a row.  A page slot with no valid row targets the
+    scratch page, which gets its own content back: padded tokens change
+    nothing anywhere."""
+    S, width = pool.shape[2:]
+    T = rows.shape[0]
+    n_pages = -(-T // S) + 1            # a chunk may start mid-page
+    off = pos0 % S
+    grid = jax.lax.dynamic_update_slice(
+        jnp.zeros((n_pages * S, width), rows.dtype),
+        rows.reshape(T, width), (off, 0)).reshape(n_pages, 1, 1, S, width)
+    t = jnp.arange(n_pages * S, dtype=jnp.int32) - off
+    live = ((t >= 0) & (t < n_valid)).reshape(n_pages, S)
+    slot = pos0 // S + jnp.arange(n_pages, dtype=jnp.int32)
+    pid = jnp.where(live.any(axis=1),
+                    page_row[jnp.clip(slot, 0, page_row.shape[0] - 1)], 0)
+
+    def write(k, pool):
+        at = (li, pid[k], 0, 0)
+        old = jax.lax.dynamic_slice(pool, at, (1, 1, S, width))
+        return jax.lax.dynamic_update_slice(
+            pool, jnp.where(live[k][None, None, :, None], grid[k], old), at)
+    return jax.lax.fori_loop(0, n_pages, write, pool)
+
+
+def _kv_append(pages, li, wp, ws, val, chunk=None):
+    """Write new tokens into layer ``li`` of a rows-form pool.
 
     ``wp``/``ws``: (..., T) int write page/slot per token; the LAST axis
     indexes CONSECUTIVE positions of one sequence (decode passes T=1 by
     expanding a singleton axis; prefill passes the chunk; verify the
-    spec window).  ``val``: ``ws.shape + (KVH, D)``.
+    spec window).  ``val``: ``ws.shape + (KVH, D)``.  ``chunk``:
+    prefill's ``(page_row, pos0, n_valid)``, which lets the write go a
+    page at a time (:func:`_write_chunk`); wp/ws then describe the same
+    targets token by token.
 
-    fp pages scatter directly.  int8 :class:`~..ops.pallas.
+    fp pools write the rows as they are.  int8 :class:`~..ops.pallas.
     paged_attention.QPages` quantize with the page-start scale latch: a
     token landing at page slot 0 sets its page's per-head scale to
     ``amax/127``; every other token reuses the scale its page start
@@ -228,8 +403,14 @@ def _kv_append(pages, li, wp, ws, val):
     it (``src = t - ws``), from the scales pool otherwise.  Duplicate
     scale writes within a window all carry the same value, so the
     scatter is order-independent."""
+    if chunk is None:
+        def write(pool, rows):
+            return _write_rows(pool, li, wp, ws, rows)
+    else:
+        def write(pool, rows):
+            return _write_chunk(pool, li, *chunk, rows)
     if not isinstance(pages, _paged.QPages):
-        return pages.at[li, :, wp, ws, :].set(val)
+        return write(pages, val)
     amax = jnp.abs(val.astype(jnp.float32)).max(axis=-1)   # ws.shape+(KVH,)
     fresh = jnp.where(amax > 0, amax / 127.0, 1.0).astype(jnp.float32)
     old = pages.s[li, :, wp]                               # ws.shape+(KVH,)
@@ -240,24 +421,56 @@ def _kv_append(pages, li, wp, ws, val):
     snew = jnp.where((src >= 0)[..., None], start_fresh, old)
     codes = jnp.clip(jnp.round(val.astype(jnp.float32) / snew[..., None]),
                      -127, 127).astype(jnp.int8)
-    return _paged.QPages(q=pages.q.at[li, :, wp, ws, :].set(codes),
+    return _paged.QPages(q=write(pages.q, codes),
                          s=pages.s.at[li, :, wp].set(snew))
 
 
-def _kv_layer(pages, li):
-    """Layer ``li``'s page view — NamedTuple-safe (QPages[li] would
-    index the tuple fields, not the layer axis)."""
+def _gather_kv(pages, li, tables, num_kv_heads):
+    """Contiguous fp32 per-sequence context from layer ``li`` of a
+    rows-form pool: the pages of ``tables`` (B, pages_per_seq) gathered
+    as whole slabs straight out of the pool (slicing the layer out first
+    makes XLA copy, and convert, the whole slab to read a few pages of
+    it), then set head-major -> (B, KVH, pages_per_seq * S, D), the view
+    :func:`~..ops.pallas.paged_attention.attend_ctx` and a non-paged
+    decoder share.  int8 codes are dequantized by their page's latched
+    per-head scale, value for value what ``gather_pages_deq`` gives for
+    the pages form."""
+    rows = _codes(pages)
+    b, pps = tables.shape
+    S = rows.shape[2]
+    ctx = rows[li, tables].reshape(b, pps, S, num_kv_heads, -1)
+    ctx = ctx.transpose(0, 3, 1, 2, 4)                 # (B,KVH,pps,S,D)
     if isinstance(pages, _paged.QPages):
-        return _paged.QPages(q=pages.q[li], s=pages.s[li])
-    return pages[li]
+        sg = jnp.swapaxes(pages.s[li][:, tables], 0, 1)  # (B,KVH,pps)
+        ctx = ctx.astype(jnp.float32) * sg[..., None, None]
+    return ctx.reshape(b, num_kv_heads, pps * S, -1)
 
 
-def _gather_kv(pages_li, tables):
-    """Contiguous fp32 per-sequence context from one layer's pages —
-    plain gather for fp, gather + dequant for int8."""
-    if isinstance(pages_li, _paged.QPages):
-        return _paged.gather_pages_deq(pages_li.q, pages_li.s, tables)
-    return _paged.gather_pages(pages_li, tables)
+def _head_major(pages, li, num_kv_heads):
+    """Layer ``li`` of a float rows-form pool as the paged-attention
+    kernel wants it: (KVH, P, S, D).  A copy of the layer's slab, so
+    only the kernel path takes it."""
+    P, S = pages.shape[1:3]
+    return pages[li].reshape(P, S, num_kv_heads, -1).transpose(2, 0, 1, 3)
+
+
+def _decode_attention(q, k_pages, v_pages, li, lengths, tables,
+                      num_kv_heads):
+    """One query token per sequence against layer ``li`` of the pools:
+    jax's Pallas kernel where :mod:`~..ops.pallas.paged_attention`
+    selects it (float pools, head_dim a multiple of 128 on a TPU; the
+    interpreter under ``MXNET_PAGED_ATTENTION=interpret``), else the
+    gather and the masked f32 softmax the op's reference is made of."""
+    if (not isinstance(k_pages, _paged.QPages)
+            and _paged.kernel_mode_for(q.shape[-1]) is not None):
+        return _paged.paged_attention(
+            q, _head_major(k_pages, li, num_kv_heads),
+            _head_major(v_pages, li, num_kv_heads), lengths, tables)
+    _paged.last_path = "xla"
+    return _paged.attend_ctx(
+        q, _gather_kv(k_pages, li, tables, num_kv_heads),
+        _gather_kv(v_pages, li, tables, num_kv_heads), lengths,
+        1.0 / (q.shape[-1] ** 0.5))
 
 
 # ---------------------------------------------------------------------------
@@ -300,8 +513,8 @@ class TPPlan:
     Holds the local (per-shard) decode geometry — heads, KV heads and
     FFN width divided by tp; ``units``/``head_dim`` stay FULL because
     activations are replicated — plus the PartitionSpecs for the param
-    pytree and the paged KV slabs (KV-head axis over tp, the Pope et al.
-    layout SNIPPETS.md [3] uses).  Built via :func:`tp_plan`.
+    pytree and the KV pools (each shard holds its own KV heads of every
+    token row).  Built via :func:`tp_plan`.
     """
 
     def __init__(self, sharding, cfg, quant=None, kv_int8=False):
@@ -319,21 +532,23 @@ class TPPlan:
         #: the GEMM param leaves to QuantW8/QuantW4 spec structures
         self.quant = quant
         self.kv_int8 = bool(kv_int8)
-        # engine page layout (L, KVH, total_pages, S, D): KV heads over
-        # tp; the per-layer kernel view drops L -> P("tp", None, None,
-        # None) exactly as the ISSUE/SNIPPETS layout reads
+        # the pool in rows form (L, P, S, KVH * D): heads lie contiguous
+        # in a row, so splitting the row over tp gives each shard its
+        # own KV heads (the Pope et al. layout SNIPPETS.md [3] uses);
+        # the pages form the fused cell takes splits the KVH axis
+        self.kv_rows_spec = P(None, None, None, "tp")
         self.kv_spec = P(None, "tp", None, None, None)
         if self.kv_int8:
-            # int8 pages: codes pool shards like fp pages; the parallel
-            # scales pool (L, KVH, P) shards along the same KV-head axis
-            self.kv_in_spec = _paged.QPages(q=self.kv_spec,
+            # int8: the codes shard like fp rows; the parallel scales
+            # pool (L, KVH, P) shards along its KV-head axis
+            self.kv_in_spec = _paged.QPages(q=self.kv_rows_spec,
                                             s=P(None, "tp", None))
             self.kv_sharding = _paged.QPages(
-                q=NamedSharding(self.mesh, self.kv_spec),
+                q=NamedSharding(self.mesh, self.kv_rows_spec),
                 s=NamedSharding(self.mesh, P(None, "tp", None)))
         else:
-            self.kv_in_spec = self.kv_spec
-            self.kv_sharding = NamedSharding(self.mesh, self.kv_spec)
+            self.kv_in_spec = self.kv_rows_spec
+            self.kv_sharding = NamedSharding(self.mesh, self.kv_rows_spec)
 
     def leaf_spec(self, kind, shape):
         """PartitionSpec for one layer-param leaf (``wq``/``b2``/…),
@@ -399,20 +614,21 @@ class TPPlan:
         return jax.tree.unflatten(treedef, placed)
 
     def place_kv(self, pages):
-        """(Re)pin a page array to the KV-head sharding — used at init
-        and after host-side page mutations (install/import) that may
-        have produced a differently-placed result."""
+        """(Re)pin a rows-form pool to the KV-head sharding — used at
+        init and after host-side page mutations (install/import) that
+        may have produced a differently-placed result."""
         return jax.device_put(pages, self.kv_sharding)
 
-    def wrap(self, fn, n_rest, n_out_rest):
-        """jit(shard_map(fn)) with the plan's layout: params + KV pages
-        sharded, every other operand/result replicated; pages donated so
-        the cache stays in place across steps."""
+    def wrap(self, fn, n_rest, n_out_rest, pages_form=False):
+        """jit(shard_map(fn)) with the plan's layout: params + KV pools
+        sharded (rows form, or the pages form the fused cell takes),
+        every other operand/result replicated; pools donated so the
+        cache stays in place across steps."""
         from jax.sharding import PartitionSpec as P
         rep = P()
-        in_specs = ((self.param_specs(), self.kv_in_spec, self.kv_in_spec)
-                    + (rep,) * n_rest)
-        out_specs = (self.kv_in_spec, self.kv_in_spec) + (rep,) * n_out_rest
+        kv = self.kv_spec if pages_form else self.kv_in_spec
+        in_specs = (self.param_specs(), kv, kv) + (rep,) * n_rest
+        out_specs = (kv, kv) + (rep,) * n_out_rest
         smapped = jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                                 out_specs=out_specs, check_vma=False)
         return jax.jit(smapped, donate_argnums=(1, 2))
@@ -496,10 +712,13 @@ def make_decode_step(cfg, page_size, sharding=None, quant=None,
     program with an fp one even at identical geometry.
 
     fn(params, k_pages, v_pages, tokens, positions, page_tables, active)
-      k_pages/v_pages: (layers, KVH, total_pages, page_size, head_dim)
-                       (donated: updated in place on accelerators);
-                       with kv_dtype="int8" a QPages (codes, scales)
-                       pytree of the same page geometry
+      k_pages/v_pages: the pools (:func:`fresh_pool`), rows form
+                       (layers, total_pages, page_size, KVH * head_dim),
+                       donated and updated in place; with
+                       kv_dtype="int8" a QPages (codes, scales) pytree.
+                       The pages form (layers, KVH, total_pages,
+                       page_size, head_dim) is taken too; what comes
+                       back is rows form (:class:`PoolProgram`)
       tokens:     (B,) int32 — this step's input token per slot
       positions:  (B,) int32 — cache index the token lands at
       page_tables:(B, pages_per_seq) int32
@@ -536,26 +755,31 @@ def _build_decode_step(cfg, page_size, plan=None):
         lengths = jnp.where(active, positions + 1, 0).astype(jnp.int32)
         for li, lp in enumerate(params["layers"]):
             q, k, v = _qkv(x, lp, qcfg)                 # (B, H/KVH, D)
-            # advanced indices split by ':' put the batch dim first:
-            # the target block is (B, 1, KVH, D) — k/v's native layout
-            # behind a singleton token axis (each slot is its own
-            # sequence, so the scale-latch window is one token wide)
+            # a singleton token axis: each slot is its own sequence,
+            # so the scale-latch window is one token wide
             k_pages = _kv_append(k_pages, li, wp[:, None], ws[:, None],
                                  k[:, None])
             v_pages = _kv_append(v_pages, li, wp[:, None], ws[:, None],
                                  v[:, None])
-            att = _paged.paged_attention(
-                q, _kv_layer(k_pages, li), _kv_layer(v_pages, li),
-                lengths, page_tables)
+            att = _decode_attention(q, k_pages, v_pages, li, lengths,
+                                    page_tables, qcfg.num_kv_heads)
             x = _layer_tail(x, att.reshape(B, Cl), lp, axis=axis)
         logits = jnp.dot(x.astype(jnp.float32),
                          params["embed"].astype(jnp.float32).T)
         return (k_pages, v_pages,
                 jnp.argmax(logits, axis=-1).astype(jnp.int32), logits)
 
+    return _pool_program(step, cfg, plan, n_rest=4, n_out_rest=2)
+
+
+def _pool_program(fn, cfg, plan, n_rest, n_out_rest, pages_form=False):
+    """The :class:`PoolProgram` of step function ``fn``: jitted with the
+    pools donated, per shard under a TP plan."""
     if plan is None:
-        return jax.jit(step, donate_argnums=(1, 2))
-    return plan.wrap(step, n_rest=4, n_out_rest=2)
+        inner = jax.jit(fn, donate_argnums=(1, 2))
+    else:
+        inner = plan.wrap(fn, n_rest, n_out_rest, pages_form=pages_form)
+    return PoolProgram(inner, cfg.num_kv_heads, takes_rows=not pages_form)
 
 
 def make_token_combine(slots):
@@ -681,28 +905,32 @@ def _build_decode_step_fused(cfg, page_size, layer_group, mode, plan=None):
         return (k_pages, v_pages,
                 jnp.argmax(logits, axis=-1).astype(jnp.int32), logits)
 
-    if plan is None:
-        return jax.jit(step, donate_argnums=(1, 2))
-    return plan.wrap(step, n_rest=4, n_out_rest=2)
+    return _pool_program(step, cfg, plan, n_rest=4, n_out_rest=2,
+                         pages_form=True)
 
 
-def _kv_structs(cfg, page_size, total_pages, kv_dtype="float32"):
-    """ShapeDtypeStruct of one page pool (fp array or int8 QPages)."""
-    shape = (cfg.num_layers, cfg.num_kv_heads, int(total_pages),
-             int(page_size), cfg.head_dim)
+def _kv_structs(cfg, page_size, total_pages, kv_dtype="float32",
+                rows=True):
+    """ShapeDtypeStruct of one pool (fp array or int8 QPages) in the
+    form a :class:`PoolProgram`'s inner program takes."""
+    scales = (cfg.num_layers, cfg.num_kv_heads, int(total_pages))
+    if rows:
+        shape = pool_shape(cfg, total_pages, page_size)
+    else:
+        shape = scales + (int(page_size), cfg.head_dim)
     if str(kv_dtype) == "int8":
         return _paged.QPages(
             q=jax.ShapeDtypeStruct(shape, jnp.int8),
-            s=jax.ShapeDtypeStruct(shape[:3], jnp.float32))
+            s=jax.ShapeDtypeStruct(scales, jnp.float32))
     return jax.ShapeDtypeStruct(shape, jnp.float32)
 
 
 def _decode_step_structs(params, cfg, page_size, slots, pages_per_seq,
-                         total_pages, kv_dtype="float32"):
+                         total_pages, kv_dtype="float32", rows=True):
     """ShapeDtypeStruct argument tuple of one decode step (census
     tracing/lowering without touching real buffers).  Quantized param
     leaves (QuantW8/QuantW4 pytrees) map leaf-wise like raw arrays."""
-    kp = _kv_structs(cfg, page_size, total_pages, kv_dtype)
+    kp = _kv_structs(cfg, page_size, total_pages, kv_dtype, rows)
     return (jax.tree.map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params),
             kp, kp,
@@ -740,8 +968,9 @@ def decode_launch_stats(params, cfg, page_size, slots, pages_per_seq,
                               kv_dtype=kv_dtype)
         n_groups = cfg.num_layers
     args = _decode_step_structs(params, cfg, S, slots, pages_per_seq,
-                                total_pages, kv_dtype=kv_dtype)
-    jaxpr = jax.make_jaxpr(fn)(*args)
+                                total_pages, kv_dtype=kv_dtype,
+                                rows=fn.takes_rows)
+    jaxpr = jax.make_jaxpr(fn.inner)(*args)
     launches = _fused.count_launches(jaxpr)
     pallas = _fused.count_pallas_calls(jaxpr)
     return {"fused": bool(fused), "layer_groups": int(n_groups),
@@ -776,8 +1005,9 @@ def decode_collective_stats(params, cfg, page_size, slots, pages_per_seq,
         fn = make_decode_step(cfg, S, sharding=sharding, quant=quant,
                               kv_dtype=kv_dtype)
     args = _decode_step_structs(params, cfg, S, slots, pages_per_seq,
-                                total_pages, kv_dtype=kv_dtype)
-    census = _shardcfg.collective_census(fn.lower(*args))
+                                total_pages, kv_dtype=kv_dtype,
+                                rows=fn.takes_rows)
+    census = _shardcfg.collective_census(fn.inner.lower(*args))
     return {"mesh": sharding.describe(), "tp": plan.tp,
             "fused": bool(fused), "collectives": census}
 
@@ -828,14 +1058,16 @@ def _build_prefill_chunk(cfg, page_size, chunk, plan=None):
              + params["pos"][jnp.clip(idx, 0, cfg.max_length - 1)])
         wp = jnp.where(valid, page_row[idx // S], 0)
         ws = jnp.where(valid, idx % S, 0)
+        chunk = (page_row, pos0, n_valid)
+        kvh = qcfg.num_kv_heads
         for li, lp in enumerate(params["layers"]):
             q, k, v = _qkv(x, lp, qcfg)                 # (P, H/KVH, D)
-            k_pages = _kv_append(k_pages, li, wp, ws, k)
-            v_pages = _kv_append(v_pages, li, wp, ws, v)
+            k_pages = _kv_append(k_pages, li, wp, ws, k, chunk)
+            v_pages = _kv_append(v_pages, li, wp, ws, v, chunk)
             # gather THIS sequence's pages (prefix + the chunk just
-            # written) back to a contiguous (C, KVH, D) view
-            kc = _gather_kv(_kv_layer(k_pages, li), page_row[None])[0]
-            vc = _gather_kv(_kv_layer(v_pages, li), page_row[None])[0]
+            # written) back to a contiguous (KVH, C, D) view
+            kc = _gather_kv(k_pages, li, page_row[None], kvh)[0]
+            vc = _gather_kv(v_pages, li, page_row[None], kvh)[0]
             kr = jnp.repeat(kc, g, axis=0)              # (H, C, D)
             vr = jnp.repeat(vc, g, axis=0)
             qf = q.astype(jnp.float32).swapaxes(0, 1) * scale  # (H, P, D)
@@ -855,9 +1087,7 @@ def _build_prefill_chunk(cfg, page_size, chunk, plan=None):
         return (k_pages, v_pages,
                 jnp.argmax(last_logits).astype(jnp.int32), last_logits)
 
-    if plan is None:
-        return jax.jit(prefill, donate_argnums=(1, 2))
-    return plan.wrap(prefill, n_rest=4, n_out_rest=2)
+    return _pool_program(prefill, cfg, plan, n_rest=4, n_out_rest=2)
 
 
 def make_verify_step(cfg, page_size, width, sharding=None, quant=None,
@@ -927,8 +1157,8 @@ def _build_verify_step(cfg, page_size, width, plan=None):
             q, k, v = _qkv(x, lp, qcfg)                 # (B, W, H/KVH, D)
             k_pages = _kv_append(k_pages, li, wp, ws, k)
             v_pages = _kv_append(v_pages, li, wp, ws, v)
-            kc = _gather_kv(_kv_layer(k_pages, li), page_tables)
-            vc = _gather_kv(_kv_layer(v_pages, li), page_tables)
+            kc = _gather_kv(k_pages, li, page_tables, qcfg.num_kv_heads)
+            vc = _gather_kv(v_pages, li, page_tables, qcfg.num_kv_heads)
             kr = jnp.repeat(kc, g, axis=1)              # (B, H, C, D)
             vr = jnp.repeat(vc, g, axis=1)
             qf = q.astype(jnp.float32).transpose(0, 2, 1, 3) * scale
@@ -948,9 +1178,7 @@ def _build_verify_step(cfg, page_size, width, plan=None):
         return (k_pages, v_pages,
                 jnp.argmax(logits, axis=-1).astype(jnp.int32))
 
-    if plan is None:
-        return jax.jit(verify, donate_argnums=(1, 2))
-    return plan.wrap(verify, n_rest=5, n_out_rest=1)
+    return _pool_program(verify, cfg, plan, n_rest=5, n_out_rest=1)
 
 
 def verify_launch_stats(params, cfg, page_size, width, slots,
@@ -976,7 +1204,7 @@ def verify_launch_stats(params, cfg, page_size, width, slots,
             jax.ShapeDtypeStruct((slots,), jnp.int32),
             jax.ShapeDtypeStruct((slots, pages_per_seq), jnp.int32),
             jax.ShapeDtypeStruct((slots,), jnp.bool_))
-    jaxpr = jax.make_jaxpr(fn)(*args)
+    jaxpr = jax.make_jaxpr(fn.inner)(*args)
     launches = _fused.count_launches(jaxpr)
     return {"width": W,
             "launches_per_step": int(launches),

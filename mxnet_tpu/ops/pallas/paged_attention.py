@@ -49,7 +49,9 @@ class QPages(NamedTuple):
     """int8 KV page pool + parallel per-(page, head) scales pool.
 
     ``q``: int8 codes, the fp page layout with the same axes —
-    ``(KVH, P, S, D)`` per layer or ``(L, KVH, P, S, D)`` stacked.
+    ``(KVH, P, S, D)`` per layer, ``(L, KVH, P, S, D)`` stacked, or the
+    token rows ``(L, P, S, KVH * D)`` the step programs hold
+    (``models.decoder``).
     ``s``: f32 scales, one per (page, kv-head) — ``(KVH, P)`` /
     ``(L, KVH, P)``; ``token ≈ q * s`` for every token in the page.
 
@@ -79,6 +81,20 @@ last_path = None
 def _mode():
     """'compiled' | 'interpret' | None (XLA reference)."""
     return kernel_mode("MXNET_PAGED_ATTENTION")
+
+
+def kernel_mode_for(head_dim):
+    """:func:`_mode` for heads of ``head_dim``: None where the compiler
+    refuses the kernel."""
+    mode = _mode()
+    if mode == "compiled" and head_dim % 128:
+        # jax's kernel blocks its (.., 1) softmax carries by head_dim, and
+        # Mosaic refuses a 64-wide block of a 1-wide array: "the last two
+        # dimensions of your block shape [must be] divisible by 8 and 128
+        # respectively, or be equal to the respective dimensions of the
+        # overall array" (v5e, PR 21).  Such heads read through the gather.
+        mode = None
+    return mode
 
 
 def _pages_per_block(pages_per_seq):
@@ -114,8 +130,9 @@ def gather_pages(pages, page_indices):
 def copy_page(pages, src, dst):
     """Duplicate one physical page: ``pages[..., dst, :, :] <-
     pages[..., src, :, :]``.  Works on any layout whose page axis is
-    third-from-last — both the kernel layout ``(KVH, P, S, D)`` and the
-    engine's stacked ``(L, KVH, P, S, D)``.  This is the device half of
+    third-from-last — the kernel layout ``(KVH, P, S, D)``, the stacked
+    ``(L, KVH, P, S, D)`` and the engine's token rows ``(L, P, S,
+    KVH * D)``.  This is the device half of
     a copy-on-write fork (``PageAllocator.fork`` is the bookkeeping
     half): the writer copies the shared page into its fresh private one
     before the first divergent write.
@@ -216,14 +233,7 @@ def paged_attention(q, k_pages, v_pages, lengths, page_indices, scale=None):
         v_ctx = gather_pages_deq(v_pages.q, v_pages.s, page_indices)
         last_path = "xla"
         return attend_ctx(q, k_ctx, v_ctx, lengths, s)
-    mode = _mode()
-    if mode == "compiled" and q.shape[-1] % 128:
-        # jax's kernel blocks its (.., 1) softmax carries by head_dim, and
-        # Mosaic refuses a 64-wide block of a 1-wide array: "the last two
-        # dimensions of your block shape [must be] divisible by 8 and 128
-        # respectively, or be equal to the respective dimensions of the
-        # overall array" (v5e, PR 21).  Such heads read through the gather.
-        mode = None
+    mode = kernel_mode_for(q.shape[-1])
     if mode is not None:
         import contextlib
         from jax.experimental.pallas import tpu as pltpu

@@ -102,7 +102,6 @@ from .. import faults
 from ..models import decoder as _decoder
 from ..ops.pallas import fused_cell as _fused_cell
 from ..ops.pallas import paged_attention as _paged
-from ..ops.pallas.paged_attention import copy_page as _copy_page
 from .autoscale import SLOPolicy
 from .errors import (BadRequestError, DeadlineExceededError, QueueFullError,
                      ServerClosedError, ServingError, SessionResetError)
@@ -325,8 +324,6 @@ class DecodeEngine:
             * (1 if self.kv_dtype == "int8" else 4),
             scale_page_bytes=(2 * cfg.num_layers * cfg.num_kv_heads * 4
                               if self.kv_dtype == "int8" else 0))
-        shape = (cfg.num_layers, cfg.num_kv_heads, total, self.page_size,
-                 cfg.head_dim)
         # tensor-parallel serving (ISSUE 13): resolve the sharding into a
         # TPPlan BEFORE building any program — params go column/row-
         # parallel, KV pages split along KV heads, and every decode/
@@ -348,8 +345,10 @@ class DecodeEngine:
             self.params = self.model.jax_params(tp=self.tp)
         if self._tp_plan is not None:
             self.params = self._tp_plan.place_params(self.params)
-        self._kp = self._place_kv(self._fresh_pool(shape))
-        self._vp = self._place_kv(self._fresh_pool(shape))
+        self._kp, self._vp = (
+            self._place_kv(_decoder.fresh_pool(
+                cfg, total, self.page_size, self.kv_dtype))
+            for _ in range(2))
         self._tables = onp.zeros((self.slots, self.pages_per_seq),
                                  onp.int32)
         self._tables_dev = None  # device copy, rebuilt when rows change
@@ -898,19 +897,19 @@ class DecodeEngine:
                 if not self._reclaim(keep=sid):
                     raise
         if n:
+            # the wire speaks pages form (L, KVH, n, S, D); the pool
+            # holds token rows: convert at the edge, write in place
             idx = jnp.asarray(onp.asarray(pages, onp.int32))
-            if ks is not None:
-                self._kp = self._place_kv(_paged.QPages(
-                    q=self._kp.q.at[:, :, idx].set(jnp.asarray(k)),
-                    s=self._kp.s.at[:, :, idx].set(jnp.asarray(ks))))
-                self._vp = self._place_kv(_paged.QPages(
-                    q=self._vp.q.at[:, :, idx].set(jnp.asarray(v)),
-                    s=self._vp.s.at[:, :, idx].set(jnp.asarray(vs))))
-            else:
-                self._kp = self._place_kv(
-                    self._kp.at[:, :, idx].set(jnp.asarray(k)))
-                self._vp = self._place_kv(
-                    self._vp.at[:, :, idx].set(jnp.asarray(v)))
+
+            def rows(codes, scales):
+                codes = jnp.asarray(codes)
+                return _decoder.rows_from_pages(
+                    codes if scales is None
+                    else _paged.QPages(q=codes, s=jnp.asarray(scales)))
+            self._kp = self._place_kv(
+                _decoder.put_pages(self._kp, idx, rows(k, ks)))
+            self._vp = self._place_kv(
+                _decoder.put_pages(self._vp, idx, rows(v, vs)))
         sess = _Session(sid, owner)
         sess.pos = int(meta["pos"])
         sess.pending = (int(meta["pending"])
@@ -932,18 +931,22 @@ class DecodeEngine:
         ks = vs = None
         if pages:
             idx = jnp.asarray(onp.asarray(pages, onp.int32))
+
+            def wire(rows):
+                # token rows (L, n, S, KVH * D) -> the wire's pages
+                # form (L, KVH, n, S, D), byte for byte what it was
+                return onp.asarray(_decoder.pages_from_rows(
+                    jnp.take(rows, idx, axis=1), cfg.num_kv_heads))
             if self.kv_dtype == "int8":
                 # quantized pages ship as-is: codes + per-page scales
-                # (format v2) — the importer scatters them back without
+                # (format v2) — the importer writes them back without
                 # a single dequant/requant round trip, so migration
                 # stays bit-identical like the fp path
-                k = onp.asarray(jnp.take(self._kp.q, idx, axis=2))
-                v = onp.asarray(jnp.take(self._vp.q, idx, axis=2))
+                k, v = wire(self._kp.q), wire(self._vp.q)
                 ks = onp.asarray(jnp.take(self._kp.s, idx, axis=2))
                 vs = onp.asarray(jnp.take(self._vp.s, idx, axis=2))
             else:
-                k = onp.asarray(jnp.take(self._kp, idx, axis=2))
-                v = onp.asarray(jnp.take(self._vp, idx, axis=2))
+                k, v = wire(self._kp), wire(self._vp)
         else:
             shape = (cfg.num_layers, cfg.num_kv_heads, 0, self.page_size,
                      cfg.head_dim)
@@ -1265,8 +1268,7 @@ class DecodeEngine:
                 # the first divergent write lands
                 old = pfx_pages[-1]
                 new = self.alloc.fork(owner, old)
-                self._kp = self._place_kv(_copy_page(self._kp, old, new))
-                self._vp = self._place_kv(_copy_page(self._vp, old, new))
+                self._fork_page(old, new)
                 self.metrics.count(self.name, "cow_forks_total")
         self.metrics.count(self.name, "sequences_total")
         self._sync_table(slot)
@@ -1359,23 +1361,20 @@ class DecodeEngine:
                         return
         self.alloc.free(owner)
 
-    def _fresh_pool(self, shape):
-        """A zeroed KV page pool: a plain fp32 array, or an int8
-        ``QPages`` (codes, per-page-per-head scales) pair.  Scales
-        initialize to ONE so untouched pages (the scratch page,
-        inactive slots) dequantize to exact zeros, like the fp pool."""
-        if self.kv_dtype == "int8":
-            return _paged.QPages(q=jnp.zeros(shape, jnp.int8),
-                                 s=jnp.ones(shape[:3], jnp.float32))
-        return jnp.zeros(shape, jnp.float32)
+    def _fork_page(self, old, new):
+        """The device half of a copy-on-write fork: page ``old`` copied
+        over page ``new`` of both pools, in place."""
+        self._kp, self._vp = (
+            self._place_kv(_decoder.fork_page(pool, old, new))
+            for pool in (self._kp, self._vp))
 
     def _place_kv(self, pages):
-        """Pin (or re-pin) a page array to the TP KV sharding.  No-op
-        when serving replicated.  Host-side page mutations (`.at[].set`
-        imports, copy-on-write forks) produce fresh arrays whose
-        placement XLA chooses freely; re-pinning keeps every update on
-        the head-sharded layout so the next decode step never inserts a
-        resharding transfer."""
+        """Pin (or re-pin) a pool to the TP KV sharding.  No-op when
+        serving replicated.  The host-side page edits (imports,
+        copy-on-write forks) are jitted programs whose results' placement
+        XLA chooses; re-pinning keeps every update on the head-sharded
+        layout so the next decode step never inserts a resharding
+        transfer."""
         if self._tp_plan is None:
             return pages
         return self._tp_plan.place_kv(pages)
@@ -2295,8 +2294,7 @@ class DecodeEngine:
                 else:
                     new = None
             if new is not None:
-                self._kp = _copy_page(self._kp, old, new)
-                self._vp = _copy_page(self._vp, old, new)
+                self._fork_page(old, new)
                 self.metrics.count(self.name, "cow_forks_total")
             # (an unforkable pool is safe anyway: the dirty offsets sit
             # past every sharer's published token count, which readers

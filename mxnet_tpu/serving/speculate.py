@@ -158,10 +158,9 @@ class DraftModelDrafter(Drafter):
         if not total:
             total = int(max_seqs) * self.pages_per_seq + 1
         self.alloc = PageAllocator(total, self.page_size)
-        shape = (self.cfg.num_layers, self.cfg.num_kv_heads, total,
-                 self.page_size, self.cfg.head_dim)
-        self._kp = jnp.zeros(shape, jnp.float32)
-        self._vp = jnp.zeros(shape, jnp.float32)
+        self._kp, self._vp = (
+            _decoder.fresh_pool(self.cfg, total, self.page_size)
+            for _ in range(2))
         self._pos = {}   # owner -> confirmed tokens in the draft cache
         self._decode_fn = _decoder.make_decode_step(self.cfg,
                                                     self.page_size)

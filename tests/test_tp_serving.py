@@ -278,9 +278,11 @@ def test_tp_engine_fused_decode_parity(eight_devices, lm, monkeypatch):
 def test_tp_engine_kv_pages_head_sharded(eight_devices, lm):
     eng = make_engine(lm, sharding=tp_config())
     try:
+        # the pool holds one row of all KV heads per token: splitting
+        # the row over tp gives each shard its own heads
         for pages in (eng._kp, eng._vp):
             spec = pages.sharding.spec
-            assert tuple(spec)[:2] == (None, "tp"), spec
+            assert tuple(spec) == (None, None, None, "tp"), spec
     finally:
         assert eng.stop()
 
