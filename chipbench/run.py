@@ -13,9 +13,10 @@ everything before it is for the reader.  Without a TPU, or with fewer chips
 than the cell asks for, the run exits non-zero and prints no result.
 
 ``--override '<json>'`` merges values into the configuration (``"config"``),
-the traffic mix (``"traffic"``) or allows the CPU (``"allow_cpu"``) for a
-rehearsal.  Such a run is not a run of the cell: it says so first and its
-result carries ``"correct": false``.
+the traffic mix (``"traffic"``), allows the CPU (``"allow_cpu"``) for a
+rehearsal, or has the kind read controls beside the program (``"controls"``:
+a list of their names).  Such a run is not a run of the cell: it says so
+first and its result carries ``"correct": false``.
 """
 import time
 
@@ -24,6 +25,7 @@ T_START = time.perf_counter()     # set-up is counted from here
 import argparse
 import importlib
 import json
+import math
 import os
 import sys
 import tempfile
@@ -176,6 +178,7 @@ def main():
         "devices": devs[:cell["chips"]], "config": config,
         "traffic": traffic, "clock": clock, "log": log,
         "resolve": resolve, "trace_dir": None,
+        "controls": tuple(override.get("controls", ())),
     }
     with tempfile.TemporaryDirectory(prefix="chipbench_trace_") as tdir:
         if args.trace:
@@ -202,6 +205,15 @@ def main():
         fullest.get("peak_bytes_in_use", 0), facts.get("window_bytes", 0)))
     log("memory of the fullest chip: %s" % json.dumps(facts["memory"],
                                                        sort_keys=True))
+    # each number compared beside its limit; what a kind compares once the
+    # window has closed runs here, after the memory peak was read, and
+    # outside both set-up and window
+    checks = list(facts.get("checks", []))
+    if "after_window" in facts:
+        t0 = time.perf_counter()
+        checks += facts.pop("after_window")()
+        log("the reference over the window's answers took %.1f s"
+            % (time.perf_counter() - t0))
     facts["end_to_end"]["setup_s"] = clock.setup_s
     log("set-up %.3f s: %s" % (clock.setup_s, ", ".join(
         "%s %.2f" % kv for kv in clock.split)))
@@ -227,8 +239,16 @@ def main():
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
     device = {"platform": devs[0].platform, "kind": kind, "count": len(devs),
               "memory_peak_bytes": int(facts["memory"]["peak_bytes"])}
+    checks.append({"name": "compilations_in_window", "value": len(in_window),
+                   "limit": 0})
+    # a number that is not finite is no JSON: it goes out as 1e30
+    checks = {c["name"]: {"value": float(c["value"])
+                          if math.isfinite(c["value"]) else 1e30,
+                          "limit": c["limit"]} for c in checks}
+    held = all(c["value"] <= c["limit"] for c in checks.values())
     result = {
-        "correct": bool(facts["correct"] and not in_window and not override),
+        "correct": bool(facts.get("correct", True) and held
+                        and facts["attempted"] > 0 and not override),
         "attempted": int(facts["attempted"]), "failed": int(facts["failed"]),
         "metrics": metrics, "device": device,
     }
@@ -237,7 +257,13 @@ def main():
         device["window_s"] = facts["trace"]["window_s"]
         result["breakdown"] = {"device_ops": facts["trace"]["device_ops"],
                                "idle_gaps": facts["trace"]["idle_gaps"]}
+    result["checks"] = checks       # last in the line, and last on stderr
     log(json.dumps(result))
+    for name, c in checks.items():
+        print("check %s: %.6g, limit %.6g -> %s"
+              % (name, c["value"], c["limit"],
+                 "held" if c["value"] <= c["limit"] else "NOT HELD"),
+              file=sys.stderr, flush=True)
     return 0
 
 

@@ -2,6 +2,7 @@
 DynamicBatcher -> DecodeEngine, asked over HTTP on localhost by the load
 generator below; the length tables; and the check of the engine's compiled
 programs against the plain float32 forward kept here."""
+import functools
 import math
 import statistics
 import threading
@@ -12,12 +13,23 @@ import numpy as np
 #: max |paged - reference| / std(reference logits) over every logit of every
 #: checked position.  The reference runs at jax.default_matmul_precision
 #: "highest"; the engine's programs run at the chip's default, where a float32
-#: matmul is one bf16 pass on the MXU, through 12 layers.  Measured on the
-#: v5e: 0.040 worst of 25 x 50257 logits (PR 21), 0.038 (PR 22), 0.008 rms.
-#: The bound is twice the worst.  A dropped or misplaced term (a bias, a
-#: residual, a position row, a page read from the wrong slot) moves logits by
-#: order 1 in these units.
+#: matmul is one bf16 pass on the MXU, through 12 layers.  Read on the v5e
+#: (PERF.md, PR 28): the engine 0.037-0.046 over some thirty seeds since PR
+#: 21; in its place the bfloat16 reference 0.043-0.054 (the engine's own
+#: precision), the int8 reference 0.108-0.155, the fp8 one 0.51-0.66.  A
+#: dropped or misplaced term (a bias, a residual, a position row, a page read
+#: from the wrong slot) moves logits by order 1 in these units.
 LOGIT_TOL = 0.08
+
+#: the widest gap by which a served token's logit may lie below the plain
+#: reference's best at its position, in units of the standard deviation of
+#: that position's logits.  Read on the v5e (PERF.md, PR 28): the engine's
+#: answers at most 0.034 over 19 seeds; a served token altered at least 0.29;
+#: the fp8 reference's first tokens 0.33-0.62 at the widest.  (The int8 and
+#: bfloat16 references read 0.03-0.07 and 0.005-0.03: a greedy token changes
+#: only where the two best logits lie closer than the noise, so this number
+#: tells a wrong token, not a precision.)
+SERVED_GAP_TOL = 0.12
 
 
 # ---------------------------------------------------------------------------
@@ -80,8 +92,8 @@ def run_load(ask, table, traffic, seconds, now=time.perf_counter,
     """Issue requests in table order, cycling, until ``seconds`` after the
     window opened, then wait at most ``drain_s`` for the answers.
 
-    ``ask(i, prompt_len, output_len)`` sends request i and returns the number
-    of tokens answered; it raises if the request failed.  In a closed loop
+    ``ask(i, prompt_len, output_len)`` sends request i and returns the tokens
+    answered; it raises if the request failed.  In a closed loop
     ``clients`` threads each issue when their last request was answered; in
     an open loop ``workers`` threads issue each request when it is due and
     time it from then.  Returns (one record per request issued, window open
@@ -112,13 +124,14 @@ def run_load(ask, table, traffic, seconds, now=time.perf_counter,
                 state["next"] += 1
                 rec = {"i": i, "prompt": table[i % len(table)][0],
                        "output": table[i % len(table)][1], "t_due": t_due,
-                       "t_sent": None, "t_done": None, "answered": None,
-                       "error": None}
+                       "t_sent": None, "t_done": None, "tokens": None,
+                       "answered": None, "error": None}
                 records.append(rec)
             wait_until(t_due)
             rec["t_sent"] = now()
             try:
-                rec["answered"] = ask(i, rec["prompt"], rec["output"])
+                rec["tokens"] = list(ask(i, rec["prompt"], rec["output"]))
+                rec["answered"] = len(rec["tokens"])
             except Exception as e:  # a failed request is counted, not raised
                 rec["error"] = "%s: %s" % (type(e).__name__, e)
             rec["t_done"] = now()
@@ -142,77 +155,203 @@ def run_load(ask, table, traffic, seconds, now=time.perf_counter,
     return out, t_open
 
 
-def summarize(records, t_open):
-    """The serving end-to-end metrics from the load generator's records."""
-    ok = [r for r in records if r["error"] is None
-          and r["answered"] == r["output"]]
+def held_token_seconds(r, t_end):
+    """Token-seconds of KV cache that an answered request held up to
+    ``t_end``: its prompt all the while, and its answer growing evenly from
+    nothing to whole, so over the first share f of its time it holds
+    prompt + f/2 of its answer on average."""
+    held = min(r["t_done"], t_end) - r["t_sent"]
+    if held <= 0:
+        return 0.0
+    f = held / (r["t_done"] - r["t_sent"])
+    return (r["prompt"] + r["answered"] * f / 2) * held
+
+
+def summarize(records, t_open, seconds):
+    """The serving end-to-end metrics from the load generator's records.
+
+    The latencies, ``attempted`` and ``failed`` are over every request issued,
+    those answered in the drain included.  The served rate is over the window
+    alone: prompt + answered tokens of the requests answered in full at or
+    before the window's close (``t_open + seconds``), over the time from the
+    window's open to the last of those answers.  Numerator and denominator
+    end on the same event, so neither the drain's tail (occupancy falling
+    from every caller to none) nor which long row is in flight at the close
+    is in the rate."""
+    ok = answered_in_full(records)
     lat = sorted(1e3 * (r["t_done"] - (r["t_from"] or r["t_due"]))
                  for r in records)
-    t_last = max((r["t_done"] for r in ok), default=t_open)
+    inside = [r for r in ok if r["t_done"] <= t_open + seconds]
+    # 0 where nothing was answered inside the window: the rates are then None
+    span = max((r["t_done"] for r in inside), default=t_open) - t_open
     late = [1e3 * (r["t_sent"] - r["t_due"]) for r in records
             if r["t_sent"] is not None]
     return {
         "attempted": len(records), "failed": len(records) - len(ok),
         "latency_p50_ms": float(np.percentile(lat, 50)) if lat else None,
         "latency_p95_ms": float(np.percentile(lat, 95)) if lat else None,
-        "served_tokens_per_s": (sum(r["prompt"] + r["answered"] for r in ok)
-                                / (t_last - t_open) if ok else None),
+        "served_tokens_per_s": (sum(r["prompt"] + r["answered"]
+                                    for r in inside) / span
+                                if span > 0 else None),
         "loadgen_late_p95_ms": (float(np.percentile(late, 95))
                                 if late else None),
-        # tokens held in the KV cache, mean over the run: each answered
-        # request holds its prompt and, on average, half its answer for as
-        # long as it took (what flops_bytes.decode_step_bytes reads)
-        "live_tokens_mean": (sum((r["prompt"] + r["answered"] / 2)
-                                 * (r["t_done"] - r["t_sent"]) for r in ok)
-                             / (t_last - t_open) if ok else None),
-        "drain_s": t_last - t_open,
+        # tokens held in the KV cache, mean over the same span (what
+        # flops_bytes.decode_step_bytes reads)
+        "live_tokens_mean": (sum(held_token_seconds(r, t_open + span)
+                                 for r in ok) / span if span > 0 else None),
+        "answered_in_window": len(inside),
+        "rate_span_s": span,
+        "drain_s": max((r["t_done"] for r in ok), default=t_open) - t_open,
     }
 
 
 # ---------------------------------------------------------------------------
 # the plain reference, and the engine's programs driven against it
 # ---------------------------------------------------------------------------
-def reference_logits(params, cfg, fed, n_rows):
-    """The decoder block as the repo defines it (post-LN, exact GELU, tied
-    output embedding, learned positions) in plain float32 jax.numpy at the
-    highest matmul precision: no kernel, no cache, O(L^2) attention.  Logits
-    of the last ``n_rows`` positions of ``fed``."""
+@functools.lru_cache(maxsize=None)
+def _plain_decoder(num_heads, num_kv_heads, head_dim, n_rows, dtype):
+    """The jitted plain forward: (params, tokens, start) -> logits of the
+    ``n_rows`` positions from ``start`` on.  One program for a given length of
+    ``tokens``, whatever ``start`` is."""
     import jax
     import jax.numpy as jnp
+    H, KVH, D = num_heads, num_kv_heads, head_dim
 
     def ln(x, g, b):
         mu = x.mean(-1, keepdims=True)
         var = jnp.square(x - mu).mean(-1, keepdims=True)
         return (x - mu) / jnp.sqrt(var + 1e-5) * g + b
 
-    def forward(p, tokens):
+    # the grids of the coarser controls, for a row scaled to [-1, 1]
+    grids = {
+        "int8": lambda a: jnp.round(a * 127.0) / 127.0,
+        "float8_e4m3fn": lambda a: (a * 448.0).astype(
+            jnp.float8_e4m3fn).astype(jnp.float32) / 448.0,
+    }
+
+    def on_grid(a):
+        """Each row of ``a`` scaled to its range and rounded to the grid."""
+        top = jnp.abs(a).max(-1, keepdims=True)
+        top = jnp.where(top > 0, top, 1.0)
+        return grids[dtype](a / top) * top
+
+    def mm(x, w):
+        """x @ w.T; under a coarser control both sides on its grid first
+        (each token's activations, each output channel's weights), as a
+        serving path that holds them in 8 bits would."""
+        return on_grid(x) @ on_grid(w).T if dtype in grids else x @ w.T
+
+    def forward(p, tokens, start):
+        p = jax.tree.map(lambda a: a.astype(
+            "float32" if dtype in grids else dtype), p)
         L = tokens.shape[0]
-        H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         x = p["embed"][tokens] + p["pos"][:L]
         causal = jnp.tril(jnp.ones((L, L), bool))
         for lp in p["layers"]:
-            q = (x @ lp["wq"].T + lp["bq"]).reshape(L, H, D)
-            k = (x @ lp["wk"].T + lp["bk"]).reshape(L, KVH, D)
-            v = (x @ lp["wv"].T + lp["bv"]).reshape(L, KVH, D)
+            q = (mm(x, lp["wq"]) + lp["bq"]).reshape(L, H, D)
+            k = (mm(x, lp["wk"]) + lp["bk"]).reshape(L, KVH, D)
+            v = (mm(x, lp["wv"]) + lp["bv"]).reshape(L, KVH, D)
             k, v = (jnp.repeat(a, H // KVH, axis=1) for a in (k, v))
             s = jnp.einsum("qhd,khd->hqk", q, k) / math.sqrt(D)
             a = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
             att = jnp.einsum("hqk,khd->qhd", a, v).reshape(L, H * D)
-            x = ln(x + att @ lp["wo"].T + lp["bo"], lp["ln1g"], lp["ln1b"])
-            h = jax.nn.gelu(x @ lp["w1"].T + lp["b1"], approximate=False)
-            x = ln(x + h @ lp["w2"].T + lp["b2"], lp["ln2g"], lp["ln2b"])
-        return x[L - n_rows:] @ p["embed"].T
+            x = ln(x + mm(att, lp["wo"]) + lp["bo"], lp["ln1g"], lp["ln1b"])
+            h = jax.nn.gelu(mm(x, lp["w1"]) + lp["b1"], approximate=False)
+            x = ln(x + mm(h, lp["w2"]) + lp["b2"], lp["ln2g"], lp["ln2b"])
+        rows = jax.lax.dynamic_slice_in_dim(x, start, n_rows, axis=0)
+        return mm(rows, p["embed"]).astype(jnp.float32)
 
-    with jax.default_matmul_precision("highest"):
-        return np.asarray(jax.jit(forward)(
-            jax.tree.map(lambda a: a.astype(jnp.float32), params),
-            jnp.asarray(fed, jnp.int32)))
+    return jax.jit(forward)
+
+
+def reference_logits(params, cfg, fed, n_rows, pad_to=None, dtype="float32"):
+    """The decoder block as the repo defines it (post-LN, exact GELU, tied
+    output embedding, learned positions) in plain float32 jax.numpy at the
+    highest matmul precision: no kernel, no cache, O(L^2) attention.  Logits
+    (on the device) of the last ``n_rows`` positions of ``fed``; where
+    ``fed`` has fewer, of its first ``n_rows`` positions.  ``pad_to`` pads
+    ``fed`` behind its end (the causal mask keeps the padding out of every
+    row before it), so requests of any length share one program.
+    ``dtype`` other than float32 is a control: ``"int8"`` and
+    ``"float8_e4m3fn"`` the same forward with both sides of every projection
+    on that grid, ``"bfloat16"`` with weights and activations in bfloat16 at
+    the chip's default precision (which is the precision of the engine's own
+    matrix products, so that one does not fail: PERF.md, PR 28)."""
+    import jax
+    import jax.numpy as jnp
+    tokens = np.zeros(max(pad_to or 0, len(fed), n_rows), np.int32)
+    tokens[:len(fed)] = fed
+    fn = _plain_decoder(cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+                        int(n_rows), dtype)
+    with jax.default_matmul_precision(
+            "default" if dtype == "bfloat16" else "highest"):
+        return fn(params, jnp.asarray(tokens),
+                  jnp.int32(max(0, len(fed) - n_rows)))
+
+
+@functools.lru_cache(maxsize=None)
+def _gap_program():
+    import jax
+    import jax.numpy as jnp
+
+    def gaps(logits, want, valid):
+        best = logits.max(-1)
+        picked = jnp.take_along_axis(logits, want[:, None], axis=-1)[:, 0]
+        gap = (best - picked) / logits.std(-1)
+        return jnp.where(valid, gap, 0.0), logits.argmax(-1)
+    return jax.jit(gaps)
+
+
+def reference_over(reference, params, cfg, prompt, served, n_rows, pad_to,
+                   dtype="float32"):
+    """One pass of the reference over ``prompt`` + ``served`` (the tokens a
+    request was answered with, greedy).  Returns ``judge(tokens)``: per served
+    position, the gap by which that token's logit lies below the reference's
+    best there, in units of the standard deviation of the position's logits
+    (0 where the reference puts that token first), and the token the
+    reference puts first."""
+    fed = list(prompt) + list(served[:-1])
+    lo = len(prompt) - 1 - max(0, len(fed) - n_rows)
+    valid = np.zeros(n_rows, bool)
+    valid[lo:lo + len(served)] = True
+    logits = reference(params, cfg, fed, n_rows, pad_to=pad_to, dtype=dtype)
+
+    def judge(tokens):
+        judged = np.zeros(n_rows, np.int32)
+        judged[valid] = tokens
+        gap, top = _gap_program()(logits, judged, valid)
+        return np.asarray(gap)[valid], np.asarray(top)[valid]
+    return judge
+
+
+def answered_in_full(records):
+    return [r for r in records if r["error"] is None
+            and r["answered"] == r["output"]]
+
+
+def sample_served(records, seed, n_tokens):
+    """The requests the served-token check looks at: the longest answered
+    request (prompt + answer; the first such), then answered requests in an
+    order drawn from the seed until ``n_tokens`` served tokens are in."""
+    ok = answered_in_full(records)
+    if not ok:
+        return []
+    longest = max(ok, key=lambda r: (r["prompt"] + r["output"], -r["i"]))
+    picked, tokens = [longest], longest["output"]
+    for k in np.random.default_rng([seed, 4]).permutation(len(ok)):
+        if tokens >= n_tokens:
+            break
+        if ok[k] is not longest:
+            picked.append(ok[k])
+            tokens += ok[k]["output"]
+    return picked
 
 
 def paged_logits(engine, prompt, n_decode):
     """Prefill ``prompt`` chunk by chunk, then decode ``n_decode`` greedy
     tokens, through the programs the engine built (the builders' cache hands
-    back the same jitted functions) on a page pool of the engine's shape.
+    back the same jitted functions) on a pool the program hands out
+    (``decoder.fresh_pool``: its shape and layout are the program's business).
     Returns (tokens fed, one logits row per fed position from the last
     prompt token on).  After chip_smoke.py:paged_logits."""
     import jax.numpy as jnp
@@ -226,9 +365,8 @@ def paged_logits(engine, prompt, n_decode):
             or engine.decode_fused_mode is not None):
         raise RuntimeError("the logits check would drive a program the "
                            "engine does not run")
-    shape = (cfg.num_layers, cfg.num_kv_heads, engine.alloc.total_pages, S,
-             cfg.head_dim)
-    kp, vp = jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32)
+    kp, vp = (decoder.fresh_pool(cfg, engine.alloc.total_pages, S,
+                                 engine.kv_dtype) for _ in range(2))
     pps, B = engine.pages_per_seq, engine.slots
     row = np.arange(1, pps + 1, dtype=np.int32)
     for lo in range(0, len(prompt), chunk):
@@ -256,41 +394,110 @@ def paged_logits(engine, prompt, n_decode):
     return fed, np.stack(rows)
 
 
-def check_reference(engine, lm, reference, check, seed, log):
-    vocab = lm.config.vocab_size
-    prompt = np.random.default_rng([seed, 1]).integers(
-        0, vocab, size=check["prompt_tokens"]).tolist()
+def prompt_ids(seed, stream, i, n, vocab):
+    """Request i's prompt: fresh token ids for every issue, so no two
+    requests share a prefix and the prefix cache never hits unless a mix
+    says so."""
+    return np.random.default_rng([seed, stream, i]).integers(
+        0, vocab, size=n).tolist()
+
+
+def check_reference(engine, lm, reference, check, seed, log, controls=()):
+    """Before the window: the engine's own compiled programs, driven by hand
+    over one prompt and a few greedy steps, against the plain forward, every
+    logit.  Returns the number compared beside its limit, and the same number
+    of each of ``controls`` (the reference at a lower precision)."""
+    prompt = prompt_ids(seed, 1, 0, check["prompt_tokens"],
+                        lm.config.vocab_size)
     fed, got = paged_logits(engine, prompt, check["decode_steps"])
-    ref = reference(lm.jax_params(), lm.config, fed, got.shape[0])
-    err = float(np.abs(got - ref).max() / ref.std())
+    ref = np.asarray(reference(lm.jax_params(), lm.config, fed, got.shape[0]))
+
+    def max_err(logits):
+        if logits.shape != ref.shape or not np.isfinite(logits).all():
+            return float("inf")
+        return float(np.abs(logits - ref).max() / ref.std())
+    err = max_err(got)
     rms = float(np.sqrt(np.mean(np.square(got - ref))) / ref.std())
-    ok = bool(got.shape == ref.shape and np.isfinite(got).all()
-              and err < LOGIT_TOL)
     log("reference check: %d prompt tokens + %d decode steps through the "
         "paged cache vs the plain float32 forward: max %.4f rms %.4f of "
         "std(reference), tolerance %.2f -> %s"
         % (len(prompt), check["decode_steps"], err, rms, LOGIT_TOL,
-           "ok" if ok else "DISAGREE"))
-    return ok
+           "ok" if err < LOGIT_TOL else "DISAGREE"))
+    out = [{"name": "program_logits_max_err", "value": err,
+            "limit": LOGIT_TOL}]
+    for dtype in controls:
+        if dtype == "altered":      # a fault of the answers, not of these
+            continue
+        low = max_err(np.asarray(reference(lm.jax_params(), lm.config, fed,
+                                           got.shape[0], dtype=dtype)))
+        log("control (the %s reference in the program's place, the same "
+            "logits): max %.4f of std(reference)" % (dtype, low))
+        out.append({"name": "control_%s_logits_max_err" % dtype,
+                    "value": low, "limit": LOGIT_TOL})
+    return out
+
+
+def check_served(lm, reference, records, seed, check, n_rows, pad_to, log,
+                 controls=()):
+    """After the window: what the timed path answered, at the timed sizes.
+    A sample of the requests it finished (``sample_served``), each run once
+    through the plain forward with the tokens it was answered with; the
+    number compared is the widest gap by which a served token's logit lies
+    below the reference's best.  ``controls`` reads beside it, at every
+    position of the same prompts and tokens, the token that the reference at
+    each lower precision puts first, and (``"altered"``) the token next to
+    the served one in the vocabulary, judged the same way."""
+    params, cfg = lm.jax_params(), lm.config
+    gaps, differ = [], 0
+    beside = {name: [] for name in controls}
+    sample = sample_served(records, seed, check["served_tokens"])
+    for r in sample:
+        served = np.asarray(r["tokens"])
+        args = (reference, params, cfg,
+                prompt_ids(seed, 2, r["i"], r["prompt"], cfg.vocab_size),
+                served, n_rows, pad_to)
+        judge = reference_over(*args)
+        gap, top = judge(served)
+        gaps.extend(gap.tolist())
+        differ += int((top != served).sum())
+        for name in controls:
+            other = ((served + 1) % cfg.vocab_size if name == "altered"
+                     else reference_over(*args, dtype=name)(served)[1])
+            beside[name].extend(judge(other)[0].tolist())
+    worst = max(gaps) if gaps and np.isfinite(gaps).all() else float("inf")
+    log("served-token check: %d requests (the longest among them), %d served "
+        "tokens through the plain float32 forward: widest gap below the "
+        "reference's best %.4f of std(logits), mean %.5f, %d tokens are not "
+        "the reference's first; limit %.2f -> %s"
+        % (len(sample), len(gaps), worst, np.mean(gaps) if gaps else 0.0,
+           differ, SERVED_GAP_TOL,
+           "ok" if worst < SERVED_GAP_TOL else "DISAGREE"))
+    out = [{"name": "served_gap_max", "value": worst,
+            "limit": SERVED_GAP_TOL}]
+    for name, g in beside.items():
+        g = np.asarray(g if g else [np.inf])
+        log("control (%s, in the program's place at the same positions): "
+            "gap widest %.4f mean %.5f least %.4f, %d of %d tokens are not "
+            "the float32 reference's first"
+            % (name, g.max(), g.mean(), g.min(), np.count_nonzero(g), g.size))
+        # an altered token has to fail wherever it stands, a precision at
+        # its worst position
+        out.append({"name": "control_%s_served_gap" % name,
+                    "value": float(g.min() if name == "altered" else g.max()),
+                    "limit": SERVED_GAP_TOL})
+    return out
 
 
 # ---------------------------------------------------------------------------
-def run(ctx):
-    import jax
+def serve_window(ctx, lm, table, reference):
+    """Engine and server up, the check of the engine's programs, the warm-up
+    requests, the measured window with its drain, engine and server down.
+    Nothing of the engine outlives the call, so its pools are free when the
+    reference runs over the answers."""
     from mxnet_tpu.serving import DecodeEngine, ModelServer, ServingClient
     from chipbench import trace as reduction
     log, config, traffic = ctx["log"], ctx["config"], ctx["traffic"]
     seed, seconds, lap = ctx["seed"], ctx["seconds"], ctx["clock"].lap
-    lap("imports + device start")
-    kwargs = {k: config[v] for k, v in config["builder_kwargs"].items()}
-    lm = ctx["resolve"](config["builder"])(seed=seed, **kwargs)
-    jax.block_until_ready(lm.jax_params())
-    lap("weights")
-    table = make_table(traffic)
-    log("table of %d rows: prompt %s output %s"
-        % (len(table), describe([p for p, _ in table]),
-           describe([o for _, o in table])))
-
     limit = seconds + traffic["drain_s"] + 30.0   # above the drain limit
     engine = DecodeEngine(lm, **config["engine"])
     server = ModelServer(request_timeout_s=limit)
@@ -306,23 +513,20 @@ def run(ctx):
                engine.decode_fused_mode, engine.kv_dtype,
                engine.prefix_cache is not None))
         lap("engine warm-up")
-        ok_ref = check_reference(
-            engine, lm, ctx["resolve"](config["reference"], "serve"),
-            config["check"], seed, log)
+        check = check_reference(engine, lm, reference, config["check"], seed,
+                                log, ctx["controls"])
         lap("reference check")
 
         local = threading.local()
 
         def ask(i, prompt_len, output_len, stream=2):
-            # fresh token ids for every issue: no two requests share a
-            # prefix, so the prefix cache never hits unless a mix says so
-            ids = np.random.default_rng([seed, stream, i]).integers(
-                0, lm.config.vocab_size, size=prompt_len).tolist()
+            ids = prompt_ids(seed, stream, i, prompt_len,
+                             lm.config.vocab_size)
             if getattr(local, "cli", None) is None:
                 local.cli = ServingClient(host, port, timeout=limit,
                                           retries=0)
-            out = local.cli.generate("lm", ids, max_tokens=output_len)
-            return len(out["tokens"])
+            return local.cli.generate("lm", ids,
+                                      max_tokens=output_len)["tokens"]
 
         # the served path once at each end of the table's prompt lengths,
         # side by side, so every host-side program of a step exists
@@ -351,19 +555,39 @@ def run(ctx):
         if ctx["trace"]:
             tracer.join()
         ctx["clock"].close_window()
-        snap = server.metrics.snapshot()["models"].get("lm", {})
-        stats = engine.stats()
+        stats = {"serving": server.metrics.snapshot()["models"].get("lm", {}),
+                 "engine": engine.stats()}
+        return records, t_open, stats, check
     finally:
         if tracing.is_set():
             reduction.stop()
         server.stop(drain=False, timeout=10.0)
         engine.stop(drain=False)
 
-    out = summarize(records, t_open)
+
+def run(ctx):
+    import jax
+    log, config, traffic = ctx["log"], ctx["config"], ctx["traffic"]
+    seed, seconds, lap = ctx["seed"], ctx["seconds"], ctx["clock"].lap
+    lap("imports + device start")
+    kwargs = {k: config[v] for k, v in config["builder_kwargs"].items()}
+    lm = ctx["resolve"](config["builder"])(seed=seed, **kwargs)
+    jax.block_until_ready(lm.jax_params())
+    lap("weights")
+    table = make_table(traffic)
+    log("table of %d rows: prompt %s output %s"
+        % (len(table), describe([p for p, _ in table]),
+           describe([o for _, o in table])))
+    reference = ctx["resolve"](config["reference"], "serve")
+    records, t_open, stats, check = serve_window(ctx, lm, table, reference)
+
+    out = summarize(records, t_open, seconds)
     log("window %.3f s + %.3f s to the last answer: %d requests issued, %d "
-        "answered in full, %d failed"
+        "answered in full, %d failed; %d answered inside the window, the "
+        "last %.3f s after its open: the served rate is over those"
         % (seconds, out["drain_s"] - seconds, out["attempted"],
-           out["attempted"] - out["failed"], out["failed"]))
+           out["attempted"] - out["failed"], out["failed"],
+           out["answered_in_window"], out["rate_span_s"]))
     for r in records:
         if r["error"] or r["answered"] != r["output"]:
             log("  request %d (%d -> %d): answered %s %s"
@@ -374,9 +598,20 @@ def run(ctx):
         "shows here)" % np.diff(done).max())
     log("latencies in order of issue, ms: %s" % " ".join(
         "%.0f" % (1e3 * (r["t_done"] - r["t_from"])) for r in records))
-    return {
-        "correct": ok_ref and out["failed"] == 0 and out["attempted"] > 0,
-        "attempted": out["attempted"], "failed": out["failed"],
-        "end_to_end": out, "stats": {"serving": snap, "engine": stats},
-    }
+    log("sent, ms after the window's open, in order of issue: %s" % " ".join(
+        "%.0f" % (1e3 * (r["t_sent"] - t_open)) for r in records
+        if r["t_sent"] is not None))
 
+    def after_window():
+        """Run by run.py once the device's memory peak has been read."""
+        return check_served(lm, reference, records, seed, config["check"],
+                            max(o for _, o in table), lm.config.max_length,
+                            log, ctx["controls"])
+
+    return {
+        "attempted": out["attempted"], "failed": out["failed"],
+        "checks": check + [{"name": "requests_failed",
+                            "value": out["failed"], "limit": 0}],
+        "after_window": after_window,
+        "end_to_end": out, "stats": stats,
+    }

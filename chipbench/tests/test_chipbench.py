@@ -97,7 +97,7 @@ def test_closed_loop_issues_in_table_order_and_counts_failures():
             hang.wait(5.0)          # never answered inside the drain limit
         if i == 3:
             raise RuntimeError("refused")
-        return output - 1 if i == 4 else output     # 4 answers short
+        return [1] * (output - 1 if i == 4 else output)     # 4 answers short
 
     records, t_open = serve.run_load(ask, table, traffic, 0.15)
     hang.set()
@@ -106,7 +106,7 @@ def test_closed_loop_issues_in_table_order_and_counts_failures():
     assert sorted(s[0] for s in seen) == list(range(len(seen)))
     assert all((p, o) == table[i % 3] for i, p, o in seen)
     assert len(seen) > 6
-    out = serve.summarize(records, t_open)
+    out = serve.summarize(records, t_open, 0.15)
     assert out["attempted"] == len(records) == len(seen)
     bad = {r["i"] for r in records
            if r["error"] or r["answered"] != r["output"]}
@@ -121,11 +121,11 @@ def test_closed_loop_issues_in_table_order_and_counts_failures():
 def test_open_loop_times_from_the_due_time():
     traffic = {"kind": "open", "workers": 4, "drain_s": 1.0,
                "rate_per_s": 50.0, "gaps": {"multiplier": 3, "offset": 1}}
-    records, t_open = serve.run_load(lambda i, p, o: o, [(5, 1)], traffic,
-                                     0.2)
+    records, t_open = serve.run_load(lambda i, p, o: [0] * o, [(5, 1)],
+                                     traffic, 0.2)
     assert len(records) == len(serve.due_times(traffic, 0.2))
     assert all(r["t_from"] == r["t_due"] <= r["t_sent"] for r in records)
-    assert serve.summarize(records, t_open)["failed"] == 0
+    assert serve.summarize(records, t_open, 0.2)["failed"] == 0
 
 
 # ---------------------------------------------------------------------------
