@@ -23,6 +23,12 @@ GQA layout: ``num_heads`` query heads grouped onto ``num_kv_heads`` KV
 heads (head ``h`` reads KV head ``h // (H // KVH)``) — the grouping the
 TPU paged-attention kernel expects, consistent across all three paths.
 
+A second block, state-space layers with attention layers among them
+(:mod:`.hybrid`, builder :func:`hybrid_lm`), is served by the same
+factories: ``make_decode_step``, ``make_prefill_chunk``, ``fresh_pool``
+and ``full_forward`` dispatch on the config (:func:`is_hybrid`), and its
+pools carry a recurrent state paged beside the KV rows.
+
 Weights are read once through :meth:`CausalLM.jax_params` (raw
 ``jax.Array`` pytree) and treated as frozen for serving — the registry
 hot-swap path replaces the whole model, never mutates weights in place.
@@ -62,7 +68,7 @@ __all__ = ["DecoderConfig", "CausalLM", "full_forward", "make_decode_step",
            "fn_cache_stats", "decode_launch_stats",
            "verify_launch_stats", "decode_collective_stats", "tp_plan",
            "TPPlan", "causal_lm", "decoder_tiny", "decoder_tiny_lm",
-           "decoder_draft"]
+           "decoder_draft", "hybrid_lm", "is_hybrid"]
 
 
 # ---------------------------------------------------------------------------
@@ -236,19 +242,49 @@ def pool_shape(cfg, total_pages, page_size):
             cfg.num_kv_heads * cfg.head_dim)
 
 
-def fresh_pool(cfg, total_pages, page_size, kv_dtype="float32"):
-    """A zeroed pool in rows form: a float32 array, or an int8
-    :class:`~..ops.pallas.paged_attention.QPages` (codes in rows form,
-    per-(layer, head, page) scales).  Scales start at ONE so untouched
-    pages (the scratch page, inactive slots) dequantize to exact zeros,
-    like the float pool."""
+def is_hybrid(cfg):
+    """True for a model with state-space layers
+    (:class:`~.hybrid.HybridConfig`): its pools carry a recurrent state
+    beside the KV rows and its programs come from :mod:`.hybrid`."""
+    return hasattr(cfg, "layer_kinds")
+
+
+def _kv_dtype(cfg, kv_dtype):
+    """The cache dtype of a program or pool: the one asked for, else the
+    model's own (``cfg.kv_dtype`` where the config has one), else
+    float32.  A caller that names none (the benchmark's logits check) and
+    the engine, which names its own, then meet in the same cache entry."""
+    return str(kv_dtype if kv_dtype is not None
+               else getattr(cfg, "kv_dtype", "float32"))
+
+
+def fresh_pool(cfg, total_pages, page_size, kv_dtype=None):
+    """A zeroed pool, one of the two (K, V) a step program takes.  What a
+    pool is is the program's business; callers hold it as an opaque
+    pytree and hand it back.
+
+    For the classic block: rows form, a float32 or bfloat16 array, or an
+    int8 :class:`~..ops.pallas.paged_attention.QPages` (codes in rows
+    form, per-(layer, head, page) scales).  Scales start at ONE so
+    untouched pages (the scratch page, inactive slots) dequantize to exact
+    zeros, like the float pool.
+
+    For a model with state-space layers: a :class:`~.hybrid.HybridPool`,
+    the attention layers' token rows and, beside them, **one state entry
+    per page and state-space layer** (the recurrent state after the last
+    token written into that page, float32).  The K pool carries the first
+    half of the channels and the V pool the second, so the two calls
+    return the same structure and neither part stays unread."""
+    kv_dtype = _kv_dtype(cfg, kv_dtype)
+    if is_hybrid(cfg):
+        return _hybrid.fresh_pool(cfg, total_pages, page_size, kv_dtype)
     shape = pool_shape(cfg, total_pages, page_size)
-    if str(kv_dtype) == "int8":
+    if kv_dtype == "int8":
         return _paged.QPages(
             q=jnp.zeros(shape, jnp.int8),
             s=jnp.ones((cfg.num_layers, cfg.num_kv_heads, shape[1]),
                        jnp.float32))
-    return jnp.zeros(shape, jnp.float32)
+    return jnp.zeros(shape, jnp.dtype(kv_dtype))
 
 
 def _codes(pool):
@@ -284,7 +320,10 @@ def pages_from_rows(pool, num_kv_heads):
 @functools.partial(jax.jit, donate_argnums=0)
 def fork_page(pool, src, dst):
     """Copy page ``src`` over page ``dst`` in every layer of a rows-form
-    pool, in place: the device half of a copy-on-write fork."""
+    pool, in place: the device half of a copy-on-write fork.  A hybrid
+    pool's page takes its state entries along."""
+    if isinstance(pool, _hybrid.HybridPool):
+        return jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), pool)
     return _paged.copy_page(pool, src, dst)
 
 
@@ -410,7 +449,7 @@ def _kv_append(pages, li, wp, ws, val, chunk=None):
         def write(pool, rows):
             return _write_chunk(pool, li, *chunk, rows)
     if not isinstance(pages, _paged.QPages):
-        return write(pages, val)
+        return write(pages, val.astype(pages.dtype))
     amax = jnp.abs(val.astype(jnp.float32)).max(axis=-1)   # ws.shape+(KVH,)
     fresh = jnp.where(amax > 0, amax / 127.0, 1.0).astype(jnp.float32)
     old = pages.s[li, :, wp]                               # ws.shape+(KVH,)
@@ -680,6 +719,8 @@ def full_forward(params, cfg, tokens):
 
     Whole-sequence causal attention through the flash kernel; the greedy
     parity oracle for the incremental paged decode path."""
+    if is_hybrid(cfg):
+        return _hybrid.full_forward(params, cfg, tokens)
     B, L = tokens.shape
     g = cfg.num_heads // cfg.num_kv_heads
     x = params["embed"][tokens] + params["pos"][:L]
@@ -698,8 +739,33 @@ def full_forward(params, cfg, tokens):
 # ---------------------------------------------------------------------------
 # incremental decode over the paged KV cache
 # ---------------------------------------------------------------------------
+def _refuse_hybrid(cfg, what):
+    """The programs of a model with state-space layers exist for one chip,
+    unquantized: anything else is refused by name, never served by a
+    program that does something else."""
+    if is_hybrid(cfg):
+        raise ValueError(
+            "decoder: %s is not supported for a model with state-space "
+            "layers" % what)
+
+
+def hybrid_program(cfg, sharding, quant, kv_dtype):
+    """True where the programs to build are :mod:`.hybrid`'s; what those
+    programs cannot do is refused here (the engine asks before it builds
+    anything, the factories again for whoever calls them directly)."""
+    if not is_hybrid(cfg):
+        return False
+    if sharding is not None and int(sharding.axis_size("tp")) > 1:
+        _refuse_hybrid(cfg, "a tp sharding")
+    if quant is not None:
+        _refuse_hybrid(cfg, "weight quantisation (%r)" % (quant,))
+    if kv_dtype == "int8":
+        _refuse_hybrid(cfg, "an int8 KV pool")
+    return True
+
+
 def make_decode_step(cfg, page_size, sharding=None, quant=None,
-                     kv_dtype="float32"):
+                     kv_dtype=None):
     """Build (or fetch) the jitted batched decode step for
     (cfg, page_size) — cached in the bounded per-geometry LRU.
 
@@ -718,7 +784,15 @@ def make_decode_step(cfg, page_size, sharding=None, quant=None,
                        kv_dtype="int8" a QPages (codes, scales) pytree.
                        The pages form (layers, KVH, total_pages,
                        page_size, head_dim) is taken too; what comes
-                       back is rows form (:class:`PoolProgram`)
+                       back is rows form (:class:`PoolProgram`).  For a
+                       model with state-space layers a
+                       :class:`~.hybrid.HybridPool` each: a lane at
+                       position p reads the state entry of the page
+                       that holds p - 1 (zeros at p = 0) and writes the
+                       entry of the page that holds p; an inactive lane
+                       writes the scratch page's.  ``kv_dtype`` left out
+                       is the model's own (``cfg.kv_dtype``), else
+                       float32
       tokens:     (B,) int32 — this step's input token per slot
       positions:  (B,) int32 — cache index the token lands at
       page_tables:(B, pages_per_seq) int32
@@ -726,8 +800,12 @@ def make_decode_step(cfg, page_size, sharding=None, quant=None,
                   read garbage; the engine discards their outputs
     -> (k_pages, v_pages, next_tokens (B,) int32, logits (B, vocab) f32)
     """
+    kv_dtype = _kv_dtype(cfg, kv_dtype)
     key = ("decode", cfg, int(page_size), _shard_token(sharding),
-           quant, str(kv_dtype))
+           quant, kv_dtype)
+    if hybrid_program(cfg, sharding, quant, kv_dtype):
+        return _fn_cache.get(key, lambda: _hybrid.build_decode_step(
+            cfg, int(page_size)))
     return _fn_cache.get(key, lambda: _build_decode_step(
         cfg, int(page_size), tp_plan(cfg, sharding, quant=quant,
                                      kv_int8=(kv_dtype == "int8"))))
@@ -836,6 +914,7 @@ def make_decode_step_fused(cfg, page_size, layer_group=0, mode="interpret",
     dequant-matmul kernel instead — quantization trades the single-launch
     program for the bandwidth win, it does not stack with it.
     """
+    _refuse_hybrid(cfg, "the fused decode cell")
     if quant is not None or str(kv_dtype) != "float32":
         warnings.warn(
             "decoder: the fused decode step is fp-only; serving the "
@@ -913,6 +992,9 @@ def _kv_structs(cfg, page_size, total_pages, kv_dtype="float32",
                 rows=True):
     """ShapeDtypeStruct of one pool (fp array or int8 QPages) in the
     form a :class:`PoolProgram`'s inner program takes."""
+    if is_hybrid(cfg):
+        return jax.eval_shape(lambda: fresh_pool(cfg, total_pages,
+                                                 page_size, kv_dtype))
     scales = (cfg.num_layers, cfg.num_kv_heads, int(total_pages))
     if rows:
         shape = pool_shape(cfg, total_pages, page_size)
@@ -922,7 +1004,7 @@ def _kv_structs(cfg, page_size, total_pages, kv_dtype="float32",
         return _paged.QPages(
             q=jax.ShapeDtypeStruct(shape, jnp.int8),
             s=jax.ShapeDtypeStruct(scales, jnp.float32))
-    return jax.ShapeDtypeStruct(shape, jnp.float32)
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(_kv_dtype(cfg, kv_dtype)))
 
 
 def _decode_step_structs(params, cfg, page_size, slots, pages_per_seq,
@@ -969,8 +1051,8 @@ def decode_launch_stats(params, cfg, page_size, slots, pages_per_seq,
         n_groups = cfg.num_layers
     args = _decode_step_structs(params, cfg, S, slots, pages_per_seq,
                                 total_pages, kv_dtype=kv_dtype,
-                                rows=fn.takes_rows)
-    jaxpr = jax.make_jaxpr(fn.inner)(*args)
+                                rows=getattr(fn, "takes_rows", True))
+    jaxpr = jax.make_jaxpr(getattr(fn, "inner", fn))(*args)
     launches = _fused.count_launches(jaxpr)
     pallas = _fused.count_pallas_calls(jaxpr)
     return {"fused": bool(fused), "layer_groups": int(n_groups),
@@ -1013,7 +1095,7 @@ def decode_collective_stats(params, cfg, page_size, slots, pages_per_seq,
 
 
 def make_prefill_chunk(cfg, page_size, chunk, sharding=None, quant=None,
-                       kv_dtype="float32"):
+                       kv_dtype=None):
     """Build (or fetch) the jitted single-sequence chunk prefill for
     (cfg, page_size, chunk) — cached in the bounded per-geometry LRU.
 
@@ -1033,9 +1115,21 @@ def make_prefill_chunk(cfg, page_size, chunk, sharding=None, quant=None,
     ``sharding`` with an active tp axis runs the chunk per-shard under
     ``shard_map`` (local heads, row-parallel all-reduce at the tail),
     bit-compatible with the sharded decode step's pages.
+
+    For a model with state-space layers the pools are
+    :class:`~.hybrid.HybridPool`: the chunk reads the state entry of the
+    page that holds ``pos0 - 1`` (zeros at ``pos0 = 0``), scans, and
+    writes an entry for every page it touches (the state after the page's
+    last token, or after the chunk's last valid one); where a sequence's
+    state lives follows from ``page_row`` alone.  ``kv_dtype`` left out is
+    the model's own (``cfg.kv_dtype``), else float32.
     """
+    kv_dtype = _kv_dtype(cfg, kv_dtype)
     key = ("prefill", cfg, int(page_size), int(chunk),
-           _shard_token(sharding), quant, str(kv_dtype))
+           _shard_token(sharding), quant, kv_dtype)
+    if hybrid_program(cfg, sharding, quant, kv_dtype):
+        return _fn_cache.get(key, lambda: _hybrid.build_prefill_chunk(
+            cfg, int(page_size), int(chunk)))
     return _fn_cache.get(key, lambda: _build_prefill_chunk(
         cfg, int(page_size), int(chunk),
         tp_plan(cfg, sharding, quant=quant,
@@ -1122,6 +1216,9 @@ def make_verify_step(cfg, page_size, width, sharding=None, quant=None,
     under ``shard_map`` — speculative decoding rides the TP engine
     unmodified (the acceptance logic only sees replicated out_tokens).
     """
+    _refuse_hybrid(cfg, "the verify program of speculative decoding (a "
+                   "rejected draft would need the state rolled back inside "
+                   "a page)")
     key = ("verify", cfg, int(page_size), int(width),
            _shard_token(sharding), quant, str(kv_dtype))
     return _fn_cache.get(key, lambda: _build_verify_step(
@@ -1360,3 +1457,16 @@ def decoder_draft(target, seed=0, num_layers=1, units=32, hidden_size=64,
                    eos_id=getattr(target, "eos_id", None))
     net.initialize(mx.init.Xavier())
     return net
+
+
+def hybrid_lm(seed=0, **kw):
+    """Initialized, deterministic :class:`~.hybrid.HybridLM` (state-space
+    layers with attention layers among them) of any size, the weights
+    drawn on the device in the model's dtype: :func:`.hybrid.hybrid_lm`
+    under the name a replica spec or a benchmark configuration gives
+    (``mxnet_tpu.models.decoder:hybrid_lm``)."""
+    return _hybrid.hybrid_lm(seed, **kw)
+
+
+# at the end: hybrid.py builds on the helpers above
+from . import hybrid as _hybrid  # noqa: E402

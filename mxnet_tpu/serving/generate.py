@@ -100,6 +100,7 @@ import jax.numpy as jnp
 from .. import config as _config
 from .. import faults
 from ..models import decoder as _decoder
+from ..models import hybrid as _hybrid
 from ..ops.pallas import fused_cell as _fused_cell
 from ..ops.pallas import paged_attention as _paged
 from .autoscale import SLOPolicy
@@ -116,6 +117,16 @@ _log = logging.getLogger(__name__)
 
 #: one identifier per request of this process, on every span of the request
 next_rid = itertools.count(1).__next__
+
+def _upload(host):
+    """``host`` (a numpy array the engine goes on to reuse) on the device,
+    from a private copy.  ``jnp.asarray`` may alias aligned numpy memory on
+    the CPU backend, and ``jnp.array`` then copies on the device,
+    asynchronously: a launch would read a staging buffer that the next
+    launch has refilled by then (seen as a fresh lane fed token 0, in the
+    processes whose buffer happened to be aligned)."""
+    return jnp.asarray(onp.array(host))
+
 
 # a request's phases on the engine's clock (_Request.mark)
 _QUEUE, _PREFILL, _DECODE = range(3)
@@ -244,6 +255,16 @@ class DecodeEngine:
                        WHOLE batch to drain (batch-level scheduling);
                        everything else identical
 
+    ``kv_dtype`` is float32, bfloat16 or int8; left out it is the
+    model's own (``config.kv_dtype``: a model published in bfloat16
+    caches in bfloat16), else float32.  A model with state-space layers
+    (:mod:`mxnet_tpu.models.hybrid`) keeps its recurrent state paged
+    beside the KV rows, one entry a page; for such a model speculation,
+    a tp sharding, int8 KV, weight quantisation, session migration, the
+    prefill / decode roles and the fused decode cell are refused at
+    construction (``ValueError``), session export / import when called,
+    and the prefix cache publishes whole pages only.
+
     ``MXNET_DECODE_FUSED`` routes the decode step through the
     persistent fused-cell kernel (``ops/pallas/fused_cell``): one
     Pallas launch per ``MXNET_DECODE_LAYER_GROUP`` decoder layers
@@ -283,15 +304,25 @@ class DecodeEngine:
                 else _config.get("MXNET_QUANT_GROUP")))
             qmode = model.quant_mode
         self.quant = model.quant_token() if qmode is not None else None
-        self.kv_dtype = str(kv_dtype if kv_dtype is not None
-                            else _config.get("MXNET_QUANT_KV")
-                            or "float32")
-        if self.kv_dtype not in ("float32", "int8"):
-            raise ValueError("kv_dtype must be float32 or int8, got %r"
-                             % (self.kv_dtype,))
         self.model = model
         self.name = name
         self.cfg = model.config
+        # the cache dtype: the one asked for, else the model's own (a
+        # model published in bfloat16 caches in bfloat16), else float32
+        self.kv_dtype = str(kv_dtype if kv_dtype is not None
+                            else _config.get("MXNET_QUANT_KV")
+                            or getattr(self.cfg, "kv_dtype", "float32"))
+        if self.kv_dtype not in ("float32", "bfloat16", "int8"):
+            raise ValueError("kv_dtype must be float32, bfloat16 or int8, "
+                             "got %r" % (self.kv_dtype,))
+        # a model with state-space layers keeps a recurrent state beside
+        # its KV rows, one entry a page (models/hybrid.py); what the engine
+        # cannot keep exact for it is refused here, by name
+        self.hybrid = _decoder.hybrid_program(self.cfg, sharding,
+                                              self.quant, self.kv_dtype)
+        if self.hybrid:
+            self._refuse_for_hybrid(speculate=speculate, migrate=migrate,
+                                    pagestore=pagestore, role=role)
         self.params = model.jax_params()
         self.slots = int(slots if slots is not None
                          else _config.get("MXNET_GEN_SLOTS"))
@@ -317,11 +348,16 @@ class DecodeEngine:
             else _config.get("MXNET_GEN_SESSION_TTL"))
 
         cfg = self.cfg
-        elems = 2 * cfg.num_layers * cfg.num_kv_heads * cfg.head_dim
+        kv_layers = (cfg.layer_kinds.count("attention") if self.hybrid
+                     else cfg.num_layers)
+        elems = 2 * kv_layers * cfg.num_kv_heads * cfg.head_dim
+        #: bytes of one page's state entries (0 without state-space layers)
+        self.state_entry_bytes = (_hybrid.state_entry_bytes(cfg)
+                                  if self.hybrid else 0)
         self.alloc = PageAllocator(
             total, self.page_size, kv_dtype=self.kv_dtype,
             page_bytes=elems * self.page_size
-            * (1 if self.kv_dtype == "int8" else 4),
+            * jnp.dtype(self.kv_dtype).itemsize + self.state_entry_bytes,
             scale_page_bytes=(2 * cfg.num_layers * cfg.num_kv_heads * 4
                               if self.kv_dtype == "int8" else 0))
         # tensor-parallel serving (ISSUE 13): resolve the sharding into a
@@ -476,7 +512,7 @@ class DecodeEngine:
         # pinned staging buffers, reused every step: batch formation
         # fills these in place instead of allocating fresh numpy arrays,
         # and the device active mask re-uploads only when it changes.
-        # Uploads go through jnp.array (an explicit copy): jnp.asarray
+        # Uploads go through _upload (a private host copy): jnp.asarray
         # zero-copy-aliases numpy memory on CPU, and a buffer an
         # in-flight launch still reads must never be mutated in place.
         self._stage_tokens = onp.zeros(self.slots, onp.int32)
@@ -485,6 +521,42 @@ class DecodeEngine:
         self._stage_carry = onp.zeros(self.slots, bool)
         self._active_dev = None
         self._active_key = None
+
+    # -- a model with state-space layers: what is refused ------------------
+    def _refuse_for_hybrid(self, speculate, migrate, pagestore, role):
+        """Raise a ``ValueError`` that names the first thing asked of this
+        engine which it cannot keep exact for a model with state-space
+        layers (ROADMAP, "What the system cannot run yet"); what the
+        programs cannot do (a tp sharding, quantisation, int8 KV)
+        ``decoder.hybrid_program`` has refused already."""
+        def want(arg, knob):
+            return bool(arg if arg is not None else _config.get(knob))
+        refused = [
+            ("speculative decoding (a rejected draft would need the "
+             "recurrent state rolled back inside a page)",
+             want(speculate, "MXNET_GEN_SPECULATE")),
+            ("session migration to a page store (the wire format carries "
+             "no state entries)",
+             want(migrate, "MXNET_GEN_MIGRATE")
+             and want(pagestore, "MXNET_GEN_PAGESTORE")),
+            ("role %r (its hand-off exports sessions)" % (role,),
+             str(role if role is not None
+                 else _config.get("MXNET_GEN_ROLE") or "mixed") != "mixed"),
+            ("the fused decode cell (MXNET_DECODE_FUSED)",
+             _fused_cell.decode_mode() is not None),
+        ]
+        for what, asked in refused:
+            if asked:
+                raise ValueError(
+                    "decode engine %r: %s is not supported for a model "
+                    "with state-space layers" % (self.name, what))
+
+    def _refuse_session_wire(self, what):
+        if self.hybrid:
+            raise ValueError(
+                "decode engine %r: %s is not supported for a model with "
+                "state-space layers (the wire format carries no state "
+                "entries)" % (self.name, what))
 
     # -- admission --------------------------------------------------------
     @property
@@ -970,6 +1042,8 @@ class DecodeEngine:
         geometry restores it bit-exactly (same pages, same greedy
         continuation).  Raises ``KeyError`` for unknown sessions and
         ``RuntimeError`` for busy or replay-pending ones."""
+        self._refuse_session_wire("session export")
+
         def op():
             with self._cond:
                 sess = self._sessions.get(session)
@@ -990,6 +1064,8 @@ class DecodeEngine:
     def import_session(self, blob, gen=None):
         """Install an :meth:`export_session` buffer as a parked session
         on this engine; returns the session id."""
+        self._refuse_session_wire("session import")
+
         def op():
             faults.check("session.import")
             sid = self._install_pages(None, bytes(blob), gen)
@@ -1262,6 +1338,11 @@ class DecodeEngine:
             self.metrics.count(self.name, "prefix_hits_total")
             self.metrics.count(self.name, "prefix_tokens_saved_total",
                                base)
+            if self.hybrid:
+                # whole pages only: each brings its state entry along
+                self.metrics.count(self.name,
+                                   "state_prefix_pages_shared_total",
+                                   len(pfx_pages))
             if pfx_partial:
                 # the trailing shared page is partially filled and this
                 # sequence will write into it: fork copy-on-write before
@@ -1316,10 +1397,10 @@ class DecodeEngine:
 
     def _tables_device(self):
         if self._tables_dev is None:
-            # jnp.array, not asarray: the device copy must be a real
+            # _upload, not asarray: the device copy must be a real
             # copy — an in-flight launch keeps reading it after the
             # host mutates self._tables for the next step
-            self._tables_dev = jnp.array(self._tables)
+            self._tables_dev = _upload(self._tables)
         return self._tables_dev
 
     def _active_device(self, mask):
@@ -1327,7 +1408,7 @@ class DecodeEngine:
         membership actually changes (steady-state steps reuse it)."""
         key = mask.tobytes()
         if self._active_key != key:
-            self._active_dev = jnp.array(mask)
+            self._active_dev = _upload(mask)
             self._active_key = key
         return self._active_dev
 
@@ -1479,12 +1560,24 @@ class DecodeEngine:
         if not self._ensure_pages(slot, n):
             return
         rid = slot.req.rid
+        # pages of the sequence the chunk writes: each gets a state entry
+        # from a model with state-space layers
+        touched = (pages_for(slot.pos + n, self.page_size)
+                   - slot.pos // self.page_size)
+        if self.hybrid:
+            self.metrics.count(self.name, "state_entries_written_total",
+                               touched)
+            if slot.pos == 0:
+                self.metrics.count(self.name, "state_starts_total")
         with span("engine.prefill_launch", rid=rid, slot=slot.idx,
-                  tokens=n, pos=slot.pos):
+                  tokens=n, pos=slot.pos,
+                  state_pages=touched if self.hybrid else 0):
             chunk = slot.prompt[slot.done:slot.done + n]
             padded = onp.zeros(self.prefill_chunk, onp.int32)
             padded[:n] = chunk
-            row = jnp.asarray(self._tables[slot.idx])
+            # a copy: the launch reads it after _sync_table has rewritten
+            # the host row for the next chunk's pages
+            row = _upload(self._tables[slot.idx])
             self._kp, self._vp, next_tok, _ = self._prefill_fn(
                 self.params, self._kp, self._vp, jnp.asarray(padded),
                 jnp.int32(slot.pos), jnp.int32(n), row)
@@ -1503,7 +1596,12 @@ class DecodeEngine:
             # keep writing into the trailing partial page, but only at
             # offsets past its published token count, which hitters
             # never read (and a hitter forks it copy-on-write anyway).
-            self.prefix_cache.insert(list(slot.history),
+            # A model with state-space layers publishes WHOLE pages
+            # only: a page's state entry is the state after its last
+            # token, and the owner keeps writing the trailing page's.
+            whole = (len(slot.history) // self.page_size * self.page_size
+                     if self.hybrid else len(slot.history))
+            self.prefix_cache.insert(list(slot.history[:whole]),
                                      self.alloc.pages(slot.owner))
         tok = self._device_wait(int, next_tok, "engine.first_token_read",
                                 rid=rid)
@@ -1565,11 +1663,11 @@ class DecodeEngine:
             self.metrics.observe_host_gap(
                 self.name, max(0.0, t0 - self._t_force_end))
         # staging buffers are reused next step: uploads must copy
-        # (jnp.array), never alias (jnp.asarray aliases host memory on
+        # (_upload), never alias (jnp.asarray aliases host memory on
         # CPU and the dispatch reads it after we mutate)
         self._kp, self._vp, next_tokens, _ = self._decode_fn(
-            self.params, self._kp, self._vp, jnp.array(tokens),
-            jnp.array(positions), self._tables_device(),
+            self.params, self._kp, self._vp, _upload(tokens),
+            _upload(positions), self._tables_device(),
             self._active_device(active))
         next_tokens = self._device_wait(onp.asarray, next_tokens)
         now = time.perf_counter()
@@ -1672,7 +1770,7 @@ class DecodeEngine:
                     chain = True
                 else:
                     st[s.idx] = s.pending
-            # reused staging buffers: upload must COPY (jnp.array) — the
+            # reused staging buffers: upload must COPY (_upload) — the
             # dispatch reads host memory asynchronously and we refill these
             # arrays before it completes
             if chain and onp.array_equal(carry, sa):
@@ -1684,9 +1782,9 @@ class DecodeEngine:
                 tokens = self._pipe[-1].out
             elif chain:
                 tokens = _decoder.make_token_combine(self.slots)(
-                    self._pipe[-1].out, jnp.array(st), jnp.array(carry))
+                    self._pipe[-1].out, _upload(st), _upload(carry))
             else:
-                tokens = jnp.array(st)
+                tokens = _upload(st)
             t0 = time.perf_counter()
             if self._t_force_end is not None:
                 # with lanes in flight the host gap is hidden (0 by
@@ -1695,7 +1793,7 @@ class DecodeEngine:
                     self.name,
                     0.0 if depth0 else max(0.0, t0 - self._t_force_end))
             self._kp, self._vp, out, _ = self._decode_fn(
-                self.params, self._kp, self._vp, tokens, jnp.array(sp),
+                self.params, self._kp, self._vp, tokens, _upload(sp),
                 self._tables_device(), self._active_device(sa))
         fl = _Flight("plain", out, t0, [(s, s.admit_seq) for s in live],
                      set(s.owner for s in live))
@@ -2027,9 +2125,9 @@ class DecodeEngine:
                 active[s.idx] = True
             t0 = time.perf_counter()
             self._kp, self._vp, out = verify_fn(
-                self.params, self._kp, self._vp, jnp.array(tokens),
-                jnp.array(positions), jnp.array(n_valid),
-                self._tables_device(), jnp.array(active))
+                self.params, self._kp, self._vp, _upload(tokens),
+                _upload(positions), _upload(n_valid),
+                self._tables_device(), _upload(active))
             kind = "verify"
         else:
             st = self._stage_tokens
@@ -2045,8 +2143,8 @@ class DecodeEngine:
                 sa[s.idx] = True
             t0 = time.perf_counter()
             self._kp, self._vp, out, _ = self._decode_fn(
-                self.params, self._kp, self._vp, jnp.array(st),
-                jnp.array(sp), self._tables_device(),
+                self.params, self._kp, self._vp, _upload(st),
+                _upload(sp), self._tables_device(),
                 self._active_device(sa))
             kind = "plain"
         fl = _Flight(kind, out, t0, [(s, s.admit_seq) for s in live],
@@ -2532,4 +2630,13 @@ class DecodeEngine:
             out["prefix_cache"] = self.prefix_cache.stats()
         if self._spec is not None:
             out["speculative"] = self._spec.stats()
+        if self.hybrid:
+            # the recurrent state paged beside the KV rows: one entry a
+            # page (scratch page included) and state-space layer
+            out["state"] = {
+                "layers": self.cfg.layer_kinds.count("state_space"),
+                "entry_bytes": self.state_entry_bytes,
+                "pool_bytes": (self.state_entry_bytes
+                               * self.alloc.total_pages),
+                "pages_with_state_peak": out["kv"]["peak_used_pages"]}
         return out

@@ -111,9 +111,9 @@ class PageAllocator:
             raise ValueError("need >= 2 pages (page 0 is the scratch page)")
         if page_size < 1:
             raise ValueError("page_size must be >= 1")
-        if str(kv_dtype) not in ("float32", "int8"):
-            raise ValueError("kv_dtype must be float32 or int8, got %r"
-                             % (kv_dtype,))
+        if str(kv_dtype) not in ("float32", "bfloat16", "int8"):
+            raise ValueError("kv_dtype must be float32, bfloat16 or int8, "
+                             "got %r" % (kv_dtype,))
         self.total_pages = int(total_pages)
         self.page_size = int(page_size)
         # quantized pools (ISSUE 16): int8 pages carry a parallel scales

@@ -98,7 +98,13 @@ class ModelMetrics:
                 "store_rejected_total", "store_over_budget_total",
                 # the scheduler's work (PR 25): engine steps taken and
                 # prefill-chunk programs launched in them
-                "engine_steps_total", "prefill_launches_total")
+                "engine_steps_total", "prefill_launches_total",
+                # a model with state-space layers (PR 29): state entries
+                # written by prefill launches (one a page touched),
+                # sequences begun from the zero state, whole pages (with
+                # their state entries) attached on a prefix hit
+                "state_entries_written_total", "state_starts_total",
+                "state_prefix_pages_shared_total")
 
     #: parts of an engine step, host wall seconds summed over the window
     #: (DecodeEngine._step): ``ops`` worker ops + expiry, ``admit``,
