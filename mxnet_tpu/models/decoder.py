@@ -473,7 +473,15 @@ def _gather_kv(pages, li, tables, num_kv_heads):
     :func:`~..ops.pallas.paged_attention.attend_ctx` and a non-paged
     decoder share.  int8 codes are dequantized by their page's latched
     per-head scale, value for value what ``gather_pages_deq`` gives for
-    the pages form."""
+    the pages form.
+
+    The prefill-chunk and verify programs and the hybrid model's
+    attention layers still read through this view (a sequence's own
+    pages; their products run over (.., C, D) per head as a full-cache
+    decoder's do).  The decode step does not: setting the
+    context head-major costs three passes over it where ``D`` is half a
+    lane tile, so it reads :func:`_gather_rows` and agrees with this
+    view to float32 rounding (:func:`_decode_attention`)."""
     rows = _codes(pages)
     b, pps = tables.shape
     S = rows.shape[2]
@@ -493,23 +501,43 @@ def _head_major(pages, li, num_kv_heads):
     return pages[li].reshape(P, S, num_kv_heads, -1).transpose(2, 0, 1, 3)
 
 
+def _gather_rows(pages, li, tables):
+    """The context of :func:`_gather_kv` as it lies in the pool: the
+    pages of ``tables`` (B, pages_per_seq) of layer ``li`` as token rows
+    (B, pages_per_seq * S, KVH * D), no head axis split off.  int8 codes
+    are dequantized in the row form, the page's per-head scale spread
+    over its head's lanes: value for value what :func:`_gather_kv`
+    gives."""
+    ctx = _codes(pages)[li, tables]                    # (B,pps,S,KVH*D)
+    b, pps, S, width = ctx.shape
+    if isinstance(pages, _paged.QPages):
+        sg = jnp.moveaxis(pages.s[li][:, tables], 0, -1)    # (B,pps,KVH)
+        sg = jnp.repeat(sg, width // sg.shape[-1], axis=-1)
+        ctx = ctx.astype(jnp.float32) * sg[:, :, None, :]
+    return ctx.reshape(b, pps * S, width)
+
+
 def _decode_attention(q, k_pages, v_pages, li, lengths, tables,
                       num_kv_heads):
     """One query token per sequence against layer ``li`` of the pools:
     jax's Pallas kernel where :mod:`~..ops.pallas.paged_attention`
     selects it (float pools, head_dim a multiple of 128 on a TPU; the
     interpreter under ``MXNET_PAGED_ATTENTION=interpret``), else the
-    gather and the masked f32 softmax the op's reference is made of."""
+    gather and the masked f32 softmax over the gathered token rows as
+    they lie (:func:`~..ops.pallas.paged_attention.attend_rows`): the
+    mathematics of ``attend_ctx(_gather_kv(..))`` without the head-major
+    relayout of the context that prefill, verify and the hybrid programs
+    still read through; the two agree to float32 rounding."""
     if (not isinstance(k_pages, _paged.QPages)
             and _paged.kernel_mode_for(q.shape[-1]) is not None):
         return _paged.paged_attention(
             q, _head_major(k_pages, li, num_kv_heads),
             _head_major(v_pages, li, num_kv_heads), lengths, tables)
     _paged.last_path = "xla"
-    return _paged.attend_ctx(
-        q, _gather_kv(k_pages, li, tables, num_kv_heads),
-        _gather_kv(v_pages, li, tables, num_kv_heads), lengths,
-        1.0 / (q.shape[-1] ** 0.5))
+    return _paged.attend_rows(
+        q, _gather_rows(k_pages, li, tables),
+        _gather_rows(v_pages, li, tables), lengths,
+        1.0 / (q.shape[-1] ** 0.5), num_kv_heads)
 
 
 # ---------------------------------------------------------------------------

@@ -22,8 +22,22 @@ dispatch shape):
   view and attention runs as masked f32 softmax.  The whole decode
   engine is therefore tier-1 testable on CPU, and the reference IS the
   bit-exactness oracle: gathering a sequence's pages yields exactly the
-  contiguous cache a non-paged decoder would hold, so paged decode must
-  match a full-cache decode bit for bit under greedy decoding.
+  contiguous cache a non-paged decoder would hold, so a program that
+  reads through this view matches a full-cache decode bit for bit.
+
+Who reads through that head-major view: this op's reference and int8
+paths (:func:`attend_ctx`) and, with their own causal products over the
+same view (``models.decoder._gather_kv``), the prefill-chunk and verify
+programs and the hybrid model's attention layers.  The decode step of
+``models.decoder`` does not, where the kernel is not selected: it reads
+the same gathered pages as token rows
+``(B, C, KVH * D)`` through :func:`attend_rows`, which never splits the
+rows' lane axis (at head_dim 64 that split cost more than the attention,
+PERF.md PR 30).  The two are the same mathematics with the float
+additions in another order, so the decode step agrees with the
+head-major view to float32 rounding (``tests/test_decode_attention_rows
+.py``: 1e-5 of the outputs' std), and with the other programs in its
+greedy tokens, not in the bits of its logits.
 
 ``MXNET_PAGED_ATTENTION`` — ``0``/``off`` forces the reference,
 ``interpret`` runs the Pallas kernel through the TPU interpreter
@@ -166,25 +180,68 @@ def gather_pages_deq(codes, scales, page_indices):
     return ctx.reshape(b, kvh, pps * s, d)
 
 
+def _masked_softmax(logits, lengths):
+    """f32 softmax over the last axis, the keys at and past ``lengths[b]``
+    (leading axis) masked off; a length of 0 (an inactive slot) gives
+    zeros, not NaN."""
+    at = jnp.arange(logits.shape[-1])
+    live = at < lengths.reshape((-1,) + (1,) * (logits.ndim - 1))
+    p = jax.nn.softmax(jnp.where(live, logits, -jnp.inf), axis=-1)
+    return jnp.where(jnp.isnan(p), 0.0, p)
+
+
 def attend_ctx(q, k_ctx, v_ctx, lengths, scale):
     """Masked decode attention over contiguous per-sequence caches.
 
     q: (B, H, D); k_ctx/v_ctx: (B, KVH, C, D); lengths: (B,) valid keys.
     f32 softmax, GQA by head grouping.  This inner math is shared by the
     paged reference (after gather) and by full-cache reference decoders,
-    which is what makes "paged == full-cache" a bit-exact statement.
+    which is what makes "paged == full-cache" a bit-exact statement
+    between them.  The decode step of ``models.decoder`` reads
+    :func:`attend_rows` instead and agrees with this to float32
+    rounding, not bit for bit.
     """
     b, h, d = q.shape
-    kvh, c = k_ctx.shape[1], k_ctx.shape[2]
+    kvh = k_ctx.shape[1]
     g = h // kvh
     qf = (q.astype(jnp.float32) * scale).reshape(b, kvh, g, d)
     logits = jnp.einsum("bkgd,bkcd->bkgc", qf, k_ctx.astype(jnp.float32))
-    mask = jnp.arange(c)[None, None, None, :] < lengths[:, None, None, None]
-    logits = jnp.where(mask, logits, -jnp.inf)
-    p = jax.nn.softmax(logits, axis=-1)
-    p = jnp.where(jnp.isnan(p), 0.0, p)  # length-0 rows (inactive slots)
+    p = _masked_softmax(logits, lengths)
     out = jnp.einsum("bkgc,bkcd->bkgd", p, v_ctx.astype(jnp.float32))
     return out.reshape(b, h, d).astype(q.dtype)
+
+
+def attend_rows(q, k_rows, v_rows, lengths, scale, num_kv_heads):
+    """:func:`attend_ctx`'s attention over the context as token rows.
+
+    q: (B, H, D); k_rows/v_rows: (B, C, KVH * D), one row of all KV
+    heads per token, which is how the step programs' pool holds them
+    (``models.decoder``); lengths: (B,) valid keys.  The context's lane
+    axis is never split or permuted: setting ``KVH * D`` lanes head-major
+    at a head_dim of 64, half a lane tile, cost the decode step three
+    passes over the gathered context (PERF.md, PR 30).  The head structure
+    sits on the small side of each product instead: the query of head
+    ``h`` is laid into a row that is zero outside the lanes of its KV
+    head ``h // g``, the scores contract whole rows, the values come back
+    as whole rows, and each head keeps its own lanes of them.  The added
+    terms are exact zeros, so this is :func:`attend_ctx`'s mathematics
+    with the float additions in another order: the two agree to float32
+    rounding, not bit for bit."""
+    b, h, d = q.shape
+    width = k_rows.shape[-1]
+    kvh = int(num_kv_heads)
+    g = h // kvh
+    # own[h, e]: lane e belongs to the KV head that head h reads
+    own = (jnp.arange(width, dtype=jnp.int32)[None, :] // d
+           == jnp.arange(h, dtype=jnp.int32)[:, None] // g)
+    qf = q.astype(jnp.float32) * scale
+    q_rows = jnp.where(own[None], jnp.tile(qf, (1, 1, kvh)), 0.0)
+    logits = jnp.einsum("bhe,bce->bhc", q_rows, k_rows.astype(jnp.float32))
+    p = _masked_softmax(logits, lengths)
+    out_rows = jnp.einsum("bhc,bce->bhe", p, v_rows.astype(jnp.float32))
+    # the block-diagonal pick, on (B, H, KVH * D): exact zeros added
+    out = jnp.where(own[None], out_rows, 0.0).reshape(b, h, kvh, d).sum(2)
+    return out.astype(q.dtype)
 
 
 def paged_attention_reference(q, k_pages, v_pages, lengths, page_indices,

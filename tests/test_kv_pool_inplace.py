@@ -11,6 +11,18 @@ write path could differ: page tables that alias pages, inactive slots
 and padded tokens (which write the scratch page), a chunk whose
 ``n_valid`` is short of the chunk, a ``pos0`` in the middle of a page.
 
+Since PR 30 the decode step's attention reads the gathered token rows as
+they lie (``attend_rows``) and no longer the head-major view the old
+helpers build: the same products with the float additions in another
+order.  The two ``decode-*`` cases therefore keep the pools' comparison
+bit for bit where the writes alone decide it (the first layer: the
+writes did not change; a later layer's K and V come through the layers
+before it, attention included, so they are held to 1e-5 of their std
+and int8 codes to one step) and the next tokens exact, and hold the
+logits to 1e-5 of their std; ``prefill-*`` and ``verify-*`` still read
+the head-major view and stay bit-identical throughout.
+``tests/test_decode_attention_rows.py`` holds the new form itself.
+
 The scratch page (page 0) is left out of the pool comparison: nothing
 reads it validly, duplicate writes to it land in an order XLA does not
 define for a scatter, and the page-at-a-time prefill write leaves it as
@@ -165,7 +177,11 @@ def run_two_steps(fn, params, k_pool, v_pool, which):
     return k_pool, v_pool, outs
 
 
-def assert_same_pool(new_rows, old_pages):
+def assert_same_pool(new_rows, old_pages, exact_layers=None):
+    """Bit for bit in the first ``exact_layers`` layers (all by default).
+    In the layers after them what is written came through an attention
+    whose additions ran in another order, so the rows agree to 1e-5 of
+    their std, int8 codes to one step and their scales to 1e-5."""
     old_rows = decoder.rows_from_pages(old_pages)
     for name, new, old in zip(("codes", "scales"),
                               jax.tree.leaves(new_rows),
@@ -175,7 +191,14 @@ def assert_same_pool(new_rows, old_pages):
         # the page axis is 1 in rows-form codes, last in the scales
         live = ((slice(None), slice(1, None)) if new.ndim == 4
                 else (Ellipsis, slice(1, None)))
-        assert new[live].tobytes() == old[live].tobytes(), name
+        new_live, old_live = new[live], old[live]
+        n = new.shape[0] if exact_layers is None else exact_layers
+        assert new_live[:n].tobytes() == old_live[:n].tobytes(), name
+        gap = onp.abs(new_live[n:].astype(onp.float32)
+                      - old_live[n:].astype(onp.float32))
+        if gap.size:
+            assert gap.max() <= (1 if new.dtype == onp.int8
+                                 else 1e-5 * old_live[n:].std()), name
         assert onp.isfinite(new.astype(onp.float32)).all(), name
 
 
@@ -207,9 +230,17 @@ def test_in_place_pool_is_bit_identical_to_scatter_and_gather(
         build(lm.config, which), lm.jax_params(), kp, vp, which)
     for step, (new, old) in enumerate(zip(new_outs, old_outs)):
         for n, o in zip(new, old):
-            assert n.dtype == o.dtype and n.tobytes() == o.tobytes(), step
-    assert_same_pool(new_k, old_k)
-    assert_same_pool(new_v, old_v)
+            assert n.dtype == o.dtype and n.shape == o.shape, step
+            if which == "decode" and n.dtype == onp.float32:
+                # the logits: attention's additions run in another order
+                assert onp.abs(n - o).max() < 1e-5 * o.std(), step
+            else:
+                assert n.tobytes() == o.tobytes(), step
+    # the decode step's first layer writes what the embeddings alone
+    # decide; the later layers write what its attention handed on
+    exact = 1 if which == "decode" else None
+    assert_same_pool(new_k, old_k, exact)
+    assert_same_pool(new_v, old_v, exact)
 
 
 def test_pool_forms_round_trip(lm):
