@@ -154,12 +154,7 @@ def paged_logits(engine, prompt, n_decode):
     compiles = decoder.fn_cache_stats()["compiles"]
     prefill = decoder.make_prefill_chunk(cfg, S, chunk,
                                          sharding=engine.sharding)
-    if engine.decode_fused_mode is not None:
-        decode = decoder.make_decode_step_fused(
-            cfg, S, engine.layer_group, engine.decode_fused_mode,
-            sharding=engine.sharding)
-    else:
-        decode = decoder.make_decode_step(cfg, S, sharding=engine.sharding)
+    decode = decoder.make_decode_step(cfg, S, sharding=engine.sharding)
     check(decoder.fn_cache_stats()["compiles"] == compiles,
           "the logits check built a program the engine did not")
     kp, vp = (decoder.fresh_pool(cfg, engine.alloc.total_pages, S)
@@ -241,12 +236,10 @@ def logits_agree(tag, got, ref):
 
 
 def describe_engine(engine, st):
-    from mxnet_tpu.ops.pallas import epilogue, fused_cell, paged_attention
-    log("engine: decode_fused=%s (None is the per-op tower) launches=%s"
-        % (st["decode_fused"], json.dumps(st["launches"], sort_keys=True)))
-    log("engine: last_path: paged_attention=%s bias_gelu=%s fused_cell=%s"
-        % (paged_attention.last_path, epilogue.last_path,
-           fused_cell.last_path))
+    from mxnet_tpu.ops.pallas import epilogue, paged_attention
+    log("engine: launches=%s" % json.dumps(st["launches"], sort_keys=True))
+    log("engine: last_path: paged_attention=%s bias_gelu=%s"
+        % (paged_attention.last_path, epilogue.last_path))
 
 
 def pool_leg(sz, lm):
@@ -289,7 +282,7 @@ def pool_leg(sz, lm):
     in_place = ("parameter", "get-tuple-element", "bitcast",
                 "dynamic-update-slice")
     for name, (fn, rest) in programs.items():
-        text = fn.inner.lower(params, pool, pool, *rest).compile().as_text()
+        text = fn.lower(params, pool, pool, *rest).compile().as_text()
         # a computation's name -> the operation at its root
         roots, current = {}, None
         for line in text.splitlines():
@@ -326,29 +319,21 @@ def pool_leg(sz, lm):
 
 
 def check_decode_program(sz, st):
-    """The program the engine selected is the one its step traced."""
-    from mxnet_tpu.ops.pallas import epilogue, fused_cell, paged_attention
-    fused = st["decode_fused"] is not None
-    check(st["launches"]["fused"] == fused,
-          "engine selected decode_fused=%s but traced %r"
-          % (st["decode_fused"], st["launches"]))
+    """The decode step the engine traced holds the kernels that ran."""
+    from mxnet_tpu.ops.pallas import epilogue, paged_attention
     if sz.rehearse:
         return
-    if fused:
-        check(fused_cell.last_path == "pallas",
-              "fused decode cell ran %r" % fused_cell.last_path)
-    else:
-        # per layer the tower holds one bias_gelu and one paged
-        # attention; each is a Pallas call exactly where its module's
-        # last_path says the kernel ran
-        want = sz.lm["num_layers"] * (
-            (paged_attention.last_path == "pallas")
-            + (epilogue.last_path == "pallas"))
-        check(st["launches"]["pallas_per_step"] == want,
-              "decode tower traced %d Pallas calls; paged attention ran "
-              "%r and bias_gelu %r"
-              % (st["launches"]["pallas_per_step"],
-                 paged_attention.last_path, epilogue.last_path))
+    # per layer the tower holds one bias_gelu and one paged attention;
+    # each is a Pallas call exactly where its module's last_path says
+    # the kernel ran
+    want = sz.lm["num_layers"] * (
+        (paged_attention.last_path == "pallas")
+        + (epilogue.last_path == "pallas"))
+    check(st["launches"]["pallas_per_step"] == want,
+          "decode tower traced %d Pallas calls; paged attention ran "
+          "%r and bias_gelu %r"
+          % (st["launches"]["pallas_per_step"],
+             paged_attention.last_path, epilogue.last_path))
 
 
 def serving_leg(sz, lm):

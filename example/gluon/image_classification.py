@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Image classification with model-zoo networks (parity: reference
-example/gluon/image_classification.py — BASELINE configs #2/#4 seed).
+example/gluon/image_classification.py — reference configs #2/#4 seed).
 
 Usage:
   python example/gluon/image_classification.py --model resnet18_v1 \

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """MNIST training (parity: reference example/gluon/mnist/mnist.py —
-BASELINE config #1: the minimum end-to-end slice).
+reference config #1: the minimum end-to-end slice).
 
 Usage: python example/gluon/mnist/mnist.py [--epochs 3] [--hybridize]
 """

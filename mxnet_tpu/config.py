@@ -452,17 +452,6 @@ register("MXNET_RNN_FUSED_CELL", str, "", "honored",
          "TPU backend, scan elsewhere), '0' forces the scan/"
          "wavefront paths, 'interpret' forces the kernel in interpreter "
          "mode (CPU test lane)", "ops.pallas.fused_cell.rnn_mode")
-register("MXNET_DECODE_FUSED", str, "", "honored",
-         "persistent fused decode-step kernel for the LLM engine: one "
-         "Pallas launch per layer group (qkv + KV append + paged "
-         "attention + FFN epilogue chain) instead of the per-op XLA "
-         "tower.  'interpret' = the CPU test lane; anything else = "
-         "the tower (the v5e's compiler refuses the cell)",
-         "ops.pallas.fused_cell.decode_mode")
-register("MXNET_DECODE_LAYER_GROUP", int, 0, "honored",
-         "decoder layers per fused decode-step kernel launch (0 = all "
-         "layers in ONE group — one launch per token per engine step)",
-         "serving.DecodeEngine")
 register("MXNET_GEN_SPECULATE", int, 0, "honored",
          "1 = speculative decoding in the LLM engine: a drafter "
          "proposes up to MXNET_GEN_SPEC_K tokens per slot and one wide "

@@ -1,7 +1,7 @@
 """ResNet v1/v2 (parity: python/mxnet/gluon/model_zoo/vision/resnet.py —
 BasicBlockV1/V2, BottleneckV1/V2, resnet18-152).  All convs hit the MXU via
 lax.conv_general_dilated; hybridize() compiles the whole tower into one XLA
-program (BASELINE config #2 model).
+program (reference config #2 model).
 
 TPU-first addition: every network/block takes ``layout`` ("NCHW" default
 for reference compat, or "NHWC").  NHWC is the MXU-native layout — it

@@ -31,7 +31,7 @@ Per-store lowering:
 Observability: every launch records ``comm.bucket.<dtype>`` into the
 profiler's comm table (count, bytes, queue→launch latency), and
 ``GradBucketer.stats()`` reports buckets / launches / bytes / segment
-boundaries per step for the bench assertions (bench.py dp row).
+boundaries per step (asserted by tests/test_bucketing.py).
 """
 from __future__ import annotations
 

@@ -1,5 +1,5 @@
 """BERT (parity target: the reference's BERT fast path — fused attention
-ops `src/operator/contrib/transformer.cc` driven from gluon; BASELINE
+ops `src/operator/contrib/transformer.cc` driven from gluon; reference
 config #3 "BERT-base pretraining, AMP bf16, fused attention via Pallas").
 
 TPU-native design: attention is `npx.flash_attention` (the Pallas blockwise

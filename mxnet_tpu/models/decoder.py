@@ -61,8 +61,8 @@ warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable")
 
 __all__ = ["DecoderConfig", "CausalLM", "full_forward", "make_decode_step",
-           "make_decode_step_fused", "make_prefill_chunk",
-           "make_verify_step", "make_token_combine", "PoolProgram",
+           "make_prefill_chunk",
+           "make_verify_step", "make_token_combine",
            "pool_shape", "fresh_pool", "rows_from_pages", "pages_from_rows",
            "fork_page", "put_pages",
            "fn_cache_stats", "decode_launch_stats",
@@ -134,7 +134,7 @@ _fn_cache = _FnCache()
 
 def fn_cache_stats():
     """{size, cap, compiles, evictions} of the decode/prefill program
-    cache (shared across decode, fused-decode, and prefill builders)."""
+    cache (shared across decode, prefill and verify builders)."""
     return _fn_cache.stats()
 
 
@@ -228,9 +228,9 @@ def _layer_tail(x, att_merged, lp, axis=None):
 # row-major one with no padding: the pool enters and leaves a program
 # as it lies, a token's K is one contiguous row to write, and a page one
 # contiguous slab to gather.  The pages form ``(L, KVH, P, S, D)`` is
-# what the paged-attention op, the fused decode cell and the wire format
-# of a migrated session speak; at head_dim 64 a TPU lays THAT shape out
-# with the page axis on the lanes, which no scatter or gather can use,
+# what the paged-attention op and the wire format of a migrated session
+# speak; at head_dim 64 a TPU lays THAT shape out with the page axis on
+# the lanes, which no scatter or gather can use,
 # and XLA then relays the whole pool out on the way into and out of
 # every launch (PERF.md, PR 26: two thirds of the device's time).  A
 # layout pinned on the program (``jax.experimental.layout.Format``)
@@ -338,39 +338,6 @@ def put_pages(pool, idx, blob):
     if isinstance(pool, _paged.QPages):
         return _paged.QPages(q=codes, s=pool.s.at[:, :, idx].set(blob.s))
     return codes
-
-
-class PoolProgram:
-    """What the ``make_*`` factories return: the jitted step program
-    ``inner(params, k_pool, v_pool, *rest) -> (k_pool, v_pool, *out)``
-    (pools donated, input aliased to output) behind one call that takes
-    the pools in either form and hands them back in rows form.  The
-    engine holds rows form, which is what ``inner`` takes
-    (``takes_rows``; the fused decode cell still takes pages form), so
-    its launches convert nothing.  A caller that built a pages-form pool
-    by hand (the benchmark's reference check, older tests) has it
-    converted on the way into its first call, and feeds back what it
-    was handed from then on: the same executable either way."""
-
-    def __init__(self, inner, num_kv_heads, takes_rows=True):
-        self.inner = inner
-        self.num_kv_heads = int(num_kv_heads)
-        self.takes_rows = bool(takes_rows)
-
-    def _as(self, pool, rows):
-        if (_codes(pool).ndim == 4) == rows:
-            return pool
-        # waited for, so one conversion's pool-sized temporary is gone
-        # before the next conversion or the program asks for memory
-        return jax.block_until_ready(
-            rows_from_pages(pool) if rows
-            else pages_from_rows(pool, self.num_kv_heads))
-
-    def __call__(self, params, k_pool, v_pool, *rest):
-        k_pool, v_pool, *out = self.inner(
-            params, self._as(k_pool, self.takes_rows),
-            self._as(v_pool, self.takes_rows), *rest)
-        return (self._as(k_pool, True), self._as(v_pool, True), *out)
 
 
 def _write_rows(pool, li, wp, ws, rows):
@@ -601,10 +568,8 @@ class TPPlan:
         self.kv_int8 = bool(kv_int8)
         # the pool in rows form (L, P, S, KVH * D): heads lie contiguous
         # in a row, so splitting the row over tp gives each shard its
-        # own KV heads (the Pope et al. layout SNIPPETS.md [3] uses);
-        # the pages form the fused cell takes splits the KVH axis
+        # own KV heads (the Pope et al. layout SNIPPETS.md [3] uses)
         self.kv_rows_spec = P(None, None, None, "tp")
-        self.kv_spec = P(None, "tp", None, None, None)
         if self.kv_int8:
             # int8: the codes shard like fp rows; the parallel scales
             # pool (L, KVH, P) shards along its KV-head axis
@@ -686,14 +651,13 @@ class TPPlan:
         may have produced a differently-placed result."""
         return jax.device_put(pages, self.kv_sharding)
 
-    def wrap(self, fn, n_rest, n_out_rest, pages_form=False):
+    def wrap(self, fn, n_rest, n_out_rest):
         """jit(shard_map(fn)) with the plan's layout: params + KV pools
-        sharded (rows form, or the pages form the fused cell takes),
-        every other operand/result replicated; pools donated so the
-        cache stays in place across steps."""
+        (rows form) sharded, every other operand/result replicated;
+        pools donated so the cache stays in place across steps."""
         from jax.sharding import PartitionSpec as P
         rep = P()
-        kv = self.kv_spec if pages_form else self.kv_in_spec
+        kv = self.kv_in_spec
         in_specs = (self.param_specs(), kv, kv) + (rep,) * n_rest
         out_specs = (kv, kv) + (rep,) * n_out_rest
         smapped = jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
@@ -810,10 +774,7 @@ def make_decode_step(cfg, page_size, sharding=None, quant=None,
                        (layers, total_pages, page_size, KVH * head_dim),
                        donated and updated in place; with
                        kv_dtype="int8" a QPages (codes, scales) pytree.
-                       The pages form (layers, KVH, total_pages,
-                       page_size, head_dim) is taken too; what comes
-                       back is rows form (:class:`PoolProgram`).  For a
-                       model with state-space layers a
+                       For a model with state-space layers a
                        :class:`~.hybrid.HybridPool` each: a lane at
                        position p reads the state entry of the page
                        that holds p - 1 (zeros at p = 0) and writes the
@@ -875,17 +836,16 @@ def _build_decode_step(cfg, page_size, plan=None):
         return (k_pages, v_pages,
                 jnp.argmax(logits, axis=-1).astype(jnp.int32), logits)
 
-    return _pool_program(step, cfg, plan, n_rest=4, n_out_rest=2)
+    return _pool_program(step, plan, n_rest=4, n_out_rest=2)
 
 
-def _pool_program(fn, cfg, plan, n_rest, n_out_rest, pages_form=False):
-    """The :class:`PoolProgram` of step function ``fn``: jitted with the
-    pools donated, per shard under a TP plan."""
+def _pool_program(fn, plan, n_rest, n_out_rest):
+    """Step function ``fn(params, k_pool, v_pool, *rest) -> (k_pool,
+    v_pool, *out)`` jitted with the pools donated (input aliased to
+    output, rows form in and out), per shard under a TP plan."""
     if plan is None:
-        inner = jax.jit(fn, donate_argnums=(1, 2))
-    else:
-        inner = plan.wrap(fn, n_rest, n_out_rest, pages_form=pages_form)
-    return PoolProgram(inner, cfg.num_kv_heads, takes_rows=not pages_form)
+        return jax.jit(fn, donate_argnums=(1, 2))
+    return plan.wrap(fn, n_rest, n_out_rest)
 
 
 def make_token_combine(slots):
@@ -907,140 +867,18 @@ def make_token_combine(slots):
         lambda chained, staged, carry: jnp.where(carry, chained, staged)))
 
 
-def _group_bounds(num_layers, layer_group):
-    """[(lo, hi), …] contiguous layer groups of size ≤ layer_group
-    (0 / >=L collapses to one group — the default: ONE launch/step)."""
-    g = int(layer_group) or num_layers
-    g = max(1, min(g, num_layers))
-    return [(lo, min(lo + g, num_layers))
-            for lo in range(0, num_layers, g)]
-
-
-def _stack_layer_params(params, lo, hi):
-    keys = params["layers"][0].keys()
-    return {k: jnp.stack([params["layers"][li][k]
-                          for li in range(lo, hi)]) for k in keys}
-
-
-def make_decode_step_fused(cfg, page_size, layer_group=0, mode="interpret",
-                           sharding=None, quant=None, kv_dtype="float32"):
-    """Build (or fetch) the PERSISTENT-KERNEL decode step: one
-    ``fused_cell.decode_layer_group`` Pallas launch per layer group
-    (default: all layers in one group) instead of the per-op XLA tower.
-    Same signature and donation contract as :func:`make_decode_step`;
-    greedy next-token parity is asserted by tests/test_fused_cell.py.
-
-    Under an active tp sharding the fusion splits at the two collective
-    boundaries of each layer (a Pallas body cannot carry a psum): one
-    attention-phase launch (qkv + KV append + paged read + local
-    out-proj partial), the row-parallel all-reduce, then one FFN-phase
-    launch, the second all-reduce — still the only cross-chip traffic.
-
-    The persistent kernel is fp-only: its body latches fp weight slabs
-    and fp page slabs in VMEM.  A quant token or int8 KV falls back
-    (loudly) to the per-op step, whose GEMMs run the fused
-    dequant-matmul kernel instead — quantization trades the single-launch
-    program for the bandwidth win, it does not stack with it.
-    """
-    _refuse_hybrid(cfg, "the fused decode cell")
-    if quant is not None or str(kv_dtype) != "float32":
-        warnings.warn(
-            "decoder: the fused decode step is fp-only; serving the "
-            "per-op path with quant=%r kv_dtype=%s (the dequant-matmul "
-            "kernel carries the quantized GEMMs)" % (quant, kv_dtype),
-            stacklevel=2)
-        return make_decode_step(cfg, page_size, sharding=sharding,
-                                quant=quant, kv_dtype=kv_dtype)
-    key = ("decode_fused", cfg, int(page_size), int(layer_group),
-           str(mode), _shard_token(sharding))
-    return _fn_cache.get(key, lambda: _build_decode_step_fused(
-        cfg, int(page_size), int(layer_group), mode,
-        tp_plan(cfg, sharding)))
-
-
-def _build_decode_step_fused(cfg, page_size, layer_group, mode, plan=None):
-    S = int(page_size)
-    groups = _group_bounds(cfg.num_layers, layer_group)
-    qcfg = plan.local_cfg if plan is not None else cfg
-
-    def step(params, k_pages, v_pages, tokens, positions, page_tables,
-             active):
-        x = (params["embed"][tokens]
-             + params["pos"][jnp.clip(positions, 0, cfg.max_length - 1)])
-        page_of = jnp.take_along_axis(
-            page_tables, (positions // S)[:, None], axis=1)[:, 0]
-        wp = jnp.where(active, page_of, 0).astype(jnp.int32)
-        ws = jnp.where(active, positions % S, 0).astype(jnp.int32)
-        lengths = jnp.where(active, positions + 1, 0).astype(jnp.int32)
-        meta = jnp.stack([wp, ws])
-        pt = page_tables.astype(jnp.int32)
-        if plan is not None:
-            # per-layer phase kernels with the collective in between
-            for li, lp in enumerate(params["layers"]):
-                kp_l, vp_l, o_part = _fused.decode_attn_phase(
-                    x, k_pages[li], v_pages[li], lp, meta, pt,
-                    lengths[:, None], qcfg, mode)
-                k_pages = jax.lax.dynamic_update_slice_in_dim(
-                    k_pages, kp_l[None], li, axis=0)
-                v_pages = jax.lax.dynamic_update_slice_in_dim(
-                    v_pages, vp_l[None], li, axis=0)
-                o = jax.lax.psum(o_part, plan.axis) + lp["bo"]
-                x = _ln(x + o, lp["ln1g"], lp["ln1b"])
-                f_part = _fused.decode_ffn_phase(
-                    x, lp["w1"], lp["b1"], lp["w2"], mode)
-                f = jax.lax.psum(f_part, plan.axis) + lp["b2"]
-                x = _ln(x + f, lp["ln2g"], lp["ln2b"])
-        else:
-            for (lo, hi) in groups:
-                stacked = _stack_layer_params(params, lo, hi)
-                if len(groups) == 1:
-                    kp_g, vp_g = k_pages, v_pages
-                else:
-                    kp_g, vp_g = k_pages[lo:hi], v_pages[lo:hi]
-                kp_g, vp_g, x = _fused.decode_layer_group(
-                    x, kp_g, vp_g, stacked, meta, pt, lengths[:, None],
-                    cfg, mode)
-                if len(groups) == 1:
-                    k_pages, v_pages = kp_g, vp_g
-                else:
-                    k_pages = jax.lax.dynamic_update_slice_in_dim(
-                        k_pages, kp_g, lo, axis=0)
-                    v_pages = jax.lax.dynamic_update_slice_in_dim(
-                        v_pages, vp_g, lo, axis=0)
-        logits = jnp.dot(x.astype(jnp.float32),
-                         params["embed"].astype(jnp.float32).T)
-        return (k_pages, v_pages,
-                jnp.argmax(logits, axis=-1).astype(jnp.int32), logits)
-
-    return _pool_program(step, cfg, plan, n_rest=4, n_out_rest=2,
-                         pages_form=True)
-
-
-def _kv_structs(cfg, page_size, total_pages, kv_dtype="float32",
-                rows=True):
-    """ShapeDtypeStruct of one pool (fp array or int8 QPages) in the
-    form a :class:`PoolProgram`'s inner program takes."""
-    if is_hybrid(cfg):
-        return jax.eval_shape(lambda: fresh_pool(cfg, total_pages,
-                                                 page_size, kv_dtype))
-    scales = (cfg.num_layers, cfg.num_kv_heads, int(total_pages))
-    if rows:
-        shape = pool_shape(cfg, total_pages, page_size)
-    else:
-        shape = scales + (int(page_size), cfg.head_dim)
-    if str(kv_dtype) == "int8":
-        return _paged.QPages(
-            q=jax.ShapeDtypeStruct(shape, jnp.int8),
-            s=jax.ShapeDtypeStruct(scales, jnp.float32))
-    return jax.ShapeDtypeStruct(shape, jnp.dtype(_kv_dtype(cfg, kv_dtype)))
+def _kv_structs(cfg, page_size, total_pages, kv_dtype="float32"):
+    """ShapeDtypeStruct of one pool (:func:`fresh_pool`'s, unallocated)."""
+    return jax.eval_shape(lambda: fresh_pool(cfg, total_pages, page_size,
+                                             kv_dtype))
 
 
 def _decode_step_structs(params, cfg, page_size, slots, pages_per_seq,
-                         total_pages, kv_dtype="float32", rows=True):
+                         total_pages, kv_dtype="float32"):
     """ShapeDtypeStruct argument tuple of one decode step (census
     tracing/lowering without touching real buffers).  Quantized param
     leaves (QuantW8/QuantW4 pytrees) map leaf-wise like raw arrays."""
-    kp = _kv_structs(cfg, page_size, total_pages, kv_dtype, rows)
+    kp = _kv_structs(cfg, page_size, total_pages, kv_dtype)
     return (jax.tree.map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params),
             kp, kp,
@@ -1051,47 +889,29 @@ def _decode_step_structs(params, cfg, page_size, slots, pages_per_seq,
 
 
 def decode_launch_stats(params, cfg, page_size, slots, pages_per_seq,
-                        total_pages, fused, layer_group=0,
-                        mode="interpret", sharding=None, quant=None,
+                        total_pages, sharding=None, quant=None,
                         kv_dtype="float32"):
     """Static launch census of one decode step (the dispatch-count
-    audit): traces the chosen step program and counts launch-class
-    primitives with ``fused_cell.count_launches`` — deterministic and
-    load-independent, safe to gate CI and bench rows on.  With
-    ``sharding`` the census covers the PER-SHARD program (collectives
-    are not launch-class; see :func:`decode_collective_stats`).
+    audit): traces the step program and counts launch-class primitives
+    with ``fused_cell.count_launches`` — deterministic and
+    load-independent, safe to gate CI on.  With ``sharding`` the census
+    covers the PER-SHARD program (collectives are not launch-class; see
+    :func:`decode_collective_stats`).
 
-    Returns {fused, layer_groups, launches_per_step, pallas_per_step,
-    pallas_per_group}.
+    Returns {launches_per_step, pallas_per_step}.
     """
     S = int(page_size)
-    quantized = quant is not None or str(kv_dtype) != "float32"
-    if fused and not quantized:
-        fn = make_decode_step_fused(cfg, S, layer_group, mode,
-                                    sharding=sharding)
-        n_groups = len(_group_bounds(cfg.num_layers, layer_group))
-        if tp_plan(cfg, sharding) is not None:
-            n_groups = cfg.num_layers      # per-layer phase kernels
-    else:
-        fused = False                      # quant forces the per-op path
-        fn = make_decode_step(cfg, S, sharding=sharding, quant=quant,
-                              kv_dtype=kv_dtype)
-        n_groups = cfg.num_layers
+    fn = make_decode_step(cfg, S, sharding=sharding, quant=quant,
+                          kv_dtype=kv_dtype)
     args = _decode_step_structs(params, cfg, S, slots, pages_per_seq,
-                                total_pages, kv_dtype=kv_dtype,
-                                rows=getattr(fn, "takes_rows", True))
-    jaxpr = jax.make_jaxpr(getattr(fn, "inner", fn))(*args)
-    launches = _fused.count_launches(jaxpr)
-    pallas = _fused.count_pallas_calls(jaxpr)
-    return {"fused": bool(fused), "layer_groups": int(n_groups),
-            "launches_per_step": int(launches),
-            "pallas_per_step": int(pallas),
-            "pallas_per_group": (pallas / n_groups if n_groups else 0.0)}
+                                total_pages, kv_dtype=kv_dtype)
+    jaxpr = jax.make_jaxpr(fn)(*args)
+    return {"launches_per_step": int(_fused.count_launches(jaxpr)),
+            "pallas_per_step": int(_fused.count_pallas_calls(jaxpr))}
 
 
 def decode_collective_stats(params, cfg, page_size, slots, pages_per_seq,
-                            total_pages, sharding, fused=False,
-                            layer_group=0, mode="interpret", quant=None,
+                            total_pages, sharding, quant=None,
                             kv_dtype="float32"):
     """Static COLLECTIVE census of one sharded decode step: lowers the
     shard_map program through the partitioner and counts HLO collectives
@@ -1100,7 +920,7 @@ def decode_collective_stats(params, cfg, page_size, slots, pages_per_seq,
     gate asserts all-reduce-only (2 row-parallel reduces per layer) with
     counts invariant to batch size.
 
-    Returns {mesh, tp, fused, collectives: {class: n, ..., total}}.
+    Returns {mesh, tp, collectives: {class: n, ..., total}}.
     """
     from ..parallel import shardcfg as _shardcfg
     plan = tp_plan(cfg, sharding)
@@ -1108,18 +928,13 @@ def decode_collective_stats(params, cfg, page_size, slots, pages_per_seq,
         raise ValueError("decode_collective_stats needs a sharding with "
                          "an active tp axis that divides the geometry")
     S = int(page_size)
-    if fused and quant is None and str(kv_dtype) == "float32":
-        fn = make_decode_step_fused(cfg, S, layer_group, mode,
-                                    sharding=sharding)
-    else:
-        fn = make_decode_step(cfg, S, sharding=sharding, quant=quant,
-                              kv_dtype=kv_dtype)
+    fn = make_decode_step(cfg, S, sharding=sharding, quant=quant,
+                          kv_dtype=kv_dtype)
     args = _decode_step_structs(params, cfg, S, slots, pages_per_seq,
-                                total_pages, kv_dtype=kv_dtype,
-                                rows=fn.takes_rows)
-    census = _shardcfg.collective_census(fn.inner.lower(*args))
+                                total_pages, kv_dtype=kv_dtype)
+    census = _shardcfg.collective_census(fn.lower(*args))
     return {"mesh": sharding.describe(), "tp": plan.tp,
-            "fused": bool(fused), "collectives": census}
+            "collectives": census}
 
 
 def make_prefill_chunk(cfg, page_size, chunk, sharding=None, quant=None,
@@ -1209,7 +1024,7 @@ def _build_prefill_chunk(cfg, page_size, chunk, plan=None):
         return (k_pages, v_pages,
                 jnp.argmax(last_logits).astype(jnp.int32), last_logits)
 
-    return _pool_program(prefill, cfg, plan, n_rest=4, n_out_rest=2)
+    return _pool_program(prefill, plan, n_rest=4, n_out_rest=2)
 
 
 def make_verify_step(cfg, page_size, width, sharding=None, quant=None,
@@ -1303,7 +1118,7 @@ def _build_verify_step(cfg, page_size, width, plan=None):
         return (k_pages, v_pages,
                 jnp.argmax(logits, axis=-1).astype(jnp.int32))
 
-    return _pool_program(verify, cfg, plan, n_rest=5, n_out_rest=1)
+    return _pool_program(verify, plan, n_rest=5, n_out_rest=1)
 
 
 def verify_launch_stats(params, cfg, page_size, width, slots,
@@ -1329,7 +1144,7 @@ def verify_launch_stats(params, cfg, page_size, width, slots,
             jax.ShapeDtypeStruct((slots,), jnp.int32),
             jax.ShapeDtypeStruct((slots, pages_per_seq), jnp.int32),
             jax.ShapeDtypeStruct((slots,), jnp.bool_))
-    jaxpr = jax.make_jaxpr(fn.inner)(*args)
+    jaxpr = jax.make_jaxpr(fn)(*args)
     launches = _fused.count_launches(jaxpr)
     return {"width": W,
             "launches_per_step": int(launches),

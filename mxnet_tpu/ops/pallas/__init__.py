@@ -5,8 +5,8 @@ Parity: this tier replaces the reference's cuDNN/fused-CUDA kernels
 with TPU systolic-array kernels written in Pallas.
 
 `fused_cell` is the persistent-kernel tier for latency-bound serial
-loops: the LSTM time loop and the LLM decode step each run as one
-kernel launch with weights latched in VMEM.
+loops: the LSTM time loop runs as one kernel launch a layer with
+weights latched in VMEM.
 """
 from __future__ import annotations
 

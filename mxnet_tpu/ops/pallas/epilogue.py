@@ -47,8 +47,8 @@ _SQRT_HALF = math.sqrt(0.5)
 _INV_SQRT_2PI = 1.0 / math.sqrt(2.0 * math.pi)
 
 # per-op call counters, bumped once per (re)trace of the public entry
-# points.  bench.py and the tests assert on these to guarantee the fused
-# path is actually in the compiled program, not assumed.
+# points.  tests/test_fused_epilogue.py asserts on these to guarantee the
+# fused path is actually in the compiled program, not assumed.
 trace_counts = {"bias_gelu": 0, "bias_dropout_residual": 0}
 # which backend the last call dispatched to: "pallas"|"pallas-interpret"|"xla"
 last_path = None

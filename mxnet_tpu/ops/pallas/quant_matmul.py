@@ -21,13 +21,13 @@ weight leaf):
   The group size is derivable from the shapes: ``group = 2 * q.shape[1]
   // s.shape[1]``.
 
-The Pallas kernel (whole-array VMEM, the ``fused_cell.decode_ffn_phase``
-shape) fuses unpack + dequant + matmul into one launch; the XLA
-reference (:func:`quant_matmul_reference`) computes the identical
-formula op-for-op, which makes ``MXNET_QUANT_MATMUL=interpret`` a
-bit-exactness oracle for the kernel on CPU.  Dispatch is the repo's gate
-grammar: ``''`` auto (Pallas on a TPU backend), ``0``/``off`` forces the
-XLA reference, ``interpret`` forces the kernel in interpreter mode.
+The Pallas kernel (whole-array VMEM) fuses unpack + dequant + matmul
+into one launch; the XLA reference (:func:`quant_matmul_reference`)
+computes the identical formula op-for-op, which makes
+``MXNET_QUANT_MATMUL=interpret`` a bit-exactness oracle for the kernel
+on CPU.  Dispatch is the repo's gate grammar: ``''`` auto (Pallas on a
+TPU backend), ``0``/``off`` forces the XLA reference, ``interpret``
+forces the kernel in interpreter mode.
 """
 from __future__ import annotations
 
@@ -49,8 +49,8 @@ __all__ = ["QuantW8", "QuantW4", "quantize_w8", "quantize_w4",
 _INT8_MAX = 127.0
 _INT4_MAX = 7.0
 
-# trace-time counter (bench/tests assert the fused path is actually in
-# the compiled program — the epilogue/fused_cell convention)
+# trace-time counter (tests assert the fused path is actually in the
+# compiled program — the epilogue/fused_cell convention)
 trace_counts = {"quant_matmul": 0}
 # "pallas" | "pallas-interpret" | "xla" — which backend last latched
 last_path = None

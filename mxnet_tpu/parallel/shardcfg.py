@@ -845,7 +845,7 @@ def reshard_plan(old_cfg, new_cfg, shapes, lost_devices=()):
 
 
 # ---------------------------------------------------------------------------
-# collective census (steplat / CI gates)
+# collective census (CI gates)
 # ---------------------------------------------------------------------------
 #: HLO collective classes counted by `collective_census`
 COLLECTIVE_CLASSES = ("all-reduce", "all-gather", "reduce-scatter",

@@ -92,8 +92,9 @@ def record_comm_stat(name, nbytes=0, queue_s=0.0, n=1):
     """Accumulate one gradient-communication launch (a fused bucket
     pushpull, kvstore/bucketing.py).  Always on, like event stats — the
     per-step bucket count / bytes / queue→launch latency are the
-    observables the overlap design is validated against (bench.py asserts
-    on them).  Read back via aggregate_stats()['comm']."""
+    observables the overlap design is validated against
+    (tests/test_bucketing.py asserts on them).  Read back via
+    aggregate_stats()['comm']."""
     with _AGG["lock"]:
         st = _AGG["comm"].get(name)
         if st is None:

@@ -101,7 +101,6 @@ from .. import config as _config
 from .. import faults
 from ..models import decoder as _decoder
 from ..models import hybrid as _hybrid
-from ..ops.pallas import fused_cell as _fused_cell
 from ..ops.pallas import paged_attention as _paged
 from .autoscale import SLOPolicy
 from .errors import (BadRequestError, DeadlineExceededError, QueueFullError,
@@ -251,45 +250,34 @@ class DecodeEngine:
                        (``MXNET_GEN_PREFILL_CHUNK``)
       session_ttl_s  — idle parked-session lifetime
                        (``MXNET_GEN_SESSION_TTL``)
-      static_batching— True = the A/B baseline: admissions wait for the
-                       WHOLE batch to drain (batch-level scheduling);
-                       everything else identical
 
     ``kv_dtype`` is float32, bfloat16 or int8; left out it is the
     model's own (``config.kv_dtype``: a model published in bfloat16
     caches in bfloat16), else float32.  A model with state-space layers
     (:mod:`mxnet_tpu.models.hybrid`) keeps its recurrent state paged
     beside the KV rows, one entry a page; for such a model speculation,
-    a tp sharding, int8 KV, weight quantisation, session migration, the
-    prefill / decode roles and the fused decode cell are refused at
-    construction (``ValueError``), session export / import when called,
+    a tp sharding, int8 KV, weight quantisation, session migration and
+    the prefill / decode roles are refused at construction
+    (``ValueError``), session export / import when called,
     and the prefix cache publishes whole pages only.
 
-    ``MXNET_DECODE_FUSED`` routes the decode step through the
-    persistent fused-cell kernel (``ops/pallas/fused_cell``): one
-    Pallas launch per ``MXNET_DECODE_LAYER_GROUP`` decoder layers
-    (default: all in one group) instead of the per-op XLA tower.  The
-    cell is the CPU oracle only (``interpret``): the v5e's compiler
-    refuses it, so a TPU engine runs the tower
-    (``fused_cell.decode_mode``).  ``stats()["decode_fused"]`` names
-    the program that was chosen (``None`` is the per-op tower), and that
-    program is the one that runs —
-    a compile failure fails the step, there is no second program behind
-    it.  The static launch census lands in ``stats()["launches"]`` and the
-    metrics ``generate`` snapshot; the per-geometry decode/prefill
-    program cache is LRU-bounded by ``MXNET_GEN_FN_CACHE`` with
-    compile/evict gauges next to it.
+    The programs the engine builds are the ones that run: a compile
+    failure fails the step, there is no second program behind it.  The
+    decode step's static launch census lands in ``stats()["launches"]``
+    and the metrics ``generate`` snapshot; the per-geometry
+    decode/prefill program cache is LRU-bounded by ``MXNET_GEN_FN_CACHE``
+    with compile/evict gauges next to it.
     """
 
     def __init__(self, model, *, name="llm", slots=None, page_size=None,
                  total_pages=None, max_ctx=None, prefill_chunk=None,
                  eos_id=None, max_queue_depth=256, metrics=None,
-                 static_batching=False, session_ttl_s=None,
-                 prefix_cache=None, role=None, migrate=None,
-                 pagestore=None, speculate=None, spec_k=None,
-                 drafter=None, draft_model=None, sharding=None,
-                 quantize=None, quant_group=None, kv_dtype=None,
-                 async_decode=None, dispatch_ahead=None, slo=None):
+                 session_ttl_s=None, prefix_cache=None, role=None,
+                 migrate=None, pagestore=None, speculate=None,
+                 spec_k=None, drafter=None, draft_model=None,
+                 sharding=None, quantize=None, quant_group=None,
+                 kv_dtype=None, async_decode=None, dispatch_ahead=None,
+                 slo=None):
         # quantized serving (weight-only int8/int4 + int8 KV pages):
         # accept a pre-wrapped serving.quantize.QuantizedLM, or wrap
         # here from the kwarg/env knob.  Weights and KV cache quantize
@@ -342,7 +330,6 @@ class DecodeEngine:
             model, "eos_id", None)
         self.max_queue_depth = int(max_queue_depth)
         self.metrics = metrics if metrics is not None else ServingMetrics()
-        self.static_batching = bool(static_batching)
         self.session_ttl_s = float(
             session_ttl_s if session_ttl_s is not None
             else _config.get("MXNET_GEN_SESSION_TTL"))
@@ -388,44 +375,26 @@ class DecodeEngine:
         self._tables = onp.zeros((self.slots, self.pages_per_seq),
                                  onp.int32)
         self._tables_dev = None  # device copy, rebuilt when rows change
-        # persistent-kernel decode step (MXNET_DECODE_FUSED): one Pallas
-        # launch per layer group instead of the per-op XLA tower.  The
-        # launch census is static (trace-time) and exported as the
-        # engine's dispatch-count metric — the _bulk-flush analog.
-        self.decode_fused_mode = _fused_cell.decode_mode()
-        if self.decode_fused_mode is not None and (
-                self.quant is not None or self.kv_dtype != "float32"):
-            _log.info("decode engine %r: the fused decode cell is "
-                      "fp-only; quantized serving (quant=%r kv=%s) runs "
-                      "the per-op path", name, self.quant, self.kv_dtype)
-            self.decode_fused_mode = None
-        self.layer_group = (int(_config.get("MXNET_DECODE_LAYER_GROUP"))
-                            or cfg.num_layers)
-        if self.decode_fused_mode is not None:
-            self._decode_fn = _decoder.make_decode_step_fused(
-                cfg, self.page_size, self.layer_group,
-                self.decode_fused_mode, sharding=self.sharding)
-        else:
-            self._decode_fn = _decoder.make_decode_step(
-                cfg, self.page_size, sharding=self.sharding,
-                quant=self.quant, kv_dtype=self.kv_dtype)
+        # chipbench/serve.py reads this; it goes with those two reads
+        self.decode_fused_mode = None
+        self._decode_fn = _decoder.make_decode_step(
+            cfg, self.page_size, sharding=self.sharding,
+            quant=self.quant, kv_dtype=self.kv_dtype)
         self._prefill_fn = _decoder.make_prefill_chunk(
             cfg, self.page_size, self.prefill_chunk,
             sharding=self.sharding, quant=self.quant,
             kv_dtype=self.kv_dtype)
+        # the launch census is static (trace-time) and exported as the
+        # engine's dispatch-count metric — the _bulk-flush analog
         try:
             self.launch_stats = _decoder.decode_launch_stats(
                 self.params, cfg, self.page_size, self.slots,
                 self.pages_per_seq, total,
-                fused=self.decode_fused_mode is not None,
-                layer_group=self.layer_group,
-                mode=self.decode_fused_mode or "interpret",
                 sharding=self.sharding, quant=self.quant,
                 kv_dtype=self.kv_dtype)
         except Exception:  # pragma: no cover - tracing is best-effort
             _log.exception("decode launch census failed")
-            self.launch_stats = {"fused": self.decode_fused_mode
-                                 is not None}
+            self.launch_stats = {}
         self.metrics.observe_decode_launches(self.name, self.launch_stats)
         # static collective census (once, at engine attach): what the
         # sharded decode step moves cross-chip per step — all-reduce
@@ -437,9 +406,6 @@ class DecodeEngine:
                 self.collective_stats = _decoder.decode_collective_stats(
                     self.params, cfg, self.page_size, self.slots,
                     self.pages_per_seq, total, self.sharding,
-                    fused=self.decode_fused_mode is not None,
-                    layer_group=self.layer_group,
-                    mode=self.decode_fused_mode or "interpret",
                     quant=self.quant, kv_dtype=self.kv_dtype)
             except Exception:  # pragma: no cover - census is best-effort
                 _log.exception("decode collective census failed")
@@ -542,8 +508,6 @@ class DecodeEngine:
             ("role %r (its hand-off exports sessions)" % (role,),
              str(role if role is not None
                  else _config.get("MXNET_GEN_ROLE") or "mixed") != "mixed"),
-            ("the fused decode cell (MXNET_DECODE_FUSED)",
-             _fused_cell.decode_mode() is not None),
         ]
         for what, asked in refused:
             if asked:
@@ -1194,13 +1158,6 @@ class DecodeEngine:
         """Fill free slots from the queue's head; returns how many
         requests took a slot."""
         admitted = 0
-        if self.static_batching:
-            # batch-level scheduling (the A/B baseline): a new batch
-            # forms only once the previous one fully drained, then fills
-            # every slot it can in one go
-            with self._cond:
-                if any(s.active for s in self._slots):
-                    return admitted
         while True:
             with self._cond:
                 if not self._queue:
@@ -2596,7 +2553,6 @@ class DecodeEngine:
             sessions = len(self._sessions)
         out = {"slots": self.slots, "active": active, "queued": queued,
                "sessions": sessions, "steps": self.steps,
-               "static_batching": self.static_batching,
                "page_size": self.page_size,
                "pages_per_seq": self.pages_per_seq,
                "prefill_chunk": self.prefill_chunk,
@@ -2617,7 +2573,6 @@ class DecodeEngine:
                },
                "migration": {"enabled": self._migration_active(),
                              "pagestore": self._pagestore_addr or None},
-               "decode_fused": self.decode_fused_mode,
                "launches": dict(self.launch_stats),
                "fn_cache": _decoder.fn_cache_stats()}
         if self.sharding is not None:

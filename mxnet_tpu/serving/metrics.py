@@ -369,9 +369,9 @@ class ServingMetrics:
 
     def observe_decode_launches(self, name, stats):
         """Static launch census of the engine's decode step (see
-        models.decoder.decode_launch_stats): launches/step,
-        pallas_per_group — the _bulk-flush-counter analog for the decode
-        path; tests and bench rows assert on it."""
+        models.decoder.decode_launch_stats): launches and Pallas calls
+        per step — the _bulk-flush-counter analog for the decode path;
+        tests assert on it."""
         with self._lock:
             self._model(name).decode_launches = dict(stats)
 

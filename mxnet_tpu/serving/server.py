@@ -24,7 +24,7 @@ the exact exception class.
 
 The HTTP layer is intentionally thin: every concurrency decision
 (coalescing, shedding, deadlines) lives in the batcher, so in-process
-callers (``bench.py``) and HTTP callers get identical semantics.
+callers and HTTP callers get identical semantics.
 """
 from __future__ import annotations
 

@@ -133,12 +133,10 @@ def test_async_tp_bit_parity(lm):
 # ---------------------------------------------------------------------------
 def test_async_launch_census_identical_to_sync(lm):
     """Pipelining reorders launches; it must not change the static
-    decode program census (fused + tower counts) the tier-1 launch
-    gates pin down."""
+    decode program census the tier-1 launch gates pin down."""
     a = make_engine(lm, async_decode=True)
     s = make_engine(lm, async_decode=False)
     try:
-        assert a.decode_fused_mode == s.decode_fused_mode
         assert dict(a.launch_stats) == dict(s.launch_stats)
     finally:
         a.stop(drain=False)

@@ -1,4 +1,4 @@
-"""BERT model tests (BASELINE config #3 slice)."""
+"""BERT model tests (reference config #3 slice)."""
 import numpy as onp
 import pytest
 

@@ -152,22 +152,18 @@ def test_paged_attention_interpret_runs_the_kernel(monkeypatch):
 
 
 def test_kernel_gates_select_by_backend_alone(monkeypatch):
-    """No probe: on a TPU backend every gate answers "compiled", except
-    the decode cell's, which the v5e's compiler refuses."""
+    """No probe: on a TPU backend every gate answers "compiled"."""
     from mxnet_tpu.ops import attention
     from mxnet_tpu.ops.pallas import epilogue, quant_matmul
     for var in ("MXNET_FLASH_ATTENTION", "MXNET_EPILOGUE_KERNEL",
                 "MXNET_PAGED_ATTENTION", "MXNET_QUANT_MATMUL",
-                "MXNET_RNN_FUSED_CELL", "MXNET_DECODE_FUSED"):
+                "MXNET_RNN_FUSED_CELL"):
         monkeypatch.delenv(var, raising=False)
     gates = (attention._pallas_mode, epilogue._mode, paged._mode,
              quant_matmul.quant_mode, fused_cell.rnn_mode)
     assert [g() for g in gates] == [None] * len(gates)   # CPU backend
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert [g() for g in gates] == ["compiled"] * len(gates)
-    assert fused_cell.decode_mode() is None
-    monkeypatch.setenv("MXNET_DECODE_FUSED", "interpret")
-    assert fused_cell.decode_mode() == "interpret"     # the CPU oracle
 
 
 def test_compiled_lane_takes_a_kernel_only_where_it_compiles(monkeypatch):
@@ -217,19 +213,19 @@ def test_epilogue_kernel_is_not_handed_to_gspmd(monkeypatch):
             assert epilogue._mode() == "compiled"
 
 
-def test_engine_names_the_decode_program_it_runs(monkeypatch):
+def test_engine_names_the_decode_program_it_runs():
     from mxnet_tpu.serving import DecodeEngine
     lm = decoder.decoder_tiny_lm(seed=0)
-    for env, fused in (("0", None), ("interpret", "interpret")):
-        monkeypatch.setenv("MXNET_DECODE_FUSED", env)
-        eng = DecodeEngine(lm, slots=2, page_size=8, max_ctx=32)
-        try:
-            st = eng.stats()
-            assert st["decode_fused"] == fused      # None: the tower
-            assert st["launches"]["fused"] == (fused is not None)
-            assert not hasattr(eng, "_run_decode_fn")
-        finally:
-            eng.stop()
+    eng = DecodeEngine(lm, slots=2, page_size=8, max_ctx=32)
+    try:
+        # one decode program, the builders' own, and its census
+        assert eng._decode_fn is decoder.make_decode_step(lm.config, 8)
+        assert eng.stats()["launches"] == decoder.decode_launch_stats(
+            lm.jax_params(), lm.config, 8, 2, eng.pages_per_seq,
+            eng.alloc.total_pages)
+        assert not hasattr(eng, "_run_decode_fn")
+    finally:
+        eng.stop()
 
 
 # ---------------------------------------------------------------------------

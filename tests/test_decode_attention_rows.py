@@ -212,7 +212,7 @@ def test_decode_step_holds_no_context_with_the_head_axis_split_off(kv):
     sizes = (slots, pps, page, cfg.num_kv_heads, cfg.head_dim)
     args = decoder._decode_step_structs(
         lm.jax_params(), cfg, page, slots, pps, total, kv_dtype=kv)
-    step = decoder._build_decode_step(cfg, page).inner
+    step = decoder._build_decode_step(cfg, page)
     assert split_context_arrays(jax.make_jaxpr(step)(*args), sizes) == []
     pool = args[1]
     tables = jax.ShapeDtypeStruct((slots, pps), jnp.int32)
@@ -254,7 +254,7 @@ def test_decode_step_compiled_for_the_chip_never_splits_the_context(
         lm.jax_params(), cfg, page, slots, pps, slots * pps + 1)
     args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=one_chip), args)
-    hlo = decoder._build_decode_step(cfg, page).inner.lower(
+    hlo = decoder._build_decode_step(cfg, page).lower(
         *args).compile().as_text()
     assert "f32[1,2049,16,768]" in hlo          # the pool, as it lies
     want = sorted((slots, pps, page, heads, d))

@@ -1,12 +1,12 @@
-"""Persistent fused-cell Pallas kernels (ops/pallas/fused_cell):
+"""Persistent fused-cell Pallas kernel (ops/pallas/fused_cell):
 LSTM fused-vs-scan parity (fwd + grads, fp32/bf16), wavefront
 interaction, bidirectional fallback, hybridized end-to-end, trace
-signatures, the fused decode step, launch-census gates, and the bounded
-decode/prefill program cache.
+signatures, the launch-census gate, and the bounded decode/prefill
+program cache.
 
-The CPU lane runs the kernels in Pallas interpreter mode
-(MXNET_RNN_FUSED_CELL=interpret / MXNET_DECODE_FUSED=interpret) — the
-identical kernel code path the TPU compiles.
+The CPU lane runs the kernel in Pallas interpreter mode
+(MXNET_RNN_FUSED_CELL=interpret) — the identical kernel code path the
+TPU compiles.
 """
 import os
 
@@ -246,120 +246,13 @@ def test_scan_unroll_remainder_parity(monkeypatch, unroll):
 
 
 # ---------------------------------------------------------------------------
-# fused decode step
-# ---------------------------------------------------------------------------
-def _tiny_lm():
-    from mxnet_tpu.models import decoder as dec
-    return dec.decoder_tiny_lm(seed=0, vocab_size=64, num_layers=2,
-                               units=32, hidden_size=64, num_heads=4,
-                               num_kv_heads=2, max_length=64)
-
-
-@pytest.mark.parametrize("layer_group", [0, 1])
-def test_fused_decode_step_parity(layer_group):
-    """The fused layer-group kernel must reproduce the per-op decode
-    step: bit-identical KV writes, matching greedy tokens, logits to
-    f32 tolerance — including inactive (scratch-page) slots."""
-    from mxnet_tpu.models import decoder as dec
-    lm = _tiny_lm()
-    cfg, params = lm.config, lm.jax_params()
-    S, B, pps, total = 8, 4, 8, 16
-    kp0 = jax.random.normal(jax.random.key(1),
-                            (cfg.num_layers, cfg.num_kv_heads, total, S,
-                             cfg.head_dim)) * 0.2
-    vp0 = jax.random.normal(jax.random.key(2), kp0.shape) * 0.2
-    tables = onp.zeros((B, pps), onp.int32)
-    tables[0, :2] = [1, 2]
-    tables[1, 0] = 3
-    tables[2, :2] = [4, 5]
-    pt = jnp.asarray(tables)
-    tok = jnp.asarray(onp.array([5, 9, 11, 0], onp.int32))
-    pos = jnp.asarray(onp.array([9, 3, 11, 0], onp.int32))
-    act = jnp.asarray(onp.array([True, True, True, False]))
-    f_ref = dec.make_decode_step(cfg, S)
-    f_fus = dec.make_decode_step_fused(cfg, S, layer_group, "interpret")
-    k1, v1, n1, l1 = f_ref(params, jnp.copy(kp0), jnp.copy(vp0), tok,
-                           pos, pt, act)
-    k2, v2, n2, l2 = f_fus(params, jnp.copy(kp0), jnp.copy(vp0), tok,
-                           pos, pt, act)
-    onp.testing.assert_array_equal(onp.asarray(k1), onp.asarray(k2))
-    onp.testing.assert_array_equal(onp.asarray(v1), onp.asarray(v2))
-    a = onp.asarray(act)
-    onp.testing.assert_array_equal(onp.asarray(n1)[a], onp.asarray(n2)[a])
-    onp.testing.assert_allclose(onp.asarray(l1)[a], onp.asarray(l2)[a],
-                                rtol=1e-4, atol=1e-4)
-
-
-def test_decode_launch_census_collapse():
-    """The dispatch-count acceptance: the fused step issues ≤ 1 pallas
-    launch per layer group, and its launch-class total collapses vs the
-    per-op tower."""
-    from mxnet_tpu.models import decoder as dec
-    lm = _tiny_lm()
-    cfg, params = lm.config, lm.jax_params()
-    S, B, pps, total = 8, 4, 8, 16
-    tower = dec.decode_launch_stats(params, cfg, S, B, pps, total,
-                                    fused=False)
-    fused1 = dec.decode_launch_stats(params, cfg, S, B, pps, total,
-                                     fused=True, layer_group=0,
-                                     mode="interpret")
-    fused2 = dec.decode_launch_stats(params, cfg, S, B, pps, total,
-                                     fused=True, layer_group=1,
-                                     mode="interpret")
-    assert fused1["layer_groups"] == 1
-    assert fused1["pallas_per_group"] <= 1
-    assert fused2["layer_groups"] == cfg.num_layers
-    assert fused2["pallas_per_group"] <= 1
-    assert tower["pallas_per_step"] == 0
-    # the collapse itself: a whole layer's op tower folds into 1 launch
-    assert fused1["launches_per_step"] * 3 <= tower["launches_per_step"]
-
-
-def test_engine_fused_decode_end_to_end(monkeypatch):
-    """DecodeEngine under MXNET_DECODE_FUSED=interpret: same greedy
-    tokens as the per-op engine, and the launch census lands in
-    stats()/metrics ('≤ 1 launch per layer group per token')."""
-    from mxnet_tpu.serving.generate import DecodeEngine
-    lm = _tiny_lm()
-    prompts = [[1, 2, 3], [9, 8, 7, 6], [5]]
-
-    def run(env):
-        if env is None:
-            monkeypatch.delenv("MXNET_DECODE_FUSED", raising=False)
-        else:
-            monkeypatch.setenv("MXNET_DECODE_FUSED", env)
-        eng = DecodeEngine(lm, name="llm", slots=2, page_size=8,
-                           prefill_chunk=8, max_ctx=64)
-        futs = [eng.submit(p, max_new_tokens=6) for p in prompts]
-        toks = [f.result(timeout=120)["tokens"] for f in futs]
-        stats = eng.stats()
-        snap = eng.metrics.snapshot()["models"]["llm"]
-        eng.stop()
-        assert eng.alloc.num_used == 0
-        return toks, stats, snap
-
-    toks_ref, stats_ref, _ = run("0")
-    assert stats_ref["decode_fused"] is None
-    toks_fus, stats_fus, snap = run("interpret")
-    assert toks_fus == toks_ref
-    assert stats_fus["decode_fused"] == "interpret"
-    launches = stats_fus["launches"]
-    assert launches["fused"] is True
-    assert launches["pallas_per_group"] <= 1
-    assert launches["launches_per_step"] < \
-        stats_ref["launches"]["launches_per_step"]
-    gen = snap["generate"]
-    assert gen["decode_launches"]["pallas_per_group"] <= 1
-    assert gen["fn_cache"]["compiles"] >= 1
-
-
-# ---------------------------------------------------------------------------
 # bounded decode/prefill program cache (satellite)
 # ---------------------------------------------------------------------------
 def test_fn_cache_lru_eviction(monkeypatch):
     from mxnet_tpu.models import decoder as dec
-    lm = _tiny_lm()
-    cfg = lm.config
+    cfg = dec.decoder_tiny_lm(seed=0, vocab_size=64, num_layers=2,
+                              units=32, hidden_size=64, num_heads=4,
+                              num_kv_heads=2, max_length=64).config
     monkeypatch.setenv("MXNET_GEN_FN_CACHE", "2")
     dec._fn_cache.clear()
     try:
@@ -377,19 +270,20 @@ def test_fn_cache_lru_eviction(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# steplat tier-1 gate (satellite: CI asserts launches/step, not timings)
+# launch-census tier-1 gate (CI asserts launches, not timings)
 # ---------------------------------------------------------------------------
-def test_steplat_launch_gate():
-    import benchmark.steplat as steplat
-    lstm = steplat.lstm_steplat(T=12, B=2, I=8, H=8, L=2, measure=False,
-                                fused_mode="interpret")
-    # fused: exactly one persistent kernel per layer, and the per-step
-    # launch census collapses vs the scan tower
-    assert lstm["fused"]["pallas_total"] == 2
-    assert lstm["fused"]["launches_total"] * 2 \
-        <= lstm["scan"]["launches_total"]
-    dec = steplat.decode_steplat(measure=False, fused_mode="interpret",
-                                 slots=2, page_size=8)
-    assert dec["fused"]["pallas_per_group"] <= 1
-    assert dec["fused"]["launches_per_step"] * 3 \
-        <= dec["tower"]["launches_per_step"]
+def test_lstm_launch_gate():
+    T, B, I, H, L = 12, 2, 8, 8, 2
+    x, params, h0, c0 = _rand_lstm(T, B, I, H, L)
+
+    def census(fused):
+        jaxpr = jax.make_jaxpr(
+            lambda *a: _forward(*a, H, L, fused)[0])(x, params, h0, c0)
+        return fc.count_launches(jaxpr), fc.count_pallas_calls(jaxpr)
+
+    scan_launches, scan_pallas = census(None)
+    fused_launches, fused_pallas = census("interpret")
+    # fused: exactly one persistent kernel per layer, and the launch
+    # census collapses vs the scan tower
+    assert (scan_pallas, fused_pallas) == (0, L)
+    assert fused_launches * 2 <= scan_launches

@@ -348,19 +348,12 @@ def test_engine_refuses_by_name(lm, what, kwargs):
         make_engine(lm, **kwargs)
 
 
-def test_engine_refuses_the_fused_decode_cell(lm, monkeypatch):
-    monkeypatch.setenv("MXNET_DECODE_FUSED", "interpret")
-    with pytest.raises(ValueError, match="fused decode cell"):
-        make_engine(lm)
-
-
 @pytest.mark.parametrize("build", [
     lambda cfg: decoder.make_verify_step(cfg, 4, 3),
-    lambda cfg: decoder.make_decode_step_fused(cfg, 4),
     lambda cfg: decoder.make_decode_step(cfg, 4, kv_dtype="int8"),
     lambda cfg: decoder.make_prefill_chunk(cfg, 4, 8, quant=("int8",)),
     lambda cfg: decoder.make_decode_step(cfg, 4, sharding=tp2()),
-], ids=["verify", "fused", "int8_kv", "quant", "tp"])
+], ids=["verify", "int8_kv", "quant", "tp"])
 def test_program_factories_refuse_by_name(lm, build):
     with pytest.raises(ValueError, match="state-space layers"):
         build(lm.config)

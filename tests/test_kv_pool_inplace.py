@@ -105,13 +105,13 @@ def old_formulation(monkeypatch):
 
 
 def build(cfg, which):
-    """The un-cached inner program of ``which``: traced when first called,
+    """The un-cached program of ``which``: traced when first called,
     so under ``old_formulation`` it is the old one."""
     if which == "decode":
-        return decoder._build_decode_step(cfg, S).inner
+        return decoder._build_decode_step(cfg, S)
     if which == "prefill":
-        return decoder._build_prefill_chunk(cfg, S, CHUNK).inner
-    return decoder._build_verify_step(cfg, S, W).inner
+        return decoder._build_prefill_chunk(cfg, S, CHUNK)
+    return decoder._build_verify_step(cfg, S, W)
 
 
 def random_pools(cfg, kv, seed):
@@ -253,10 +253,10 @@ def test_pool_forms_round_trip(lm):
     assert fresh.q.dtype == jnp.int8 and float(fresh.s.min()) == 1.0
 
 
-def test_factories_take_a_hand_built_pages_pool(lm):
-    """What the benchmark's reference check does: a pages-form pool of
-    zeros into the cached programs; rows form comes back, is fed back,
-    and says what a rows-form pool says."""
+def test_factories_take_a_converted_pages_pool(lm):
+    """The cached programs take rows form only.  A caller that holds a
+    pages-form pool converts it itself (`rows_from_pages`); rows form
+    comes back, is fed back, and says what a fresh pool says."""
     cfg, params = lm.config, lm.jax_params()
     prefill = decoder.make_prefill_chunk(cfg, S, CHUNK)
     decode = decoder.make_decode_step(cfg, S)
@@ -264,8 +264,8 @@ def test_factories_take_a_hand_built_pages_pool(lm):
     results = []
     for hand_built in (True, False):
         if hand_built:
-            kp, vp = jnp.zeros(shape, jnp.float32), jnp.zeros(shape,
-                                                              jnp.float32)
+            kp, vp = (decoder.rows_from_pages(jnp.zeros(shape, jnp.float32))
+                      for _ in range(2))
         else:
             kp, vp = (decoder.fresh_pool(cfg, TOTAL, S) for _ in range(2))
         kp, vp, tok, last = prefill(params, kp, vp,

@@ -152,9 +152,8 @@ def test_paged_decode_bit_exact_vs_full_cache():
         S = page_size
         pps = max_ctx // S
         total = pps + 1  # one sequence + the scratch page
-        shape = (cfg.num_layers, cfg.num_kv_heads, total, S, cfg.head_dim)
-        kp = jnp.zeros(shape, jnp.float32)
-        vp = jnp.zeros(shape, jnp.float32)
+        kp = decoder.fresh_pool(cfg, total, S)
+        vp = decoder.fresh_pool(cfg, total, S)
         row = onp.arange(1, pps + 1, dtype=onp.int32)
         prefill = decoder.make_prefill_chunk(cfg, S, 8)
         step = decoder.make_decode_step(cfg, S)
@@ -264,15 +263,25 @@ def test_chunked_prefill_does_not_stall_decode(lm):
 
 
 def test_eos_eviction_frees_pages(lm):
-    # seed-0 greedy decode converges to token 41: make that EOS
+    prompt = [1, 2, 3, 4, 5]
+    # which tokens the model emits is the weights' business: take the EOS
+    # id from a run without one, the token whose first appearance is the
+    # latest, so the second run stops exactly there
+    eng = make_engine(lm, prefix_cache=False)
+    try:
+        free = eng.submit(prompt, max_new_tokens=30).result(
+            timeout=120)["tokens"]
+    finally:
+        assert eng.stop()
+    k = max(i for i in range(len(free)) if free[i] not in free[:i])
+    eos = free[k]
     # prefix_cache off: this test asserts num_used == 0 after eviction;
     # cache-held prefix pages are legitimate retained state, not a leak
-    eng = make_engine(lm, eos_id=41, prefix_cache=False)
+    eng = make_engine(lm, eos_id=eos, prefix_cache=False)
     try:
-        res = eng.submit([1, 2, 3, 4, 5], max_new_tokens=30).result(
-            timeout=120)
+        res = eng.submit(prompt, max_new_tokens=30).result(timeout=120)
         assert res["finish_reason"] == "eos"
-        assert res["tokens"][-1] == 41
+        assert res["tokens"] == free[:k + 1]
         assert len(res["tokens"]) < 30
         deadline = time.time() + 5
         while eng.alloc.num_used and time.time() < deadline:
@@ -301,29 +310,6 @@ def test_preemption_under_page_pressure(lm):
         assert eng.stop()
     assert eng.alloc.num_used == 0
     eng.alloc.check_leaks()
-
-
-def test_static_batching_same_tokens_lower_occupancy(lm):
-    """The A/B baseline: batch-level scheduling produces the SAME tokens
-    (scheduling must never change results) at worse decode occupancy —
-    one long request pins a static batch while its siblings' slots sit
-    dead; continuous batching refills them every step."""
-    reqs = [([1, 2], 40)] + [([i + 2, i + 3], 4) for i in range(10)]
-
-    def run(static):
-        eng = make_engine(lm, slots=3, static_batching=static)
-        try:
-            futs = [eng.submit(p, max_new_tokens=n) for p, n in reqs]
-            outs = [f.result(timeout=180)["tokens"] for f in futs]
-            snap = eng.metrics.snapshot()["models"]["llm"]
-            return outs, snap["generate"]["decode_occupancy"]
-        finally:
-            assert eng.stop()
-
-    toks_c, occ_c = run(static=False)
-    toks_s, occ_s = run(static=True)
-    assert toks_c == toks_s
-    assert occ_c > occ_s, (occ_c, occ_s)
 
 
 # ---------------------------------------------------------------------------

@@ -86,21 +86,35 @@ def agreement(a, b):
     return hit / max(tot, 1)
 
 
-def tf_agreement(eng, fp_tokens, prompts=PROMPTS, max_ctx=64):
+# The int8 rungs are held to the float32 engine where float32 itself
+# decides: the battery's float32 sequences are runs of one repeated token
+# (random weights), and 4 of their 120 positions are near-ties whose top
+# two logits lie 0.016-0.099 of the row's std apart.  The int8 engine's
+# logits stand 0.108-0.155 of the std off the float32 reference (PERF.md
+# section 6, PR 28, at GPT-2-small's sizes), so below that margin the two
+# engines may name either token and neither is wrong.
+INT8_LOGITS_ERR = 0.155
+
+
+def tf_agreement(eng, fp_tokens, prompts=PROMPTS, max_ctx=64, decided=None):
     """Teacher-forced greedy agreement: for every position of the fp
     engine's trajectories, ask ``eng`` for ONE next token off the same
     prefix and compare.  Free-running comparison is the wrong oracle
     for a quantized engine — a single near-tie flip cascades the rest
     of the trajectory into a different attractor, so one flipped token
     would read as ~17% disagreement.  Per-step agreement is what the
-    quantization actually changes."""
+    quantization actually changes.  ``decided`` (one list of bools per
+    prompt, `fp_decided`) leaves out the positions float32 does not
+    decide."""
     futs, want = [], []
-    for p, t in zip(prompts, fp_tokens):
+    for n, (p, t) in enumerate(zip(prompts, fp_tokens)):
         hist = list(p) + t
         for i in range(len(t)):
             pre = hist[:len(p) + i]
             if len(pre) + 1 > max_ctx:
                 break
+            if decided is not None and not decided[n][i]:
+                continue
             futs.append(eng.submit(pre, 1))
             want.append(t[i])
     got = [f.result(timeout=300)["tokens"][0] for f in futs]
@@ -114,6 +128,24 @@ def fp_tokens(lm):
         return run_battery(eng)
     finally:
         eng.stop()
+
+
+@pytest.fixture(scope="module")
+def fp_decided(lm, fp_tokens):
+    """Per position of `fp_tokens`: the float32 forward's top two logits
+    lie further apart than the int8 engine's error."""
+    params, cfg = lm.jax_params(), lm.config
+    out = []
+    for p, t in zip(PROMPTS, fp_tokens):
+        logits = onp.asarray(decoder.full_forward(
+            params, cfg, jnp.asarray([list(p) + t], jnp.int32)))[0]
+        rows = logits[len(p) - 1:len(p) - 1 + len(t)]
+        top2 = onp.sort(rows, axis=-1)[:, -2:]
+        out.append(list((top2[:, 1] - top2[:, 0]) / rows.std(axis=-1)
+                        > INT8_LOGITS_ERR))
+    # the bar must not empty the battery: 116 of 120 positions stand
+    assert sum(map(sum, out)) >= 0.9 * sum(map(len, fp_tokens))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -200,11 +232,24 @@ def test_quantize_lm_wrapper(lm):
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("mode", ["int8", "int4"])
 def test_quant_matmul_interpret_bit_exact(monkeypatch, mode):
+    # Inputs on which every float32 product and partial sum is exact, so
+    # the bytes do not depend on the order in which a host's dot adds
+    # them: whole-number activations, and weights that are whole numbers
+    # times a power of two with the full range in every row / group (the
+    # quantizer's amax / 127 or amax / 7 is then that power of two and the
+    # codes are the whole numbers back).
     rng = onp.random.RandomState(2)
-    x = jnp.asarray(rng.randn(4, 64).astype("float32"))
-    w = rng.randn(48, 64).astype("float32")
+    x = jnp.asarray(rng.randint(-8, 9, (4, 64)).astype("float32"))
+    top, group = (127, 64) if mode == "int8" else (7, 16)
+    codes = rng.randint(-top, top + 1, (48, 64 // group, group))
+    codes[:, :, 0] = top
+    scale = 2.0 ** rng.randint(-3, 4, (48, 64 // group, 1))
+    w = (codes * scale).reshape(48, 64).astype("float32")
     qw = (qmm.quantize_w8(w) if mode == "int8"
           else qmm.quantize_w4(w, group=16))
+    onp.testing.assert_array_equal(onp.asarray(qw.s).reshape(scale.shape),
+                                   scale)
+    onp.testing.assert_array_equal(onp.asarray(qmm.dequantize_weight(qw)), w)
     ref = qmm.quant_matmul_reference(x, qw)
     monkeypatch.setenv("MXNET_QUANT_MATMUL", "interpret")
     before = qmm.trace_counts["quant_matmul"]
@@ -212,6 +257,8 @@ def test_quant_matmul_interpret_bit_exact(monkeypatch, mode):
     assert qmm.last_path == "pallas-interpret"
     assert qmm.trace_counts["quant_matmul"] == before + 1
     assert onp.asarray(out).tobytes() == onp.asarray(ref).tobytes()
+    assert onp.array_equal(onp.asarray(ref),
+                           onp.asarray(x, "float64") @ w.T.astype("float64"))
     # leading dims flow through
     x3 = jnp.asarray(rng.randn(2, 3, 64).astype("float32"))
     assert qmm.quant_matmul(x3, qw).shape == (2, 3, 48)
@@ -253,13 +300,13 @@ def test_engine_bit_parity_with_quantized_oracle(lm, mode, group):
     eng.alloc.check_leaks()
 
 
-def test_int8_engine_agreement_battery(lm, fp_tokens):
+def test_int8_engine_agreement_battery(lm, fp_tokens, fp_decided):
     """The serving acceptance gate: int8 weights + int8 KV pages agree
-    with the fp32 engine on >= 99% of greedy tokens across the
-    battery."""
+    with the fp32 engine on >= 99% of the greedy tokens that float32
+    decides (`INT8_LOGITS_ERR`) across the battery."""
     eng = make_engine(lm, quantize="int8", kv_dtype="int8")
     try:
-        score = tf_agreement(eng, fp_tokens)
+        score = tf_agreement(eng, fp_tokens, decided=fp_decided)
         st = eng.stats()
     finally:
         eng.stop()
@@ -281,11 +328,11 @@ def test_int4_engine_agreement_battery(lm, fp_tokens):
     eng.alloc.check_leaks()
 
 
-def test_int8_kv_only_agreement(lm, fp_tokens):
+def test_int8_kv_only_agreement(lm, fp_tokens, fp_decided):
     # kv_dtype=int8 with fp weights: per-page scale latch alone
     eng = make_engine(lm, kv_dtype="int8")
     try:
-        score = tf_agreement(eng, fp_tokens)
+        score = tf_agreement(eng, fp_tokens, decided=fp_decided)
         st = eng.stats()
     finally:
         eng.stop()
@@ -293,17 +340,6 @@ def test_int8_kv_only_agreement(lm, fp_tokens):
     assert st["quant"]["weights"] is None
     assert st["quant"]["kv_dtype"] == "int8"
     eng.alloc.check_leaks()
-
-
-def test_quantized_decode_not_fused(lm, monkeypatch):
-    # the fused decode cell is an fp-weight program: quantized engines
-    # must fall back to the tower path even if fusion is requested
-    monkeypatch.setenv("MXNET_DECODE_FUSED", "interpret")
-    eng = make_engine(lm, quantize="int8")
-    try:
-        assert eng.decode_fused_mode is None
-    finally:
-        eng.stop()
 
 
 # ---------------------------------------------------------------------------
@@ -574,18 +610,21 @@ def test_replica_resolve_quant_block():
         == {"quantize": "int4", "quant_group": 64}
 
 
-def test_steplat_census_quant_arm_and_fp_fused_unchanged():
-    """The dispatch-bill gate the bench row pins: the quantized decode
-    step runs the per-op tower (the fused cell is an fp-weight
-    program), and the fp fused path keeps its historical 6-launch
-    program — the quant code paths must not perturb it."""
-    from benchmark.steplat import decode_steplat
-    d = decode_steplat(measure=False, fused_mode="interpret")
-    assert d["fused"]["launches_per_step"] == 6
-    assert d["fused"]["pallas_per_group"] == 1.0
-    assert d["quant_int8"]["fused"] is False
-    assert d["quant_int8"]["launches_per_step"] > 0
-    assert d["quant_int8"]["pallas_per_step"] == 0  # CPU: XLA reference
+def test_launch_census_quant_arm(lm):
+    """The dispatch bill of the quantized decode step: int8 weights
+    dequantize inside the GEMMs the float tower already launches (same
+    count), int8 KV pages add the scale latch, and on the CPU every GEMM
+    is the XLA reference (no Pallas call)."""
+    cfg, geometry = lm.config, (8, 4, 8, 33)
+    fp = decoder.decode_launch_stats(lm.jax_params(), cfg, *geometry)
+    qparams = quantize_lm(lm, "int8").jax_params()
+    w8 = decoder.decode_launch_stats(qparams, cfg, *geometry,
+                                     quant=("int8",))
+    w8kv8 = decoder.decode_launch_stats(qparams, cfg, *geometry,
+                                        quant=("int8",), kv_dtype="int8")
+    assert w8 == fp and fp["launches_per_step"] > 0
+    assert w8kv8["launches_per_step"] > fp["launches_per_step"]
+    assert w8kv8["pallas_per_step"] == 0  # CPU: XLA reference
 
 
 def test_calibrate_kv_ranges_diagnostic(lm):

@@ -156,7 +156,7 @@ def test_bidirectional_cell():
 
 @pytest.mark.slow
 def test_lstm_lm_trains():
-    """LSTM language-model slice (BASELINE config #5 shape)."""
+    """LSTM language-model slice (reference config #5 shape)."""
     V, E, H, T, B = 20, 8, 16, 6, 4
     net = nn.HybridSequential()
 

@@ -11,17 +11,13 @@ traffic.  Oracles on the 8-fake-device lane:
   prefill, preemption-by-recompute, and prefix-cache-on runs;
 - step-fn logits within the 1e-4 band of the unsharded builders;
 - collective census: all-reduce ONLY (2 per layer), invariant to batch
-  size (tower and fused variants);
+  size;
 - per-shard launch census identical to the 1-chip program (sharding
   must not change what each chip dispatches);
 - a mesh that cannot shard the geometry (GQA kv_heads % tp != 0) warns
   loudly and serves replicated — never silently wrong.
 """
 from __future__ import annotations
-
-import os
-import sys
-
 import numpy as onp
 import pytest
 
@@ -88,7 +84,7 @@ def test_tp_plan_resolves_megatron_layout(eight_devices, lm):
     assert plan.local_cfg.num_kv_heads == lm.config.num_kv_heads // 2
     assert plan.local_cfg.hidden_size == lm.config.hidden_size // 2
     assert plan.local_cfg.head_dim == lm.config.head_dim
-    assert tuple(plan.kv_spec) == (None, "tp", None, None, None)
+    assert tuple(plan.kv_rows_spec) == (None, None, None, "tp")
 
 
 def test_tp_plan_none_without_tp_axis(eight_devices, lm):
@@ -116,10 +112,8 @@ def test_tp_plan_refuses_what_it_cannot_shard(eight_devices, lm):
 # step-fn parity (logits band) + census gates
 # ---------------------------------------------------------------------------
 def _struct_args(cfg, page_size, slots, pps, total):
-    shape = (cfg.num_layers, cfg.num_kv_heads, total, page_size,
-             cfg.head_dim)
-    kp = jnp.zeros(shape, jnp.float32)
-    return kp, jnp.zeros(shape, jnp.float32)
+    return (decoder.fresh_pool(cfg, total, page_size),
+            decoder.fresh_pool(cfg, total, page_size))
 
 
 def test_decode_step_logits_band(eight_devices, lm):
@@ -151,20 +145,18 @@ def test_collective_census_all_reduce_only_and_batch_invariant(
         eight_devices, lm):
     cfg, params = lm.config, lm.jax_params()
     page, pps = 8, 8
-    seen = {}
-    for fused in (False, True):
-        for slots in (4, 8):
-            stats = decoder.decode_collective_stats(
-                params, cfg, page, slots, pps, slots * pps + 1,
-                tp_config(), fused=fused, mode="interpret")
-            c = stats["collectives"]
-            # 2 all-reduces per layer: proj + ffn2 row-parallel sums
-            assert c["all-reduce"] == 2 * cfg.num_layers, (fused, c)
-            bad = {k: v for k, v in c.items()
-                   if k not in ("all-reduce", "total") and v}
-            assert not bad, (fused, bad)
-            seen.setdefault(fused, []).append(c)
-        assert seen[fused][0] == seen[fused][1], seen[fused]
+    seen = []
+    for slots in (4, 8):
+        stats = decoder.decode_collective_stats(
+            params, cfg, page, slots, pps, slots * pps + 1, tp_config())
+        c = stats["collectives"]
+        # 2 all-reduces per layer: proj + ffn2 row-parallel sums
+        assert c["all-reduce"] == 2 * cfg.num_layers, c
+        bad = {k: v for k, v in c.items()
+               if k not in ("all-reduce", "total") and v}
+        assert not bad, bad
+        seen.append(c)
+    assert seen[0] == seen[1], seen
 
 
 def test_launch_census_per_shard_unchanged(eight_devices, lm):
@@ -175,10 +167,9 @@ def test_launch_census_per_shard_unchanged(eight_devices, lm):
     page, slots, pps = 8, 4, 8
     total = slots * pps + 1
     ref = decoder.decode_launch_stats(params, cfg, page, slots, pps,
-                                      total, fused=False)
+                                      total)
     tp = decoder.decode_launch_stats(params, cfg, page, slots, pps,
-                                     total, fused=False,
-                                     sharding=tp_config())
+                                     total, sharding=tp_config())
     assert tp["launches_per_step"] == ref["launches_per_step"], (ref, tp)
 
 
@@ -262,19 +253,6 @@ def test_tp_engine_prefix_cache_parity(eight_devices, lm):
     eng.alloc.check_leaks()
 
 
-def test_tp_engine_fused_decode_parity(eight_devices, lm, monkeypatch):
-    """The PR-8 persistent kernel under TP: attn-phase + ffn-phase
-    Pallas launches per layer with the psum between them in XLA."""
-    rng = onp.random.RandomState(4)
-    reqs = [(list(rng.randint(1, VOCAB, size=rng.randint(2, 10))),
-             int(rng.randint(4, 12))) for _ in range(5)]
-    ref, _, _ = run_workload(lm, reqs)
-    monkeypatch.setenv("MXNET_DECODE_FUSED", "interpret")
-    tp, _, eng = run_workload(lm, reqs, sharding=tp_config())
-    assert eng.decode_fused_mode == "interpret"
-    assert tp == ref
-
-
 def test_tp_engine_kv_pages_head_sharded(eight_devices, lm):
     eng = make_engine(lm, sharding=tp_config())
     try:
@@ -331,7 +309,7 @@ def test_tp_engine_session_roundtrip(eight_devices, lm):
 
 
 # ---------------------------------------------------------------------------
-# metrics / fleet plumbing / steplat gate
+# metrics / fleet plumbing
 # ---------------------------------------------------------------------------
 def test_metrics_report_mesh_and_collectives_at_attach(eight_devices, lm):
     """Satellite: the census lands in the metrics snapshot at engine
@@ -380,21 +358,3 @@ def test_fleet_stamps_mesh_env(eight_devices):
     assert env1["MXNET_MESH_SHAPE"] == "1,2"
     assert env1["MXNET_MESH_AXES"] == "dp,tp"
     assert "--xla_force_host_platform_device_count=2" in env1["XLA_FLAGS"]
-
-
-def test_steplat_decode_tp_census_gate(eight_devices):
-    """Tier-1 gate over benchmark/steplat.py's TP census: all-reduce
-    only, batch-invariant, both decode variants."""
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "benchmark"))
-    try:
-        import steplat
-    finally:
-        sys.path.pop(0)
-    row = steplat.decode_tp_steplat()
-    assert row["tp"] == 2
-    assert row["batch_invariant"] is True
-    for variant in ("tower", "fused"):
-        c = row[variant]["collectives"]
-        assert c["all-reduce"] == 2 * row["num_layers"], (variant, c)
-        assert c["total"] == c["all-reduce"], (variant, c)

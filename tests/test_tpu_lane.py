@@ -474,83 +474,6 @@ def test_paged_attention_kernel_on_chip(head_dim):
                                 rtol=2e-2, atol=2e-2)
 
 
-def _decoder_params(cfg, seed=0):
-    rs = onp.random.RandomState(seed)
-
-    def w(*shape):
-        return jnp.asarray(rs.randn(*shape).astype("float32")
-                           * (1.0 / onp.sqrt(shape[-1])))
-
-    C, Hd = cfg.units, cfg.hidden_size
-    kvu = cfg.num_kv_heads * cfg.head_dim
-    layers = []
-    for _ in range(cfg.num_layers):
-        layers.append({
-            "wq": w(C, C), "bq": w(C), "wk": w(kvu, C), "bk": w(kvu),
-            "wv": w(kvu, C), "bv": w(kvu), "wo": w(C, C), "bo": w(C),
-            "w1": w(Hd, C), "b1": w(Hd), "w2": w(C, Hd), "b2": w(C),
-            "ln1g": jnp.ones(C), "ln1b": jnp.zeros(C),
-            "ln2g": jnp.ones(C), "ln2b": jnp.zeros(C)})
-    return {"embed": w(cfg.vocab_size, C), "pos": w(cfg.max_length, C),
-            "layers": layers}
-
-
-_DECODE_CELL = (
-    "decode_layer_group on the v5e (jax 0.9.0, PR 21), after the erf and "
-    "stacked-vector block refusals were repaired: MosaicError: INTERNAL: "
-    "Mosaic failed to compile TPU kernel: infer-vector-layout: unsupported "
-    "shape cast. The MLIR operation involved: \"tpu.reshape\" %s (the "
-    "head split in the kernel's body)")
-
-
-@pytest.mark.parametrize("geometry", [
-    pytest.param("small", marks=pytest.mark.xfail(
-        strict=True, reason=_DECODE_CELL
-        % "(vector<4x128xf32>) -> vector<4x2x2x32xf32>")),
-    pytest.param("gpt2_small", marks=pytest.mark.xfail(
-        strict=True, reason=_DECODE_CELL
-        % "(vector<8x768xf32>) -> vector<8x12x1x64xf32>"))])
-def test_fused_decode_cell_on_chip(geometry):
-    """decode_layer_group compiled, one decode step against the per-op
-    step on the same pages: small (the CPU tests' width) and GPT-2-small
-    (768 units, 12 heads of 64, 8 slots x 1024 context; depth cut to 2,
-    the kernel's VMEM plan is per layer, and was never reached).  The
-    engine never selects the cell on a TPU (fused_cell.decode_mode);
-    this is the record of why."""
-    from mxnet_tpu.models import decoder
-    from mxnet_tpu.ops.pallas import fused_cell
-    if geometry == "small":
-        cfg = decoder.DecoderConfig(512, 2, 128, 256, 4, 2, 32, 128)
-        slots, S, ctx = 4, 8, 128
-    else:
-        cfg = decoder.DecoderConfig(512, 2, 768, 3072, 12, 12, 64, 1024)
-        slots, S, ctx = 8, 16, 1024
-    pps = ctx // S
-    P = slots * pps + 1
-    rs = onp.random.RandomState(1)
-    params = _decoder_params(cfg)
-    shape = (cfg.num_layers, cfg.num_kv_heads, P, S, cfg.head_dim)
-    kv = rs.randn(2, *shape).astype("float32")
-    positions = jnp.asarray(rs.randint(1, ctx - 1, size=slots), jnp.int32)
-    tokens = jnp.asarray(rs.randint(0, cfg.vocab_size, size=slots),
-                         jnp.int32)
-    tables = jnp.asarray(
-        rs.permutation(onp.arange(1, P)).reshape(slots, pps), jnp.int32)
-    active = jnp.ones(slots, bool)
-
-    def run(fn):
-        kp, vp, _, logits = fn(params, jnp.asarray(kv[0]),
-                               jnp.asarray(kv[1]), tokens, positions,
-                               tables, active)
-        return onp.asarray(kp), onp.asarray(vp), onp.asarray(logits)
-
-    ref = run(decoder.make_decode_step(cfg, S))
-    got = run(decoder.make_decode_step_fused(cfg, S, 0, "compiled"))
-    assert fused_cell.last_path == "pallas"
-    for a, b in zip(got, ref):
-        onp.testing.assert_allclose(a, b, rtol=3e-2, atol=3e-2)
-
-
 def test_lstm_sequence_kernel_on_chip():
     """The persistent LSTM cell at the word-LM width (H=650: not a
     multiple of the 128-lane tile), forward and backward against the
@@ -622,8 +545,7 @@ def test_splash_causal_kernel_on_chip():
 
 def test_engine_runs_the_decode_program_it_selected_on_chip():
     """DecodeEngine on the chip: the program named in stats() is the one
-    its step traced and ran (the per-op tower: the decode cell is not
-    selected on a TPU), and its greedy tokens are the oracle's."""
+    its step traced and ran, and its greedy tokens are the oracle's."""
     from mxnet_tpu.models import decoder
     from mxnet_tpu.ops.pallas import epilogue, paged_attention
     from mxnet_tpu.serving import DecodeEngine
@@ -635,8 +557,6 @@ def test_engine_runs_the_decode_program_it_selected_on_chip():
         st = eng.stats()
     finally:
         eng.stop()
-    assert st["decode_fused"] is None
-    assert st["launches"]["fused"] is False
     # head_dim 16: attention reads through the gather, bias_gelu is Pallas
     assert paged_attention.last_path == "xla"
     assert epilogue.last_path == "pallas"

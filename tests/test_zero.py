@@ -3,9 +3,10 @@
 Covers: slot_spec/zero_dim placement units (first dp-divisible dim, tp
 composition, the slot0::/slot1:: checkpoint-name routing), the zero/remat
 knob surface (validation, env seeding, to_dict/shrink_to round-trip), the
-tentpole bit-identity matrix — zero ∈ {0,1} x remat ∈ {off, attention,
-tokens} trains BIT-identically (losses AND params, 3 adam steps) on the
-8-fake-device lane, with zero-3 keeping params sharded at rest — the
+tentpole identity matrix — zero ∈ {0,1} trains BIT-identically (losses
+AND params, 3 adam steps) on the 8-fake-device lane and remat ∈
+{attention, tokens} to a few float32 ulps of a gradient, with zero-3
+keeping params sharded at rest — the
 static collective-census gates (zero-1 dp grad comm is reduce-scatter +
 all-gather, one per sharded param; counts batch-invariant; zero-0
 unchanged), the remat residual proof (saved_residuals shrink + remat2 in
@@ -14,6 +15,8 @@ bucketed pushpull with a warning; comm_stats reports zero_stage), and
 the format-2 sharded checkpoint round-trip of dp-sharded slot slabs
 (same mesh and shrunken mesh).
 """
+import re
+
 import numpy as onp
 import pytest
 
@@ -183,12 +186,25 @@ def test_zero_remat_matrix_bit_identical(eight_devices, baseline_run,
                                          zero, remat):
     l0, p0 = baseline_run[0], baseline_run[1]
     l1, p1, _st, _step, _cfg = _train(zero, remat)
-    assert l0 == l1, (zero, remat, l0, l1)
     assert p0.keys() == p1.keys()
+    if remat is None:
+        # ZeRO moves where the state lives, not what is computed: bytes
+        assert l0 == l1, (zero, remat, l0, l1)
+        rtol = atol = 0.0
+    else:
+        # remat computes the backward from a forward XLA compiles anew, and
+        # a sum taken in another order differs in the last bit of a float32
+        # gradient.  Adam divides by sqrt(v): where a gradient is small the
+        # step moves by more than that bit.  Seen after 3 steps of 1e-2:
+        # every parameter differs somewhere, most in ffn.ffn1.weight by
+        # 1.26e-6 absolute; the three losses are the same bytes.  A wrong
+        # recomputation is off by a step, 1e-2: the bar is a thousandth.
+        rtol, atol = 1e-6, 1e-5
+        onp.testing.assert_allclose(l0, l1, rtol=rtol, atol=0)
     for k in p0:
-        onp.testing.assert_array_equal(p0[k], p1[k],
-                                       err_msg="%s (zero=%s remat=%s)"
-                                       % (k, zero, remat))
+        onp.testing.assert_allclose(p1[k], p0[k], rtol=rtol, atol=atol,
+                                    err_msg="%s (zero=%s remat=%s)"
+                                    % (k, zero, remat))
 
 
 def test_zero1_slots_dp_sharded(eight_devices, baseline_run):
@@ -249,7 +265,7 @@ def test_zero1_aux_state_not_supported(eight_devices):
 # ---------------------------------------------------------------------------
 # census gates: the static layout proof (tier-1, load-independent)
 # ---------------------------------------------------------------------------
-def _dense_step_census(cfg, B=8, units=32, opt="sgd"):
+def _dense_step_lowered(cfg, B=8, units=32, opt="sgd"):
     mx.random.seed(2)
     net = nn.HybridSequential()
     net.add(nn.Dense(units, activation="relu", flatten=False,
@@ -263,8 +279,25 @@ def _dense_step_census(cfg, B=8, units=32, opt="sgd"):
     state = tr.init_state()
     step = tr.build_step(donate=False)
     xb = x._data
-    return collective_census(step.lower(
-        state, xb, jnp.zeros_like(xb), jax.random.key(0), jnp.float32(0.1)))
+    return step.lower(
+        state, xb, jnp.zeros_like(xb), jax.random.key(0), jnp.float32(0.1))
+
+
+def _dense_step_census(cfg, **kw):
+    return collective_census(_dense_step_lowered(cfg, **kw))
+
+
+def _all_reduced_shapes(lowered):
+    """Every array that passes through an all-reduce of the optimized
+    HLO.  The instruction count is the compiler's: its combiner leaves one
+    all-reduce per array on the chip (CHANGES.md, PR 21) and makes one of
+    all five on this CPU pipeline.  What is reduced is the program's."""
+    shapes = []
+    for line in lowered.compile().as_text().splitlines():
+        if shardcfg._hlo_opcode(line) in ("all-reduce", "all-reduce-start"):
+            result = line.partition(" = ")[2].partition(" all-reduce")[0]
+            shapes += re.findall(r"[a-z]+[0-9]+\[[0-9,]*\]", result)
+    return sorted(shapes)
 
 
 def test_census_zero1_reduce_scatter_all_gather_only(eight_devices):
@@ -283,13 +316,17 @@ def test_census_zero1_reduce_scatter_all_gather_only(eight_devices):
 
 def test_census_zero1_unshardable_param_allreduced(eight_devices):
     """A param with no dp-divisible dim keeps the psum'd replicated
-    update — one extra all-reduce, visible in the census."""
+    update: its gradient is all-reduced whole, and no reduce-scatter or
+    all-gather touches it."""
     cfg = ShardingConfig(mesh_shape=(8,), axis_names=("dp",), zero=1)
-    c = _dense_step_census(cfg, units=6)  # (6,6) weights, (6,) biases
+    low = _dense_step_lowered(cfg, units=6)  # (6,6) weights, (6,) biases
+    c = collective_census(low)
     # weights/biases of size 6: nothing divides by 8 -> all 4 params
-    # replicated, 4 grad all-reduces + 1 loss all-reduce
+    # replicated, 4 gradients + the scalar loss all-reduced
     assert c["reduce-scatter"] == 0 and c["all-gather"] == 0, c
-    assert c["all-reduce"] == 5, c
+    assert 1 <= c["all-reduce"] <= 5, c
+    assert _all_reduced_shapes(low) == sorted(
+        ["f32[6,6]", "f32[6]", "f32[6,6]", "f32[6]", "f32[]"])
 
 
 def test_census_zero1_batch_invariant(eight_devices):
