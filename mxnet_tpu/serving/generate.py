@@ -33,6 +33,8 @@ The Orca + vLLM serving recipe, grown onto this repo's serving stack:
   trailing partial page is forked copy-on-write before its first write
   lands.  N users sharing a system prompt pay its prefill once
   (``prefix_hits`` / ``prefix_tokens_saved`` / ``cow_forks`` metrics).
+  The pages the cache keeps count as used until an allocation takes
+  back those nothing else refers to (``kv_reclaimed_pages``).
 - **Session migration** (``MXNET_GEN_MIGRATE`` +
   ``MXNET_GEN_PAGESTORE``) — sessions outlive their replica.  Every
   park synchronously pushes the session's replay transcript to the
@@ -434,6 +436,7 @@ class DecodeEngine:
         self._parts = dict.fromkeys(ModelMetrics.STEP_PARTS, 0.0)
         self._inner_s = 0.0
         self._prefill_launches = 0
+        self._reclaimed_seen = 0   # of alloc's "reclaimed", already counted
 
         # prefix caching + session migration + role specialization
         self.role = str(role if role is not None
@@ -711,7 +714,7 @@ class DecodeEngine:
                         # worker ops (session imports/exports) read or
                         # rewrite the page pools and tables; run them
                         # against retired, fully materialized state
-                        self._flush_pipe()
+                        self._flush_pipe(cause="ops")
                 self._drain_ops()
             with span("engine.expire"):
                 self._expire_queued(t0)
@@ -730,6 +733,10 @@ class DecodeEngine:
             t = self._lap("launch", t)
             with span("engine.account"):
                 kv = self.alloc.stats()
+                seen, self._reclaimed_seen = (self._reclaimed_seen,
+                                              kv["counters"]["reclaimed"])
+                self.metrics.count(self.name, "kv_reclaimed_pages_total",
+                                   self._reclaimed_seen - seen)
                 self.metrics.observe_kv_cache(
                     self.name, kv["used_pages"], kv["total_pages"],
                     kv["shared_pages"], kv["leaked_pages"],
@@ -930,7 +937,7 @@ class DecodeEngine:
                 pages = self.alloc.alloc(owner, n) if n else []
                 break
             except CacheOOM:
-                if not self._reclaim(keep=sid):
+                if not self._evict_lru_session(keep=sid):
                     raise
         if n:
             # the wire speaks pages form (L, KVH, n, S, D); the pool
@@ -1236,21 +1243,21 @@ class DecodeEngine:
                 sess.last_used = time.monotonic()
             return True
         if pfx_pages:
-            # take shared references NOW so pool-pressure eviction below
-            # cannot free the pages out from under the hit
+            # take shared references NOW so no allocation can take the
+            # pages back out from under the hit
             self.alloc.share(owner, pfx_pages)
         # watermark: enough pages to finish prefill + the first decode
         # token (plus one for the copy-on-write fork of a shared partial
-        # page), otherwise leave it queued until evictions free pages —
-        # under pressure, prefix-cache entries go first (LRU), then idle
-        # parked sessions (their later resume migrates or resets typed)
+        # page), otherwise leave it queued until pages come back.  Pages
+        # only the prefix cache keeps are room (an allocation takes them);
+        # past those, idle parked sessions go (resume migrates or resets)
         need_now = (pages_for(base + len(prefill) + 1, self.page_size)
                     - len(self.alloc.pages(owner))
                     + (1 if pfx_partial else 0))
-        while (need_now > self.alloc.num_free
-               and self._reclaim(keep=req.session)):
+        while (need_now > self.alloc.num_available
+               and self._evict_lru_session(keep=req.session)):
             pass
-        if need_now > self.alloc.num_free:
+        if need_now > self.alloc.num_available:
             if pfx_pages or replaying:
                 self.alloc.free(owner)  # drop shared refs; retry relooks
             with self._cond:
@@ -1312,17 +1319,10 @@ class DecodeEngine:
         self._sync_table(slot)
         return True
 
-    def _reclaim(self, keep=None):
-        """Free pool pages under pressure: LRU prefix-cache entries
-        first (pure capacity, nothing breaks), then idle parked
-        sessions.  Returns True while there is anything left to try."""
-        if self.prefix_cache is not None and self.prefix_cache.evict_one():
-            return True
-        return self._evict_lru_session(keep=keep)
-
     def _evict_lru_session(self, keep=None):
         """Reclaim the least-recently-used idle parked session's pages
-        (cache pressure).  Returns True when one was evicted."""
+        (the allocator has taken the prefix cache's pages by itself and
+        is out).  Returns True when one was evicted."""
         with self._cond:
             idle = [s for s in self._sessions.values()
                     if not s.busy and s.sid != keep]
@@ -1429,11 +1429,6 @@ class DecodeEngine:
                 self._sync_table(slot)
                 return True
             except CacheOOM:
-                # cheapest relief first: drop an LRU prefix-cache entry
-                # (pure capacity) before preempting live work
-                if self.prefix_cache is not None \
-                        and self.prefix_cache.evict_one():
-                    continue
                 victim = self._preempt_victim(exclude=slot)
                 if victim is None:
                     self._fail_slot(slot, ServingError(
@@ -1838,11 +1833,14 @@ class DecodeEngine:
             cb()
         self._flush_pipe(discard=True)
 
-    def _flush_pipe(self, discard=False):
-        """Drain every in-flight launch.  ``discard=True`` drops results
-        without reading them (downstream of a poisoned flight): valid
-        lanes just lose their in-flight count and relaunch from their
-        last confirmed token."""
+    def _flush_pipe(self, discard=False, cause=None):
+        """Drain every in-flight launch (counted by ``cause`` when there
+        is one).  ``discard=True`` drops results without reading them
+        (downstream of a poisoned flight): valid lanes just lose their
+        in-flight count and relaunch from their last confirmed token."""
+        if self._pipe and not discard:
+            self.metrics.count(self.name, "pipe_flushes_total")
+            self.metrics.count(self.name, "pipe_flushes_%s_total" % cause)
         while self._pipe:
             if not discard:
                 self._retire_oldest()
@@ -1869,9 +1867,10 @@ class DecodeEngine:
     def _grow_pages_inflight(self, s):
         """Page growth for an async launch: the slot's cache must cover
         ``pos + flight + 1`` positions (every unretired lane writes one).
-        The happy path allocates from the free list without touching
-        peers; on pressure the pipeline is flushed FIRST so the sync
-        preemption machinery (:meth:`_ensure_pages`) runs against a
+        The happy path allocates, free pages or the prefix cache's,
+        without touching peers or the pipe; with live sequences and
+        sessions holding the pool the pipeline is flushed FIRST so the
+        sync preemption machinery (:meth:`_ensure_pages`) runs against a
         quiesced engine whose flight counts are all zero."""
         need = (pages_for(s.pos + s.flight + 1, self.page_size)
                 - len(self.alloc.pages(s.owner)))
@@ -1882,7 +1881,7 @@ class DecodeEngine:
             self._sync_table(s)
             return True
         except CacheOOM:
-            self._flush_pipe()
+            self._flush_pipe(cause="page_pressure")
             if s.req is None or s.state != "decode":
                 return False  # the flush finished / failed / preempted it
             return self._ensure_pages(s, 1)
@@ -2336,18 +2335,15 @@ class DecodeEngine:
         if written_end > slot.pos and keep > 0 and keep <= len(pages) \
                 and slot.pos % self.page_size != 0 \
                 and self.alloc.refcount(pages[keep - 1]) > 1:
-            old = pages[keep - 1]
-            try:
-                new = self.alloc.fork(slot.owner, old)
-            except CacheOOM:
-                if self._reclaim(keep=slot.req.session
-                                 if slot.req else None):
-                    try:
-                        new = self.alloc.fork(slot.owner, old)
-                    except CacheOOM:
-                        new = None
-                else:
-                    new = None
+            old, new = pages[keep - 1], None
+            for _ in range(2):
+                try:
+                    new = self.alloc.fork(slot.owner, old)
+                    break
+                except CacheOOM:
+                    if not self._evict_lru_session(
+                            keep=slot.req.session if slot.req else None):
+                        break
             if new is not None:
                 self._fork_page(old, new)
                 self.metrics.count(self.name, "cow_forks_total")

@@ -5,7 +5,9 @@ a fixed pool of ``total_pages`` pages of ``page_size`` tokens each
 (``ops/pallas/paged_attention.py`` owns the device layout and the
 attention over it); this module owns the HOST-side bookkeeping —
 
-- a LIFO **free list** (freed pages are re-used hottest-first),
+- a LIFO **free list** (freed pages are re-used hottest-first), topped
+  up at allocation from the **reclaimable** pages: those the prefix cache
+  keeps and nothing else refers to, least recently used first,
 - per-owner **page lists** (the sequence's page table, in allocation
   order == token order),
 - per-page **refcounts**: a page may appear in several owners' page
@@ -30,10 +32,12 @@ KV state portable and shareable:
   wire format for one session's page table + live pages (the
   serialization half of KV migration; the engine owns gathering and
   scattering the device arrays),
-- :class:`PrefixCache` — content-addressed prompt-prefix pages (full
-  pages keyed by their exact token prefix, plus the trailing partial
-  page), shared copy-on-write so N sequences with a common system
-  prompt pay its prefill once.
+- :class:`PrefixCache` — content-addressed prompt-prefix pages (a chain
+  of full pages, each keyed by its parent entry and its own tokens, plus
+  the trailing partial page), shared copy-on-write so N sequences with
+  a common system prompt pay its prefill once.  The pages it keeps
+  count as used and are handed out again by ``alloc`` when the free
+  list runs short.
 
 Tensor-parallel serving (``DecodeEngine(sharding=...)``) changes NONE
 of this bookkeeping: page ids, refcounts, and occupancy are per-page
@@ -57,7 +61,8 @@ clean.
 
 Fault site ``kvcache.alloc`` (``mxnet_tpu.faults``) trips inside
 :meth:`PageAllocator.alloc`, so chaos tests can fail allocations
-deterministically; genuine exhaustion raises :class:`CacheOOM`, which
+deterministically; genuine exhaustion (live sequences and parked
+sessions hold the pool) raises :class:`CacheOOM`, which
 the decode engine turns into preemption (evict-youngest + recompute)
 rather than an error.  Invariant violations raise the typed
 :class:`~.errors.KVLeakError` from :meth:`PageAllocator.check_leaks`.
@@ -68,6 +73,7 @@ import json
 import struct
 import threading
 import zlib
+from collections import OrderedDict
 
 import numpy as onp
 
@@ -82,7 +88,8 @@ SCRATCH_PAGE = 0
 
 
 class CacheOOM(RuntimeError):
-    """The free list cannot satisfy an allocation.  Internal to the
+    """Free and reclaimable pages together cannot satisfy an allocation:
+    live sequences and parked sessions hold the pool.  Internal to the
     decode engine: the scheduler responds by preempting (or, with
     nothing to preempt, failing the request typed) — callers outside
     the engine never see this."""
@@ -103,6 +110,15 @@ class PageAllocator:
     :meth:`free`/:meth:`fork` drop references — the page rejoins the
     free list only at refcount zero, so occupancy counts every
     physically-resident page exactly once however many tables map it.
+
+    A page the prefix cache keeps (:meth:`keep`) whose only reference is
+    the cache's is *reclaimable*: it counts as used, and an allocation
+    that finds the free list short takes such pages, least recently
+    released first, in O(1) each.  :class:`CacheOOM` therefore means that
+    ``free + reclaimable`` is short.  The index of reclaimable pages
+    lives here, kept by the reference counts as they change; the cache
+    runs under this allocator's (reentrant) lock, so the two have one
+    lock between them and no order to invert.
     """
 
     def __init__(self, total_pages, page_size, kv_dtype="float32",
@@ -126,40 +142,64 @@ class PageAllocator:
         self.kv_dtype = str(kv_dtype)
         self.page_bytes = int(page_bytes)
         self.scale_page_bytes = int(scale_page_bytes)
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         # LIFO: freshly freed pages go back out first (warm reuse)
         self._free = list(range(self.total_pages - 1, SCRATCH_PAGE, -1))
         self._owned = {}   # owner -> [page, ...] in allocation order
         self._refs = {}    # page -> live reference count
+        # the prefix cache's pages: page -> its entry, and of those the
+        # ones at refcount 1 (the cache's own), least recently released
+        # first.  A sequence holds every page of the chain it hangs on
+        # and releases its table last page first, so an entry always
+        # lies before its parent here and a chain goes from its tail.
+        self._kept = {}
+        self._reclaimable = OrderedDict()
+        self._forget = None   # the cache's: take an entry out of its index
         self.peak_used = 0
         self.counters = {"allocs": 0, "frees": 0, "failed_allocs": 0,
                          "shares": 0, "forks": 0, "trims": 0,
-                         "leak_checks": 0}
+                         "reclaimed": 0, "leak_checks": 0}
         self.last_leak = []
 
     # -- allocation -------------------------------------------------------
     def alloc(self, owner, n=1):
         """Append ``n`` fresh (refcount-1) pages to ``owner``'s page
-        list; returns the new pages.  Raises :class:`CacheOOM` when the
-        free list is short (nothing is partially allocated), and
-        whatever the ``kvcache.alloc`` fault site injects."""
+        list; returns the new pages.  Raises :class:`CacheOOM` when free
+        and reclaimable pages together are short (nothing is allocated
+        then), and whatever the ``kvcache.alloc`` fault site injects."""
         n = int(n)
         if n <= 0:
             return []
         faults.check("kvcache.alloc")
         with self._lock:
-            if len(self._free) < n:
-                self.counters["failed_allocs"] += 1
-                raise CacheOOM(
-                    "kv cache exhausted: want %d page(s), %d free of %d"
-                    % (n, len(self._free), self.total_pages - 1))
-            pages = [self._free.pop() for _ in range(n)]
-            for p in pages:
-                self._refs[p] = 1
+            pages = self._take_locked(n)
             self._owned.setdefault(owner, []).extend(pages)
-            self.counters["allocs"] += n
-            self.peak_used = max(self.peak_used, self._used_locked())
             return pages
+
+    def _take_locked(self, n):
+        """``n`` pages at refcount 1 off the free list, which is topped
+        up from the reclaimable pages first.  Taking one of those needs
+        no quiesced engine: a page that a launch in flight reads or
+        writes is in its owner's table until that flight retires (the
+        engine defers the free, ``generate._free_owner``), so its count
+        is above the cache's one and it is not in the index."""
+        short = n - len(self._free)
+        if short > len(self._reclaimable):
+            self.counters["failed_allocs"] += 1
+            raise CacheOOM(
+                "kv cache exhausted: want %d page(s), %d free and %d "
+                "reclaimable of %d" % (n, len(self._free),
+                                       len(self._reclaimable),
+                                       self.total_pages - 1))
+        for _ in range(short):
+            self._drop_locked(next(iter(self._reclaimable)))
+        self.counters["reclaimed"] += max(short, 0)
+        pages = [self._free.pop() for _ in range(n)]
+        for p in pages:
+            self._refs[p] = 1
+        self.counters["allocs"] += n
+        self.peak_used = max(self.peak_used, self._used_locked())
+        return pages
 
     def share(self, owner, pages):
         """Attach already-live ``pages`` to ``owner``'s table as shared
@@ -172,6 +212,7 @@ class PageAllocator:
                     raise ValueError("share: page %d is not live" % p)
             for p in pages:
                 self._refs[p] += 1
+                self._reclaimable.pop(p, None)
             self._owned.setdefault(owner, []).extend(pages)
             self.counters["shares"] += len(pages)
         return pages
@@ -181,28 +222,25 @@ class PageAllocator:
         shared ``page`` with a fresh private page (same position in the
         table) and drop the shared reference.  Returns the new page id;
         the CALLER must copy the device contents old -> new before
-        writing.  Raises :class:`CacheOOM` when no page is free."""
+        writing.  Raises :class:`CacheOOM` when no page is free or
+        reclaimable."""
         with self._lock:
             table = self._owned.get(owner)
             if not table or page not in table:
                 raise ValueError("fork: owner %r does not hold page %d"
                                  % (owner, page))
-            if not self._free:
-                self.counters["failed_allocs"] += 1
-                raise CacheOOM("kv cache exhausted: fork needs 1 page")
-            new = self._free.pop()
-            self._refs[new] = 1
+            new, = self._take_locked(1)
             table[table.index(page)] = new
             self._deref_locked(page)
-            self.counters["allocs"] += 1
             self.counters["forks"] += 1
-            self.peak_used = max(self.peak_used, self._used_locked())
             return new
 
     def _deref_locked(self, page):
         left = self._refs[page] - 1
         if left:
             self._refs[page] = left
+            if left == 1 and page in self._kept:
+                self._reclaimable[page] = None   # the cache's is the last
         else:
             del self._refs[page]
             self._free.append(page)
@@ -255,6 +293,50 @@ class PageAllocator:
             self.counters["trims"] += 1
             return len(tail)
 
+    # -- the prefix cache's pages -----------------------------------------
+    def keep(self, entry):
+        """The prefix cache's one reference on the live ``entry.page``,
+        held under ``entry.owner``.  From here on the page is reclaimable
+        whenever that reference is its last.  False when the page is not
+        live (its owner raced off) or is kept already."""
+        with self._lock:
+            page = entry.page
+            if page not in self._refs or page in self._kept:
+                return False
+            self._refs[page] += 1
+            self._owned[entry.owner] = [page]
+            self._kept[page] = entry
+            self.counters["shares"] += 1
+            return True
+
+    def touch(self, pages):
+        """A use of the chain ``pages`` (root first) that took no
+        reference: those of them that are reclaimable become the most
+        recently used, the chain's tail still before its head."""
+        with self._lock:
+            for p in reversed(pages):
+                if p in self._reclaimable:
+                    self._reclaimable.move_to_end(p)
+
+    def unkeep(self, page=None):
+        """Drop the cache's reference on ``page``, or on the least
+        recently used reclaimable page, which then rejoins the free
+        list.  False when there is none."""
+        with self._lock:
+            if page is None:
+                page = next(iter(self._reclaimable), None)
+            if page not in self._kept:
+                return False
+            self._drop_locked(page)
+            return True
+
+    def _drop_locked(self, page):
+        entry = self._kept.pop(page)
+        self._reclaimable.pop(page, None)
+        self._forget(entry)
+        del self._owned[entry.owner]
+        self._deref_locked(page)
+
     def pages(self, owner):
         """The owner's page list (copy), allocation order == token order."""
         with self._lock:
@@ -277,6 +359,12 @@ class PageAllocator:
     def num_used(self):
         with self._lock:
             return self._used_locked()
+
+    @property
+    def num_available(self):
+        """Pages an allocation can have now: free and reclaimable."""
+        with self._lock:
+            return len(self._free) + len(self._reclaimable)
 
     def occupancy(self):
         """Used fraction of the allocatable pool (scratch page excluded)."""
@@ -324,6 +412,11 @@ class PageAllocator:
                     # refcount drift, or a page neither free nor held
                     if not (n == 0 and have == 0 and in_free):
                         bad.add(p)
+            # the index of reclaimable pages: exactly the kept pages
+            # whose one reference is the cache's
+            bad |= set(self._reclaimable) - set(self._kept)
+            bad |= {p for p in self._kept if (self._refs.get(p) == 1)
+                    != (p in self._reclaimable)}
             if bad:
                 self.last_leak = sorted(bad)
                 raise KVLeakError(
@@ -342,6 +435,7 @@ class PageAllocator:
                 "total_pages": cap,
                 "used_pages": used,
                 "free_pages": len(self._free),
+                "reclaimable_pages": len(self._reclaimable),
                 "occupancy": round(used / cap, 4) if cap else 0.0,
                 "peak_used_pages": self.peak_used,
                 "owners": len(self._owned),
@@ -483,29 +577,32 @@ def unpack_session(blob, with_scales=False):
 
 # -- prefix cache ---------------------------------------------------------
 class _PrefixEntry:
-    __slots__ = ("key", "page", "tokens", "partial", "owner", "tick")
+    __slots__ = ("key", "page", "owner")
 
-    def __init__(self, key, page, tokens, partial, owner, tick):
-        self.key = key          # exact token prefix this page completes
+    def __init__(self, key, page, owner):
+        self.key = key          # (parent entry's serial, this page's tokens)
         self.page = page
-        self.tokens = tokens    # cache positions this entry vouches for
-        self.partial = partial  # True: trailing partially-filled page
-        self.owner = owner      # allocator owner holding the cache's ref
-        self.tick = tick        # LRU clock
+        self.owner = owner      # ("pfx", serial): holds the cache's reference
 
 
 class PrefixCache:
     """Content-addressed prompt-prefix pages, shared copy-on-write.
 
-    Full pages are keyed by the exact token prefix they complete
-    (position-dependent KV makes anything weaker unsound); the trailing
-    partial page of a prompt is cached too, keyed by the full prefix it
-    holds.  A lookup returns the longest chain of cached pages covering
-    a strict prefix of the prompt (at least one token is always left to
-    prefill — its logits seed generation).  The cache holds one
-    allocator reference per entry, so hit pages stay live across the
-    inserting sequence's exit; eviction is LRU and only reclaims pool
-    space once no sequence shares the page.
+    A prompt's pages form a chain from the root: an entry is found by
+    its parent entry and its own page's tokens, compared exactly
+    (position-dependent KV makes anything weaker unsound), so a prompt's
+    keys are as long as the prompt.  The trailing partial page of a
+    prompt is cached too, under fewer tokens than a page holds.  A
+    lookup returns the longest chain of cached pages covering a strict
+    prefix of the prompt (at least one token is always left to prefill —
+    its logits seed generation).  The cache holds one allocator
+    reference per entry (:meth:`PageAllocator.keep`), so hit pages stay
+    live across the inserting sequence's exit.  They count as used until
+    an allocation that finds the free list short takes them back, least
+    recently used first and a chain from its tail; an entry whose page a
+    sequence still shares is out of that allocation's reach and stays.
+
+    The cache has no lock of its own: it runs under the allocator's.
 
     Writers never mutate a shared full page (decode appends past it);
     a hit on a *partial* page is forked copy-on-write by the engine
@@ -514,10 +611,11 @@ class PrefixCache:
 
     def __init__(self, alloc):
         self.alloc = alloc
-        self._lock = threading.Lock()
-        self._entries = {}   # key tuple -> _PrefixEntry
-        self._serial = 0
-        self._tick = 0
+        self._lock = alloc._lock
+        alloc._forget = self._forget
+        self._entries = {}   # (parent's serial, page tokens) -> _PrefixEntry
+        self._serial = 0     # the root is 0
+        self._partials = 0   # entries of a partially filled page
         self.counters = {"hits": 0, "misses": 0, "inserts": 0,
                          "evictions": 0, "tokens_saved": 0}
 
@@ -529,28 +627,31 @@ class PrefixCache:
         """Longest cached cover of a strict prefix of ``prompt``;
         returns ``(pages, covered_tokens, partial_hit)`` (all falsy on
         a miss).  The returned pages are NOT yet referenced — the
-        caller must :meth:`PageAllocator.share` them immediately."""
+        caller must :meth:`PageAllocator.share` them before its next
+        allocation, which could otherwise take them."""
         S = self.alloc.page_size
         limit = len(prompt) - 1          # always leave >=1 token to prefill
         with self._lock:
-            self._tick += 1
-            pages, covered = [], 0
+            pages, covered, parent = [], 0, 0
             while covered + S <= limit:
-                e = self._entries.get(tuple(prompt[:covered + S]))
-                if e is None or e.partial:
+                e = self._entries.get(
+                    (parent, tuple(prompt[covered:covered + S])))
+                if e is None:
                     break
-                e.tick = self._tick
                 pages.append(e.page)
                 covered += S
+                parent = e.owner[1]
             partial = False
-            for m in range(min(S - 1, limit - covered), 0, -1):
-                e = self._entries.get(tuple(prompt[:covered + m]))
-                if e is not None and e.partial:
-                    e.tick = self._tick
+            longest = min(S - 1, limit - covered) if self._partials else 0
+            for m in range(longest, 0, -1):
+                e = self._entries.get(
+                    (parent, tuple(prompt[covered:covered + m])))
+                if e is not None:
                     pages.append(e.page)
                     covered += m
                     partial = True
                     break
+            self.alloc.touch(pages)
             if covered:
                 self.counters["hits"] += 1
                 self.counters["tokens_saved"] += covered
@@ -561,56 +662,53 @@ class PrefixCache:
     def insert(self, tokens, owner_pages):
         """Publish a freshly-prefilled sequence's pages: every full page
         (and the trailing partial one) becomes a cache entry under its
-        exact prefix key, with the cache taking one shared reference.
-        Existing entries win (first writer published identical KV)."""
+        parent and its own tokens, with the cache taking one shared
+        reference.  Existing entries win (first writer published
+        identical KV); where one holds another page than the caller's,
+        nothing further is published: an entry hangs only on a page its
+        publisher holds, which is what lets a chain go from its tail."""
         S = self.alloc.page_size
         new = 0
         with self._lock:
-            self._tick += 1
-            nfull = len(tokens) // S
-            for i in range(min(nfull, len(owner_pages))):
-                new += self._insert_locked(tuple(tokens[:(i + 1) * S]),
-                                           owner_pages[i], S, False)
-            m = len(tokens) - nfull * S
-            if m and nfull < len(owner_pages):
-                new += self._insert_locked(tuple(tokens),
-                                           owner_pages[nfull], m, True)
+            parent, chain = 0, []
+            for i in range(min(pages_for(len(tokens), S), len(owner_pages))):
+                key = (parent, tuple(tokens[i * S:(i + 1) * S]))
+                e = self._entries.get(key)
+                if e is None:
+                    self._serial += 1
+                    e = _PrefixEntry(key, owner_pages[i],
+                                     ("pfx", self._serial))
+                    if not self.alloc.keep(e):
+                        break   # page raced off (owner already freed)
+                    self._entries[key] = e
+                    self._partials += len(key[1]) < S
+                    self.counters["inserts"] += 1
+                    new += 1
+                chain.append(e.page)
+                if e.page != owner_pages[i]:
+                    break
+                parent = e.owner[1]
+            self.alloc.touch(chain)
         return new
 
-    def _insert_locked(self, key, page, tokens, partial):
-        if key in self._entries:
-            self._entries[key].tick = self._tick
-            return 0
-        self._serial += 1
-        owner = ("pfx", self._serial)
-        try:
-            self.alloc.share(owner, [page])
-        except ValueError:      # page raced off (owner already freed)
-            return 0
-        self._entries[key] = _PrefixEntry(key, page, tokens, partial,
-                                          owner, self._tick)
-        self.counters["inserts"] += 1
-        return 1
+    def _forget(self, entry):
+        """The allocator dropped ``entry``'s reference (its lock held)."""
+        if self._entries.pop(entry.key, None) is not None:
+            self._partials -= len(entry.key[1]) < self.alloc.page_size
+            self.counters["evictions"] += 1
 
     def evict_one(self):
-        """Drop the LRU entry (pool pressure).  Returns True when an
-        entry was dropped — its page rejoins the pool only if no
-        sequence still shares it."""
-        with self._lock:
-            if not self._entries:
-                return False
-            key = min(self._entries.values(), key=lambda e: e.tick).key
-            e = self._entries.pop(key)
-            self.counters["evictions"] += 1
-        self.alloc.free(e.owner)
-        return True
+        """Give the least recently used page that nothing but the cache
+        refers to back to the free list, with its entry.  False when
+        there is none: entries whose pages sequences share stay."""
+        return self.alloc.unkeep()
 
     def clear(self):
         with self._lock:
-            entries = list(self._entries.values())
-            self._entries.clear()
-        for e in entries:
-            self.alloc.free(e.owner)
+            entries, self._entries = self._entries, {}
+            self._partials = 0
+            for e in entries.values():
+                self.alloc.unkeep(e.page)
         return len(entries)
 
     def stats(self):

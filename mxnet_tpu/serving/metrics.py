@@ -104,7 +104,14 @@ class ModelMetrics:
                 # sequences begun from the zero state, whole pages (with
                 # their state entries) attached on a prefix hit
                 "state_entries_written_total", "state_starts_total",
-                "state_prefix_pages_shared_total")
+                "state_prefix_pages_shared_total",
+                # a full pool (PR 32): pages an allocation took back from
+                # the prefix cache, and blocking retires of the whole
+                # decode pipeline that found launches in flight, by
+                # cause: a page a lane grew into with the pool held by
+                # live sequences and sessions, a worker op
+                "kv_reclaimed_pages_total", "pipe_flushes_total",
+                "pipe_flushes_page_pressure_total", "pipe_flushes_ops_total")
 
     #: parts of an engine step, host wall seconds summed over the window
     #: (DecodeEngine._step): ``ops`` worker ops + expiry, ``admit``,

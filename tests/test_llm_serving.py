@@ -338,28 +338,54 @@ def test_batcher_deadline_caps_flush_window():
         b.stop()
 
 
+class DecodeGate(faults.FaultRule):
+    """A rule on ``decode.step`` that never trips: every decode step
+    waits at it until the test opens it, so a request holds its slot for
+    as long as the test says and not for as long as the machine takes."""
+
+    def __init__(self):
+        super().__init__("decode.step", "error")
+        self.open = threading.Event()
+
+    def should_trip(self):
+        self.open.wait(60)
+        return False
+
+
 def test_generate_queue_deadline_and_shed(lm):
     eng = make_engine(lm, slots=1, max_queue_depth=2)
-    try:
-        # fill the slot, then the queue
-        busy = eng.submit([1, 2], max_new_tokens=30)
-        deadline = time.time() + 10
+    gate = faults.install(DecodeGate())
+
+    def hold_the_slot(max_new_tokens):
+        gate.open.clear()
+        busy = eng.submit([1, 2], max_new_tokens=max_new_tokens)
+        deadline = time.time() + 60
         while eng.active_count() == 0 and time.time() < deadline:
             time.sleep(0.005)  # busy must hold the slot, not the queue
+        assert eng.active_count() == 1
+        return busy
+    try:
+        # fill the slot, then the queue
+        busy = hold_the_slot(30)
         q1 = eng.submit([2, 3], max_new_tokens=2)
         q2 = eng.submit([3, 4], max_new_tokens=2)
         with pytest.raises(serving.QueueFullError):
             eng.submit([4, 5], max_new_tokens=2)
+        gate.open.set()
         for f in (busy, q1, q2):
             f.result(timeout=120)
         # queued deadline expires typed while the slot is busy (the
-        # busy request decodes far longer than the queued deadline)
-        busy2 = eng.submit([1, 2], max_new_tokens=60)
+        # busy request is held past the queued deadline)
+        busy2 = hold_the_slot(60)
         dead = eng.submit([9, 9], max_new_tokens=2, deadline_ms=25)
+        time.sleep(0.05)
+        gate.open.set()
         with pytest.raises(serving.DeadlineExceededError):
             dead.result(timeout=30)
         busy2.result(timeout=120)
     finally:
+        gate.open.set()
+        faults.remove(gate)
         assert eng.stop()
     assert eng.alloc.num_used == 0
 
