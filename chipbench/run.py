@@ -257,6 +257,8 @@ def main():
         device["window_s"] = facts["trace"]["window_s"]
         result["breakdown"] = {"device_ops": facts["trace"]["device_ops"],
                                "idle_gaps": facts["trace"]["idle_gaps"]}
+    if "readings" in facts:         # what a kind printed beside its checks
+        result["readings"] = facts["readings"]
     result["checks"] = checks       # last in the line, and last on stderr
     log(json.dumps(result))
     for name, c in checks.items():

@@ -5,31 +5,132 @@ programs against the plain float32 forward kept here."""
 import functools
 import math
 import statistics
+import sys
 import threading
 import time
 
 import numpy as np
 
-#: max |paged - reference| / std(reference logits) over every logit of every
-#: checked position.  The reference runs at jax.default_matmul_precision
-#: "highest"; the engine's programs run at the chip's default, where a float32
-#: matmul is one bf16 pass on the MXU, through 12 layers.  Read on the v5e
-#: (PERF.md, PR 28): the engine 0.037-0.046 over some thirty seeds since PR
-#: 21; in its place the bfloat16 reference 0.043-0.054 (the engine's own
-#: precision), the int8 reference 0.108-0.155, the fp8 one 0.51-0.66.  A
-#: dropped or misplaced term (a bias, a residual, a position row, a page read
-#: from the wrong slot) moves logits by order 1 in these units.
-LOGIT_TOL = 0.08
+#: The statistics of the two sets of numbers a serving run always prints:
+#: one error per compared row of logits, max_v |got - ref| / std(all compared
+#: reference logits), and one gap per served token, by which its logit lies
+#: below the plain reference's best at its position in units of that
+#: position's std.  Which of them decide ``correct``, and by what limit, the
+#: configuration says (``check.limits``, with the readings each limit was set
+#: from under ``assumed``; README.md has the rule): an error of the
+#: arithmetic moves every row, so every statistic; a hard choice that falls
+#: differently (a routed expert) moves the rows it falls in, so the maxima.
+ROW_ERRORS = ("max", "q90", "median")
+SERVED_GAPS = ("max", "q90")
+#: The statistics a limit may be named for.  Every row and every served token
+#: is held by the maxima.  The rows' 90th percentile lets a tenth of the rows
+#: go, so only a configuration that declares a hard choice in its forward
+#: (``check.hard_choice``, explained under ``assumed``) may name it.  The
+#: median (half of the rows wrong would pass) and the gaps' 90th percentile
+#: (0.0 in every sound run read so far: a limit on it only asks whether a
+#: tenth of the served tokens are arbitrary) are printed and never compared.
+ROW_LIMITS = ("max", "q90")
+GAP_LIMITS = ("max",)
 
-#: the widest gap by which a served token's logit may lie below the plain
-#: reference's best at its position, in units of the standard deviation of
-#: that position's logits.  Read on the v5e (PERF.md, PR 28): the engine's
-#: answers at most 0.034 over 19 seeds; a served token altered at least 0.29;
-#: the fp8 reference's first tokens 0.33-0.62 at the widest.  (The int8 and
-#: bfloat16 references read 0.03-0.07 and 0.005-0.03: a greedy token changes
-#: only where the two best logits lie closer than the noise, so this number
-#: tells a wrong token, not a precision.)
-SERVED_GAP_TOL = 0.12
+
+def row_name(stat, who="program"):
+    return "%s_logits_%s_err" % (who, stat)
+
+
+def gap_name(stat, control=None):
+    if control is None:
+        return "served_gap_%s" % stat
+    return "control_%s_served_gap%s" % (control,
+                                        "" if stat == "max" else "_" + stat)
+
+
+def check_limits(config):
+    """``check.limits`` of a ``serve`` configuration: reading -> limit.  The
+    harness has no limit of its own, so a configuration that names none, none
+    of the rows' errors, none of the served gaps, a reading that no limit is
+    taken for, or the rows' 90th percentile without declaring its hard choice
+    is refused, before anything is built."""
+    rows = [row_name(s) for s in ROW_LIMITS]
+    gaps = [gap_name(s) for s in GAP_LIMITS]
+    check = config.get("check", {})
+    limits = check.get("limits")
+    if not isinstance(limits, dict):
+        raise ValueError("the configuration has no check.limits: a map from "
+                         "a reading (%s) to its limit" % ", ".join(rows + gaps))
+    unknown = sorted(set(limits) - set(rows + gaps))
+    if unknown:
+        raise ValueError("check.limits names %s: the harness takes a limit "
+                         "for %s and for nothing else"
+                         % (", ".join(unknown), ", ".join(rows + gaps)))
+    for what, names in (("the rows' errors", rows), ("the served gaps", gaps)):
+        if not set(names) & set(limits):
+            raise ValueError("check.limits names no reading of %s (%s)"
+                             % (what, ", ".join(names)))
+    for name, limit in limits.items():
+        if isinstance(limit, bool) or not isinstance(limit, (int, float)) \
+                or not 0 <= limit < math.inf:
+            raise ValueError("check.limits.%s is %r, no limit" % (name, limit))
+    if row_name("q90") in limits:
+        for where, said in (
+                ("check.hard_choice", check.get("hard_choice")),
+                ('assumed["check.hard_choice"]',
+                 config.get("assumed", {}).get("check.hard_choice"))):
+            if not isinstance(said, str) or not said.strip():
+                raise ValueError(
+                    "check.limits names %s, which lets a tenth of the rows "
+                    "go: only a configuration whose forward makes a hard "
+                    "choice may, and it says which under %s (a string)"
+                    % (row_name("q90"), where))
+    return dict(limits)
+
+
+def statistics_of(values):
+    """max, 90th percentile and median of ``values``; all infinite where one
+    of them is not finite or there is none."""
+    v = np.asarray(values, np.float64)
+    if v.size == 0 or not np.isfinite(v).all():
+        return dict.fromkeys(ROW_ERRORS, math.inf)
+    return {"max": float(v.max()), "q90": float(np.percentile(v, 90)),
+            "median": float(np.median(v))}
+
+
+def row_errors(got, ref):
+    """One error per compared row: its largest |got - ref| over the standard
+    deviation of all compared reference logits."""
+    if got.shape != ref.shape or not np.isfinite(got).all():
+        return np.full(ref.shape[0], np.inf)
+    return np.abs(got - ref).max(-1) / ref.std()
+
+
+def row_readings(got, ref, limits, who="program"):
+    """The readings of ``got``'s rows against ``ref``'s, each beside the
+    limit the configuration names for that statistic (None: printed, not
+    compared)."""
+    stats = statistics_of(row_errors(got, ref))
+    return [{"name": row_name(s, who), "value": stats[s],
+             "limit": limits.get(row_name(s))} for s in ROW_ERRORS]
+
+
+def gap_readings(gaps, limits, control=None):
+    """The same for served tokens' gaps.  Of ``"altered"`` the reading in the
+    maximum's place is the least gap: an altered token has to fail wherever
+    it stands, a precision at its worst position."""
+    stats = statistics_of(gaps)
+    if control == "altered" and math.isfinite(stats["max"]):
+        stats["max"] = float(np.min(gaps))
+    return [{"name": gap_name(s, control), "value": stats[s],
+             "limit": limits.get(gap_name(s))} for s in SERVED_GAPS]
+
+
+def held(entries):
+    return all(e["value"] <= e["limit"] for e in entries
+               if e["limit"] is not None)
+
+
+def describe_limits(entries):
+    named = ["%s <= %g" % (e["name"], e["limit"]) for e in entries
+             if e["limit"] is not None]
+    return ", ".join(named) or "none named"
 
 
 # ---------------------------------------------------------------------------
@@ -361,8 +462,7 @@ def paged_logits(engine, prompt, n_decode):
     prefill = decoder.make_prefill_chunk(cfg, S, chunk,
                                          sharding=engine.sharding)
     decode = decoder.make_decode_step(cfg, S, sharding=engine.sharding)
-    if (decoder.fn_cache_stats()["compiles"] != built
-            or engine.decode_fused_mode is not None):
+    if decoder.fn_cache_stats()["compiles"] != built:
         raise RuntimeError("the logits check would drive a program the "
                            "engine does not run")
     kp, vp = (decoder.fresh_pool(cfg, engine.alloc.total_pages, S,
@@ -402,51 +502,49 @@ def prompt_ids(seed, stream, i, n, vocab):
         0, vocab, size=n).tolist()
 
 
-def check_reference(engine, lm, reference, check, seed, log, controls=()):
+def check_reference(engine, lm, reference, check, limits, seed, log,
+                    controls=()):
     """Before the window: the engine's own compiled programs, driven by hand
     over one prompt and a few greedy steps, against the plain forward, every
-    logit.  Returns the number compared beside its limit, and the same number
-    of each of ``controls`` (the reference at a lower precision)."""
+    logit.  Returns the readings of the rows' errors, each beside the limit
+    the configuration names for it, and the same readings of each of
+    ``controls`` (the reference at a lower precision)."""
     prompt = prompt_ids(seed, 1, 0, check["prompt_tokens"],
                         lm.config.vocab_size)
     fed, got = paged_logits(engine, prompt, check["decode_steps"])
     ref = np.asarray(reference(lm.jax_params(), lm.config, fed, got.shape[0]))
-
-    def max_err(logits):
-        if logits.shape != ref.shape or not np.isfinite(logits).all():
-            return float("inf")
-        return float(np.abs(logits - ref).max() / ref.std())
-    err = max_err(got)
+    out = row_readings(got, ref, limits)
     rms = float(np.sqrt(np.mean(np.square(got - ref))) / ref.std())
     log("reference check: %d prompt tokens + %d decode steps through the "
-        "paged cache vs the plain float32 forward: max %.4f rms %.4f of "
-        "std(reference), tolerance %.2f -> %s"
-        % (len(prompt), check["decode_steps"], err, rms, LOGIT_TOL,
-           "ok" if err < LOGIT_TOL else "DISAGREE"))
-    out = [{"name": "program_logits_max_err", "value": err,
-            "limit": LOGIT_TOL}]
+        "paged cache vs the plain float32 forward, %d rows: max %.4f q90 "
+        "%.4f median %.4f rms %.4f of std(reference); limits: %s -> %s"
+        % (len(prompt), check["decode_steps"], len(ref),
+           *(e["value"] for e in out), rms, describe_limits(out),
+           "ok" if held(out) else "DISAGREE"))
     for dtype in controls:
         if dtype == "altered":      # a fault of the answers, not of these
             continue
-        low = max_err(np.asarray(reference(lm.jax_params(), lm.config, fed,
-                                           got.shape[0], dtype=dtype)))
+        low = row_readings(np.asarray(reference(
+            lm.jax_params(), lm.config, fed, got.shape[0], dtype=dtype)),
+            ref, limits, who="control_" + dtype)
         log("control (the %s reference in the program's place, the same "
-            "logits): max %.4f of std(reference)" % (dtype, low))
-        out.append({"name": "control_%s_logits_max_err" % dtype,
-                    "value": low, "limit": LOGIT_TOL})
+            "logits): max %.4f q90 %.4f median %.4f of std(reference) -> %s"
+            % (dtype, *(e["value"] for e in low),
+               "holds" if held(low) else "fails"))
+        out += low
     return out
 
 
-def check_served(lm, reference, records, seed, check, n_rows, pad_to, log,
-                 controls=()):
+def check_served(lm, reference, records, seed, check, limits, n_rows, pad_to,
+                 log, controls=()):
     """After the window: what the timed path answered, at the timed sizes.
     A sample of the requests it finished (``sample_served``), each run once
     through the plain forward with the tokens it was answered with; the
-    number compared is the widest gap by which a served token's logit lies
-    below the reference's best.  ``controls`` reads beside it, at every
-    position of the same prompts and tokens, the token that the reference at
-    each lower precision puts first, and (``"altered"``) the token next to
-    the served one in the vocabulary, judged the same way."""
+    readings are of the gaps by which the served tokens' logits lie below the
+    reference's best.  ``controls`` reads beside them, at every position of
+    the same prompts and tokens, the token that the reference at each lower
+    precision puts first, and (``"altered"``) the token next to the served
+    one in the vocabulary, judged the same way."""
     params, cfg = lm.jax_params(), lm.config
     gaps, differ = [], 0
     beside = {name: [] for name in controls}
@@ -464,32 +562,29 @@ def check_served(lm, reference, records, seed, check, n_rows, pad_to, log,
             other = ((served + 1) % cfg.vocab_size if name == "altered"
                      else reference_over(*args, dtype=name)(served)[1])
             beside[name].extend(judge(other)[0].tolist())
-    worst = max(gaps) if gaps and np.isfinite(gaps).all() else float("inf")
+    out = gap_readings(gaps, limits)
     log("served-token check: %d requests (the longest among them), %d served "
-        "tokens through the plain float32 forward: widest gap below the "
-        "reference's best %.4f of std(logits), mean %.5f, %d tokens are not "
-        "the reference's first; limit %.2f -> %s"
-        % (len(sample), len(gaps), worst, np.mean(gaps) if gaps else 0.0,
-           differ, SERVED_GAP_TOL,
-           "ok" if worst < SERVED_GAP_TOL else "DISAGREE"))
-    out = [{"name": "served_gap_max", "value": worst,
-            "limit": SERVED_GAP_TOL}]
+        "tokens through the plain float32 forward: gap below the reference's "
+        "best widest %.4f q90 %.4f of std(logits), mean %.5f, %d tokens are "
+        "not the reference's first; limits: %s -> %s"
+        % (len(sample), len(gaps), *(e["value"] for e in out),
+           np.mean(gaps) if gaps else 0.0, differ, describe_limits(out),
+           "ok" if held(out) else "DISAGREE"))
     for name, g in beside.items():
+        low, of = gap_readings(g, limits, control=name), statistics_of(g)
         g = np.asarray(g if g else [np.inf])
         log("control (%s, in the program's place at the same positions): "
-            "gap widest %.4f mean %.5f least %.4f, %d of %d tokens are not "
-            "the float32 reference's first"
-            % (name, g.max(), g.mean(), g.min(), np.count_nonzero(g), g.size))
-        # an altered token has to fail wherever it stands, a precision at
-        # its worst position
-        out.append({"name": "control_%s_served_gap" % name,
-                    "value": float(g.min() if name == "altered" else g.max()),
-                    "limit": SERVED_GAP_TOL})
+            "gap widest %.4f q90 %.4f mean %.5f least %.4f, %d of %d tokens "
+            "are not the float32 reference's first -> %s"
+            % (name, of["max"], of["q90"], g.mean(), g.min(),
+               np.count_nonzero(g), g.size,
+               "holds" if held(low) else "fails"))
+        out += low
     return out
 
 
 # ---------------------------------------------------------------------------
-def serve_window(ctx, lm, table, reference):
+def serve_window(ctx, lm, table, reference, limits):
     """Engine and server up, the check of the engine's programs, the warm-up
     requests, the measured window with its drain, engine and server down.
     Nothing of the engine outlives the call, so its pools are free when the
@@ -506,15 +601,13 @@ def serve_window(ctx, lm, table, reference):
         server.attach_engine("lm", engine)      # warmup(): compiles
         host, port = server.start()
         log("engine: slots=%d page_size=%d pages=%d max_ctx=%d "
-            "prefill_chunk=%d async=%s decode_fused=%s kv_dtype=%s "
-            "prefix_cache=%s"
+            "prefill_chunk=%d async=%s kv_dtype=%s prefix_cache=%s"
             % (engine.slots, engine.page_size, engine.alloc.total_pages,
                engine.max_ctx, engine.prefill_chunk, engine.async_decode,
-               engine.decode_fused_mode, engine.kv_dtype,
-               engine.prefix_cache is not None))
+               engine.kv_dtype, engine.prefix_cache is not None))
         lap("engine warm-up")
-        check = check_reference(engine, lm, reference, config["check"], seed,
-                                log, ctx["controls"])
+        check = check_reference(engine, lm, reference, config["check"],
+                                limits, seed, log, ctx["controls"])
         lap("reference check")
 
         local = threading.local()
@@ -565,10 +658,22 @@ def serve_window(ctx, lm, table, reference):
         engine.stop(drain=False)
 
 
+def sift(entries, readings):
+    """The entries a limit of the configuration holds, for ``checks``; the
+    others go into ``readings`` (a number that is not finite as 1e30, which
+    is JSON)."""
+    for e in entries:
+        if e["limit"] is None:
+            readings[e["name"]] = (e["value"] if math.isfinite(e["value"])
+                                   else 1e30)
+    return [e for e in entries if e["limit"] is not None]
+
+
 def run(ctx):
     import jax
     log, config, traffic = ctx["log"], ctx["config"], ctx["traffic"]
     seed, seconds, lap = ctx["seed"], ctx["seconds"], ctx["clock"].lap
+    limits = check_limits(config)       # before anything is built
     lap("imports + device start")
     kwargs = {k: config[v] for k, v in config["builder_kwargs"].items()}
     lm = ctx["resolve"](config["builder"])(seed=seed, **kwargs)
@@ -579,7 +684,10 @@ def run(ctx):
         % (len(table), describe([p for p, _ in table]),
            describe([o for _, o in table])))
     reference = ctx["resolve"](config["reference"], "serve")
-    records, t_open, stats, check = serve_window(ctx, lm, table, reference)
+    records, t_open, stats, check = serve_window(ctx, lm, table, reference,
+                                                 limits)
+    readings = {}
+    check = sift(check, readings)
 
     out = summarize(records, t_open, seconds)
     log("window %.3f s + %.3f s to the last answer: %d requests issued, %d "
@@ -604,14 +712,19 @@ def run(ctx):
 
     def after_window():
         """Run by run.py once the device's memory peak has been read."""
-        return check_served(lm, reference, records, seed, config["check"],
-                            max(o for _, o in table), lm.config.max_length,
-                            log, ctx["controls"])
+        served = sift(check_served(
+            lm, reference, records, seed, config["check"], limits,
+            max(o for _, o in table), lm.config.max_length, log,
+            ctx["controls"]), readings)
+        for name, value in readings.items():
+            print("reading %s: %.6g (the configuration names no limit for it)"
+                  % (name, value), file=sys.stderr, flush=True)
+        return served
 
     return {
         "attempted": out["attempted"], "failed": out["failed"],
         "checks": check + [{"name": "requests_failed",
                             "value": out["failed"], "limit": 0}],
-        "after_window": after_window,
+        "readings": readings, "after_window": after_window,
         "end_to_end": out, "stats": stats,
     }

@@ -215,6 +215,22 @@ TINY = {
                                          "values": [9, 24, 40]},
                               "output": {"dist": "cycle",
                                          "values": [3, 5]}}}},
+    "jamba2-3b-serve": {
+        "config": {"num_hidden_layers": 6, "hidden_size": 64,
+                   "intermediate_size": 128, "num_attention_heads": 4,
+                   "attn_layer_period": 3, "attn_layer_offset": 1,
+                   "mamba_dt_rank": 4, "vocab_size": 128, "max_length": 128,
+                   "engine": {"slots": 4, "page_size": 16, "max_ctx": 128,
+                              "prefill_chunk": 16},
+                   # a page's edge crossed in prefill and in decode, as the
+                   # cell's own check crosses one
+                   "check": {"prompt_tokens": 18, "decode_steps": 16}},
+        "traffic": {"clients": 3, "drain_s": 20, "trace_seconds": 0.5,
+                    "table": {"rows": 8,
+                              "prompt": {"dist": "cycle",
+                                         "values": [9, 24, 40]},
+                              "output": {"dist": "cycle",
+                                         "values": [3, 5]}}}},
     "bert-base-train": {
         "config": {"num_hidden_layers": 2, "hidden_size": 64,
                    "num_attention_heads": 2, "intermediate_size": 128,
