@@ -68,7 +68,7 @@ __all__ = ["DecoderConfig", "CausalLM", "full_forward", "make_decode_step",
            "fn_cache_stats", "decode_launch_stats",
            "verify_launch_stats", "decode_collective_stats", "tp_plan",
            "TPPlan", "causal_lm", "decoder_tiny", "decoder_tiny_lm",
-           "decoder_draft", "hybrid_lm", "is_hybrid"]
+           "decoder_draft", "hybrid_lm", "routed_delta_lm", "is_hybrid"]
 
 
 # ---------------------------------------------------------------------------
@@ -243,7 +243,7 @@ def pool_shape(cfg, total_pages, page_size):
 
 
 def is_hybrid(cfg):
-    """True for a model with state-space layers
+    """True for a model with recurrent layers, state-space or delta-rule
     (:class:`~.hybrid.HybridConfig`): its pools carry a recurrent state
     beside the KV rows and its programs come from :mod:`.hybrid`."""
     return hasattr(cfg, "layer_kinds")
@@ -323,7 +323,9 @@ def fork_page(pool, src, dst):
     pool, in place: the device half of a copy-on-write fork.  A hybrid
     pool's page takes its state entries along."""
     if isinstance(pool, _hybrid.HybridPool):
-        return jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), pool)
+        rows, ssm, conv = jax.tree.map(
+            lambda a: a.at[:, dst].set(a[:, src]), tuple(pool[:3]))
+        return pool._replace(rows=rows, ssm=ssm, conv=conv)
     return _paged.copy_page(pool, src, dst)
 
 
@@ -731,14 +733,20 @@ def full_forward(params, cfg, tokens):
 # ---------------------------------------------------------------------------
 # incremental decode over the paged KV cache
 # ---------------------------------------------------------------------------
+def recurrent_name(cfg):
+    """What a refusal calls the layers that keep a paged state."""
+    return ("delta-rule layers" if _hybrid.DELTA in cfg.layer_kinds
+            else "state-space layers")
+
+
 def _refuse_hybrid(cfg, what):
-    """The programs of a model with state-space layers exist for one chip,
-    unquantized: anything else is refused by name, never served by a
-    program that does something else."""
+    """The programs of a model with recurrent layers (state-space or
+    delta-rule) exist for one chip, unquantized: anything else is refused
+    by name, never served by a program that does something else."""
     if is_hybrid(cfg):
         raise ValueError(
-            "decoder: %s is not supported for a model with state-space "
-            "layers" % what)
+            "decoder: %s is not supported for a model with %s"
+            % (what, recurrent_name(cfg)))
 
 
 def hybrid_program(cfg, sharding, quant, kv_dtype):
@@ -1309,6 +1317,51 @@ def hybrid_lm(seed=0, **kw):
     under the name a replica spec or a benchmark configuration gives
     (``mxnet_tpu.models.decoder:hybrid_lm``)."""
     return _hybrid.hybrid_lm(seed, **kw)
+
+
+def routed_delta_lm(seed=0, *, vocab_size, num_layers, units, num_heads,
+                    num_kv_heads, head_dim, attention_layers, linear_attn,
+                    experts_held, expert_shares, experts_per_token,
+                    expert_hidden, shared_experts=1, expert_share=0,
+                    hidden_size=0, attn_gate=True, neg_eigval=True,
+                    full_proj=False, rope=False, dense_layers=0,
+                    norm_topk=True, routed_scale=1.0, tied_head=False,
+                    rms_eps=1e-5, max_length=1024, kv_dtype=None,
+                    dtype="bfloat16"):
+    """A :class:`~.hybrid.HybridLM` of the ``solar_open2`` model type, as
+    **one chip of an expert-parallel group** holds it: gated delta-rule
+    layers (``linear_attn``: the published ``linear_attn_config``) with
+    gated position-free attention at ``attention_layers``, every layer's
+    feed-forward part routed over ``experts_held * expert_shares`` experts
+    of which this model holds the ``experts_held`` of share
+    ``expert_share``, beside the shared expert.  The arguments are the
+    published keys under this repo's names (a benchmark configuration's
+    ``builder_kwargs`` maps them); what the block has no code for is
+    refused, not ignored."""
+    for what, asked in (("rotary positions (use_rope)", rope),
+                        ("leading dense layers (first_k_dense_replace)",
+                         dense_layers),
+                        ("kda_use_full_proj", full_proj),
+                        ("kda_allow_neg_eigval = false", not neg_eigval)):
+        if asked:
+            raise ValueError("decoder.routed_delta_lm: %s is not supported"
+                             % what)
+    return _hybrid.hybrid_lm(
+        seed, vocab_size=vocab_size, num_layers=num_layers, units=units,
+        hidden_size=hidden_size, num_heads=num_heads,
+        num_kv_heads=num_kv_heads, head_dim=head_dim,
+        attention_layers=[i for i in attention_layers if i < num_layers],
+        recurrent=_hybrid.DELTA, attn_gate=attn_gate, tied_head=tied_head,
+        delta_heads=linear_attn["num_heads"],
+        delta_head_dim=linear_attn["head_dim"],
+        delta_rank=linear_attn["head_dim"],
+        d_conv=linear_attn["short_conv_kernel_size"],
+        n_experts=experts_held * expert_shares, experts_held=experts_held,
+        expert_share=expert_share, experts_per_token=experts_per_token,
+        expert_hidden=expert_hidden,
+        shared_hidden=expert_hidden * shared_experts, norm_topk=norm_topk,
+        routed_scale=routed_scale, rms_eps=rms_eps, max_length=max_length,
+        kv_dtype=kv_dtype, dtype=dtype)
 
 
 # at the end: hybrid.py builds on the helpers above

@@ -103,6 +103,7 @@ from .. import config as _config
 from .. import faults
 from ..models import decoder as _decoder
 from ..models import hybrid as _hybrid
+from ..models import routed as _routed
 from ..ops.pallas import paged_attention as _paged
 from .autoscale import SLOPolicy
 from .errors import (BadRequestError, DeadlineExceededError, QueueFullError,
@@ -111,6 +112,10 @@ from .kvcache import (CacheOOM, PageAllocator, PrefixCache, pack_session,
                       pages_for, unpack_session)
 from .metrics import ModelMetrics, ServingMetrics
 from ..profiler import span
+
+#: the metrics' counters of the first four of ``models.routed.COUNTS``
+_EXPERT_COUNTERS = ("expert_pairs_total", "expert_pairs_elsewhere_total",
+                    "experts_hit_total", "expert_pairs_fullest_total")
 
 __all__ = ["DecodeEngine", "next_rid"]
 
@@ -255,9 +260,10 @@ class DecodeEngine:
 
     ``kv_dtype`` is float32, bfloat16 or int8; left out it is the
     model's own (``config.kv_dtype``: a model published in bfloat16
-    caches in bfloat16), else float32.  A model with state-space layers
-    (:mod:`mxnet_tpu.models.hybrid`) keeps its recurrent state paged
-    beside the KV rows, one entry a page; for such a model speculation,
+    caches in bfloat16), else float32.  A model with recurrent layers,
+    state-space or delta-rule (:mod:`mxnet_tpu.models.hybrid`), keeps its
+    recurrent state paged beside the KV rows, one entry a page and
+    recurrent layer; for such a model speculation,
     a tp sharding, int8 KV, weight quantisation, session migration and
     the prefill / decode roles are refused at construction
     (``ValueError``), session export / import when called,
@@ -305,9 +311,10 @@ class DecodeEngine:
         if self.kv_dtype not in ("float32", "bfloat16", "int8"):
             raise ValueError("kv_dtype must be float32, bfloat16 or int8, "
                              "got %r" % (self.kv_dtype,))
-        # a model with state-space layers keeps a recurrent state beside
-        # its KV rows, one entry a page (models/hybrid.py); what the engine
-        # cannot keep exact for it is refused here, by name
+        # a model with recurrent layers (state-space, delta-rule) keeps a
+        # recurrent state beside its KV rows, one entry a page
+        # (models/hybrid.py); what the engine cannot keep exact for it is
+        # refused here, by name
         self.hybrid = _decoder.hybrid_program(self.cfg, sharding,
                                               self.quant, self.kv_dtype)
         if self.hybrid:
@@ -340,7 +347,7 @@ class DecodeEngine:
         kv_layers = (cfg.layer_kinds.count("attention") if self.hybrid
                      else cfg.num_layers)
         elems = 2 * kv_layers * cfg.num_kv_heads * cfg.head_dim
-        #: bytes of one page's state entries (0 without state-space layers)
+        #: bytes of one page's state entries (0 without recurrent layers)
         self.state_entry_bytes = (_hybrid.state_entry_bytes(cfg)
                                   if self.hybrid else 0)
         self.alloc = PageAllocator(
@@ -377,8 +384,6 @@ class DecodeEngine:
         self._tables = onp.zeros((self.slots, self.pages_per_seq),
                                  onp.int32)
         self._tables_dev = None  # device copy, rebuilt when rows change
-        # chipbench/serve.py reads this; it goes with those two reads
-        self.decode_fused_mode = None
         self._decode_fn = _decoder.make_decode_step(
             cfg, self.page_size, sharding=self.sharding,
             quant=self.quant, kv_dtype=self.kv_dtype)
@@ -490,14 +495,23 @@ class DecodeEngine:
         self._stage_carry = onp.zeros(self.slots, bool)
         self._active_dev = None
         self._active_key = None
+        #: a model that routes: the token-expert pairs a token makes over
+        #: its routed layers, and what the programs counted so far (the
+        #: pools' ``counts``, folded in whenever ``stats()`` is asked)
+        self._pairs_per_token = (cfg.experts_per_token * cfg.num_layers
+                                 if getattr(cfg, "n_experts", 0) else 0)
+        self._expert_counts = {
+            phase: {"seen": onp.zeros(len(_routed.COUNTS), onp.uint32),
+                    "total": [0] * len(_routed.COUNTS)}
+            for phase in ("prefill", "decode")}
 
     # -- a model with state-space layers: what is refused ------------------
     def _refuse_for_hybrid(self, speculate, migrate, pagestore, role):
         """Raise a ``ValueError`` that names the first thing asked of this
         engine which it cannot keep exact for a model with state-space
-        layers (ROADMAP, "What the system cannot run yet"); what the
-        programs cannot do (a tp sharding, quantisation, int8 KV)
-        ``decoder.hybrid_program`` has refused already."""
+        layers or delta-rule layers (ROADMAP, "What the system cannot run
+        yet"); what the programs cannot do (a tp sharding, quantisation,
+        int8 KV) ``decoder.hybrid_program`` has refused already."""
         def want(arg, knob):
             return bool(arg if arg is not None else _config.get(knob))
         refused = [
@@ -516,14 +530,15 @@ class DecodeEngine:
             if asked:
                 raise ValueError(
                     "decode engine %r: %s is not supported for a model "
-                    "with state-space layers" % (self.name, what))
+                    "with %s" % (self.name, what,
+                                 _decoder.recurrent_name(self.cfg)))
 
     def _refuse_session_wire(self, what):
         if self.hybrid:
             raise ValueError(
                 "decode engine %r: %s is not supported for a model with "
-                "state-space layers (the wire format carries no state "
-                "entries)" % (self.name, what))
+                "%s (the wire format carries no state entries)"
+                % (self.name, what, _decoder.recurrent_name(self.cfg)))
 
     # -- admission --------------------------------------------------------
     @property
@@ -1513,7 +1528,7 @@ class DecodeEngine:
             return
         rid = slot.req.rid
         # pages of the sequence the chunk writes: each gets a state entry
-        # from a model with state-space layers
+        # from a model with recurrent layers
         touched = (pages_for(slot.pos + n, self.page_size)
                    - slot.pos // self.page_size)
         if self.hybrid:
@@ -1523,7 +1538,8 @@ class DecodeEngine:
                 self.metrics.count(self.name, "state_starts_total")
         with span("engine.prefill_launch", rid=rid, slot=slot.idx,
                   tokens=n, pos=slot.pos,
-                  state_pages=touched if self.hybrid else 0):
+                  state_pages=touched if self.hybrid else 0,
+                  expert_pairs=n * self._pairs_per_token):
             chunk = slot.prompt[slot.done:slot.done + n]
             padded = onp.zeros(self.prefill_chunk, onp.int32)
             padded[:n] = chunk
@@ -1548,7 +1564,7 @@ class DecodeEngine:
             # keep writing into the trailing partial page, but only at
             # offsets past its published token count, which hitters
             # never read (and a hitter forks it copy-on-write anyway).
-            # A model with state-space layers publishes WHOLE pages
+            # A model with recurrent layers publishes WHOLE pages
             # only: a page's state entry is the state after its last
             # token, and the owner keeps writing the trailing page's.
             whole = (len(slot.history) // self.page_size * self.page_size
@@ -1704,7 +1720,8 @@ class DecodeEngine:
         live = [s for s in live if s.state == "decode"]
         if not live:
             return False
-        with span("engine.decode_launch", lanes=len(live), depth=depth0):
+        with span("engine.decode_launch", lanes=len(live), depth=depth0,
+                  expert_pairs=len(live) * self._pairs_per_token):
             st = self._stage_tokens
             sp = self._stage_positions
             sa = self._stage_active
@@ -2532,6 +2549,47 @@ class DecodeEngine:
             self._store_client.close()
         return ok
 
+    def _expert_stats(self):
+        """What the routed layers counted (``models.routed.COUNTS``), by
+        the program that counted it, since the engine's start.  The
+        programs sum into a uint32 leaf of their pools (the prefill chunk
+        into the K pool's, the decode step into the V pool's); this reads
+        both, folds what is new into host integers (exact while fewer than
+        2**32 pairs pass between two reads) and into the metrics'
+        counters, and never runs in a step's path.  A read that meets a
+        pool the next launch has just taken keeps the totals of the last
+        one."""
+        held = self.cfg.experts_held[1]
+        out, names = {}, _routed.COUNTS
+        for phase, pool in (("prefill", self._kp), ("decode", self._vp)):
+            kept = self._expert_counts[phase]
+            try:
+                now = onp.asarray(pool.counts).astype(onp.uint32)
+            except RuntimeError:        # donated to a launch meanwhile
+                now = kept["seen"]
+            with self._cond:
+                new = [int(d) for d in now - kept["seen"]]  # modulo 2**32
+                kept["seen"] = now
+                kept["total"] = [a + b for a, b in zip(kept["total"], new)]
+                total = dict(zip(names, kept["total"]))
+            for counter, n in zip(_EXPERT_COUNTERS, new):
+                if n:
+                    self.metrics.count(self.name, counter, n)
+            n = total["layer_launches"]
+            out[phase] = dict(
+                total, **{k + "_per_launch": total[k] / n if n else None
+                          for k in names[:4]},
+                load_max_over_mean=(held * total["pairs_fullest"]
+                                    / total["pairs"]
+                                    if total["pairs"] else None))
+        both = {k: out["prefill"][k] + out["decode"][k] for k in names}
+        out.update(both, held=held, of=self.cfg.n_experts,
+                   per_token=self.cfg.experts_per_token,
+                   load_max_over_mean=(held * both["pairs_fullest"]
+                                       / both["pairs"]
+                                       if both["pairs"] else None))
+        return out
+
     def _tokens_resident(self):
         """Logical tokens currently cached in pool pages: live slots'
         positions plus parked sessions' (replay-pending sessions hold a
@@ -2581,11 +2639,13 @@ class DecodeEngine:
             out["prefix_cache"] = self.prefix_cache.stats()
         if self._spec is not None:
             out["speculative"] = self._spec.stats()
+        if self._pairs_per_token:
+            out["experts"] = self._expert_stats()
         if self.hybrid:
             # the recurrent state paged beside the KV rows: one entry a
-            # page (scratch page included) and state-space layer
+            # page (scratch page included) and recurrent layer
             out["state"] = {
-                "layers": self.cfg.layer_kinds.count("state_space"),
+                "layers": _hybrid.recurrent_layers(self.cfg),
                 "entry_bytes": self.state_entry_bytes,
                 "pool_bytes": (self.state_entry_bytes
                                * self.alloc.total_pages),

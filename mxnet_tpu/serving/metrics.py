@@ -105,6 +105,13 @@ class ModelMetrics:
                 # their state entries) attached on a prefix hit
                 "state_entries_written_total", "state_starts_total",
                 "state_prefix_pages_shared_total",
+                # a model that routes (PR 35), from the programs' own
+                # counts, folded in when the engine's stats are asked:
+                # token-expert pairs on experts held here and on other
+                # chips', and per layer and launch the held experts that
+                # a token chose and the fullest one's pairs
+                "expert_pairs_total", "expert_pairs_elsewhere_total",
+                "experts_hit_total", "expert_pairs_fullest_total",
                 # a full pool (PR 32): pages an allocation took back from
                 # the prefix cache, and blocking retires of the whole
                 # decode pipeline that found launches in flight, by

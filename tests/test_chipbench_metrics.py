@@ -36,10 +36,19 @@ END_TO_END = {m["name"]: m for m in BENCH["end_to_end"]}
 
 
 CONFIG_OF = {w["name"]: w["config"] for w in BENCH["workloads"]}
-#: configurations whose model has state-space layers: a metric that only
-#: their cells report reads the facts of such an engine
-HYBRID = {c["name"] for c in BENCH["configs"] if "hybrid_lm" in
-          harness.load("configs", c["name"] + ".json").get("builder", "")}
+
+
+def engine_kind(config):
+    """Which of the tiny engines below stands for a configuration's: the
+    classic block's, one with state-space layers, or one that routes over
+    delta-rule layers.  A metric reads the facts of every kind of engine
+    among the cells that report it."""
+    builder = harness.load("configs", config + ".json").get("builder", "")
+    return ("routed" if "routed_delta_lm" in builder
+            else "hybrid" if "hybrid_lm" in builder else "classic")
+
+
+KIND_OF = {c["name"]: engine_kind(c["name"]) for c in BENCH["configs"]}
 
 
 def served_facts(lm):
@@ -64,9 +73,16 @@ def served_facts(lm):
 
 @pytest.fixture(scope="module")
 def facts():
-    return {False: served_facts(decoder.decoder_tiny_lm(seed=0,
-                                                        vocab_size=128)),
-            True: served_facts(decoder.hybrid_lm(seed=0))}
+    return {"classic": served_facts(decoder.decoder_tiny_lm(
+                seed=0, vocab_size=128)),
+            "hybrid": served_facts(decoder.hybrid_lm(seed=0)),
+            "routed": served_facts(decoder.routed_delta_lm(
+                seed=0, vocab_size=128, num_layers=4, units=32, num_heads=4,
+                num_kv_heads=2, head_dim=16, attention_layers=[0],
+                linear_attn={"num_heads": 4, "head_dim": 8,
+                             "short_conv_kernel_size": 4},
+                experts_held=4, expert_shares=2, experts_per_token=2,
+                expert_hidden=16, max_length=128))}
 
 
 def test_every_per_layer_metric_has_its_file():
@@ -87,17 +103,21 @@ def test_metric_file_matches_benchmark_and_reads_a_number(name, facts):
     assert callable(reader)
     if spec["reader"] != "stats_path":
         return
-    facts = facts[all(CONFIG_OF[w] in HYBRID for w in entry["workloads"])]
     args = spec["args"]
-    for path in (args["path"], args.get("over")):
-        if path is not None and path[0] == "stats":
-            value = facts
-            for key in path:
-                assert isinstance(value, dict) and key in value, (
-                    "%s: no %r on the way down %r" % (name, key, path))
-                value = value[key]
-            assert isinstance(value, (int, float)), (name, path, value)
-    if all(p is None or p[0] == "stats"
-           for p in (args["path"], args.get("over"))):
-        value = reader(facts, **args)
-        assert value is not None and value >= 0.0, (name, value)
+    for kind in sorted({KIND_OF[CONFIG_OF[w]] for w in entry["workloads"]
+                        if harness.load("configs", CONFIG_OF[w] + ".json")
+                        ["kind"] == "serve"}):
+        served = facts[kind]
+        for path in (args["path"], args.get("over")):
+            if path is not None and path[0] == "stats":
+                value = served
+                for key in path:
+                    assert isinstance(value, dict) and key in value, (
+                        "%s (%s engine): no %r on the way down %r"
+                        % (name, kind, key, path))
+                    value = value[key]
+                assert isinstance(value, (int, float)), (name, path, value)
+        if all(p is None or p[0] == "stats"
+               for p in (args["path"], args.get("over"))):
+            value = reader(served, **args)
+            assert value is not None and value >= 0.0, (name, kind, value)
