@@ -434,10 +434,12 @@ register("MXNET_GEN_DISAGG_MIN_PROMPT", int, 32, "honored",
          "unless the fleet has both a prefill and a decode pool)",
          "serving.Router")
 register("MXNET_PAGED_ATTENTION", str, "", "honored",
-         "paged-attention dispatch: '' auto (Pallas kernel on TPU for "
-         "head_dim a multiple of 128, XLA gather reference elsewhere), "
-         "'0' forces the reference, 'interpret' runs the Pallas kernel "
-         "in the TPU interpreter",
+         "paged-attention dispatch: '' auto (on a TPU jax's Pallas "
+         "kernel over the pages form for head_dim a multiple of 128, the "
+         "decode step's own over a rows-form pool whose page is whole "
+         "lane tiles; XLA gather references elsewhere), '0' forces the "
+         "references, 'interpret' runs the Pallas kernels in the TPU "
+         "interpreter",
          "ops.pallas.paged_attention")
 register("MXNET_RNN_SCAN_UNROLL", int, 5, "honored",
          "RNN time-scan unroll factor (read per call; any seq_len "

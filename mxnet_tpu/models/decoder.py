@@ -449,8 +449,9 @@ def _gather_kv(pages, li, tables, num_kv_heads):
     pages; their products run over (.., C, D) per head as a full-cache
     decoder's do).  The decode step does not: setting the
     context head-major costs three passes over it where ``D`` is half a
-    lane tile, so it reads :func:`_gather_rows` and agrees with this
-    view to float32 rounding (:func:`_decode_attention`)."""
+    lane tile, so it reads token rows, through the kernel that walks each
+    lane's live pages or through :func:`_gather_rows`, and agrees with
+    this view to float32 rounding (:func:`_decode_attention`)."""
     rows = _codes(pages)
     b, pps = tables.shape
     S = rows.shape[2]
@@ -462,21 +463,15 @@ def _gather_kv(pages, li, tables, num_kv_heads):
     return ctx.reshape(b, num_kv_heads, pps * S, -1)
 
 
-def _head_major(pages, li, num_kv_heads):
-    """Layer ``li`` of a float rows-form pool as the paged-attention
-    kernel wants it: (KVH, P, S, D).  A copy of the layer's slab, so
-    only the kernel path takes it."""
-    P, S = pages.shape[1:3]
-    return pages[li].reshape(P, S, num_kv_heads, -1).transpose(2, 0, 1, 3)
-
-
 def _gather_rows(pages, li, tables):
     """The context of :func:`_gather_kv` as it lies in the pool: the
     pages of ``tables`` (B, pages_per_seq) of layer ``li`` as token rows
     (B, pages_per_seq * S, KVH * D), no head axis split off.  int8 codes
     are dequantized in the row form, the page's per-head scale spread
     over its head's lanes: value for value what :func:`_gather_kv`
-    gives."""
+    gives.  Every page of every table, whatever the lengths: what the
+    decode step reads where the kernel is not selected, and the kernel's
+    reference."""
     ctx = _codes(pages)[li, tables]                    # (B,pps,S,KVH*D)
     b, pps, S, width = ctx.shape
     if isinstance(pages, _paged.QPages):
@@ -488,25 +483,30 @@ def _gather_rows(pages, li, tables):
 
 def _decode_attention(q, k_pages, v_pages, li, lengths, tables,
                       num_kv_heads):
-    """One query token per sequence against layer ``li`` of the pools:
-    jax's Pallas kernel where :mod:`~..ops.pallas.paged_attention`
-    selects it (float pools, head_dim a multiple of 128 on a TPU; the
-    interpreter under ``MXNET_PAGED_ATTENTION=interpret``), else the
-    gather and the masked f32 softmax over the gathered token rows as
-    they lie (:func:`~..ops.pallas.paged_attention.attend_rows`): the
-    mathematics of ``attend_ctx(_gather_kv(..))`` without the head-major
-    relayout of the context that prefill, verify and the hybrid programs
-    still read through; the two agree to float32 rounding."""
-    if (not isinstance(k_pages, _paged.QPages)
-            and _paged.kernel_mode_for(q.shape[-1]) is not None):
-        return _paged.paged_attention(
-            q, _head_major(k_pages, li, num_kv_heads),
-            _head_major(v_pages, li, num_kv_heads), lengths, tables)
+    """One query token per sequence against layer ``li`` of the pools,
+    as token rows either way (the mathematics of
+    ``attend_ctx(_gather_kv(..))`` without the head-major relayout of the
+    context that prefill, verify and the hybrid programs still read
+    through; the two agree to float32 rounding).
+
+    Where :func:`~..ops.pallas.paged_attention.rows_kernel_mode` selects
+    the kernel (a float pool whose page is whole lane tiles, on a TPU;
+    the interpreter under ``MXNET_PAGED_ATTENTION=interpret``) each
+    lane's page table is walked up to its length and those pages are read
+    out of the pool once (:func:`~..ops.pallas.paged_attention.
+    paged_attend_rows`).  Else (int8 pools, a ``tp`` shard's row under
+    128 lanes, the CPU) every page of every table is gathered and the
+    masked f32 softmax runs over the gathered rows
+    (:func:`~..ops.pallas.paged_attention.attend_rows`), which is the
+    reference the kernel is tested against."""
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    if _paged.rows_kernel_mode(k_pages) is not None:
+        return _paged.paged_attend_rows(q, k_pages, v_pages, li, lengths,
+                                        tables, scale, num_kv_heads)
     _paged.last_path = "xla"
     return _paged.attend_rows(
         q, _gather_rows(k_pages, li, tables),
-        _gather_rows(v_pages, li, tables), lengths,
-        1.0 / (q.shape[-1] ** 0.5), num_kv_heads)
+        _gather_rows(v_pages, li, tables), lengths, scale, num_kv_heads)
 
 
 # ---------------------------------------------------------------------------

@@ -10,8 +10,9 @@ and attention gathers through the table.  Allocation/eviction become
 O(1) free-list ops (``serving/kvcache.py``) and admission control is
 exact page accounting instead of worst-case reservation.
 
-Two backends behind one call (the ops/attention.py, ops/pallas/epilogue.py
-dispatch shape):
+Two backends behind :func:`paged_attention` (the ops/attention.py,
+ops/pallas/epilogue.py dispatch shape), over the pages form
+``(KVH, P, S, D)``:
 
 - **TPU**: ``jax.experimental.pallas.ops.tpu.paged_attention`` — the
   Pallas GQA kernel (SNIPPETS [3] shards this very kernel along KV
@@ -25,38 +26,65 @@ dispatch shape):
   contiguous cache a non-paged decoder would hold, so a program that
   reads through this view matches a full-cache decode bit for bit.
 
-Who reads through that head-major view: this op's reference and int8
-paths (:func:`attend_ctx`) and, with their own causal products over the
-same view (``models.decoder._gather_kv``), the prefill-chunk and verify
-programs and the hybrid model's attention layers.  The decode step of
-``models.decoder`` does not, where the kernel is not selected: it reads
-the same gathered pages as token rows
-``(B, C, KVH * D)`` through :func:`attend_rows`, which never splits the
-rows' lane axis (at head_dim 64 that split cost more than the attention,
-PERF.md PR 30).  The two are the same mathematics with the float
-additions in another order, so the decode step agrees with the
-head-major view to float32 rounding (``tests/test_decode_attention_rows
-.py``: 1e-5 of the outputs' std), and with the other programs in its
-greedy tokens, not in the bits of its logits.
+**Who reads through which path** (the step programs of
+``models.decoder`` hold the pools as token rows ``(L, P, S, KVH * D)``,
+never in the pages form):
 
-``MXNET_PAGED_ATTENTION`` — ``0``/``off`` forces the reference,
-``interpret`` runs the Pallas kernel through the TPU interpreter
-(``pltpu.force_tpu_interpret_mode`` — the CPU test lane for the kernel
-and its wrapper), default selects the kernel on a TPU backend for heads
-whose ``head_dim`` is a multiple of the 128 lanes (the compiler refuses
-the rest).  A kernel selected here that fails to compile fails the call.
+- the head-major view ``(B, KVH, C, D)`` (``models.decoder._gather_kv``
+  and :func:`attend_ctx`'s mathematics): this op's reference and int8
+  paths, the prefill-chunk and verify programs, the hybrid models'
+  attention layers; a sequence's own pages, gathered whole.
+- the decode step of the classic block, on a TPU, over a float pool
+  whose page is whole lane tiles: :func:`paged_attend_rows`, this
+  module's own kernel over the pool as it lies.  One query token a
+  lane; each lane's page table is walked up to its length and those
+  pages are copied out of the pool once, a block at a time, keys and
+  values, into :func:`attend_rows`' two products and flash-decoding's
+  running maximum and sum.  Pages past a length are never touched (in
+  the chat cell some four fifths of the table: PERF.md, PR 36), and an
+  inactive lane reads nothing.  Selected by :func:`rows_kernel_mode`
+  from what the pool is, not by a model's name.
+- the same decode step everywhere else (int8 :class:`QPages`, a ``tp``
+  shard's row under 128 lanes, a page that is no whole tile, the CPU):
+  every page of every table gathered as token rows
+  (``models.decoder._gather_rows``) into :func:`attend_rows`, which
+  never splits the rows' lane axis (at head_dim 64 that split cost more
+  than the attention, PERF.md PR 30).  This is the reference the kernel
+  is tested against.
+
+The three are the same mathematics with the float additions in another
+order, so the decode step agrees with the head-major view to float32
+rounding (``tests/test_decode_attention_rows.py``: 1e-5 of the outputs'
+std, the kernel in the interpreter included), and with the other
+programs in its greedy tokens, not in the bits of its logits.
+
+``MXNET_PAGED_ATTENTION`` — ``0``/``off`` forces the references,
+``interpret`` runs the kernels through the Pallas interpreter (the CPU
+test lane: ``pltpu.force_tpu_interpret_mode`` around jax's kernel,
+``interpret=True`` for this module's own, whose jitted call is run again
+from jax's cache, which the TPU interpreter's buffer ids do not
+survive), default selects a kernel on
+a TPU backend where the compiler takes it: jax's for heads whose
+``head_dim`` is a multiple of the 128 lanes (:func:`kernel_mode_for`),
+this module's for a pool whose page is whole tiles
+(:func:`rows_kernel_mode`).  A kernel selected here that fails to
+compile fails the call.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import kernel_mode
 
-__all__ = ["paged_attention", "paged_attention_reference", "copy_page",
-           "QPages", "gather_pages_deq", "last_path"]
+__all__ = ["paged_attention", "paged_attention_reference",
+           "paged_attend_rows", "copy_page", "QPages", "gather_pages_deq",
+           "last_path"]
 
 
 class QPages(NamedTuple):
@@ -98,15 +126,19 @@ def _mode():
 
 
 def kernel_mode_for(head_dim):
-    """:func:`_mode` for heads of ``head_dim``: None where the compiler
-    refuses the kernel."""
+    """:func:`_mode` for jax's kernel (:func:`paged_attention`, the pages
+    form) over heads of ``head_dim``: None where the compiler refuses
+    it.  The decode step does not ask here: it reads the pool as rows
+    through :func:`paged_attend_rows`, at any head_dim
+    (:func:`rows_kernel_mode`)."""
     mode = _mode()
     if mode == "compiled" and head_dim % 128:
         # jax's kernel blocks its (.., 1) softmax carries by head_dim, and
         # Mosaic refuses a 64-wide block of a 1-wide array: "the last two
         # dimensions of your block shape [must be] divisible by 8 and 128
         # respectively, or be equal to the respective dimensions of the
-        # overall array" (v5e, PR 21).  Such heads read through the gather.
+        # overall array" (v5e, PR 21).  Such heads read through the gather
+        # reference of this op.
         mode = None
     return mode
 
@@ -211,6 +243,30 @@ def attend_ctx(q, k_ctx, v_ctx, lengths, scale):
     return out.reshape(b, h, d).astype(q.dtype)
 
 
+def _own_lanes(h, num_kv_heads, head_dim):
+    """own[h, e]: lane ``e`` of a token row belongs to the KV head that
+    head ``h`` reads (head ``h`` reads KV head ``h // g``)."""
+    g = h // num_kv_heads
+    return (jnp.arange(num_kv_heads * head_dim, dtype=jnp.int32)[None, :]
+            // head_dim == jnp.arange(h, dtype=jnp.int32)[:, None] // g)
+
+
+def _q_rows(q, scale, own):
+    """The scaled query of head ``h`` laid into a whole token row that is
+    zero outside the lanes of its KV head: (B, H, D) -> (B, H, KVH * D)."""
+    kvh = own.shape[1] // q.shape[-1]
+    qf = q.astype(jnp.float32) * scale
+    return jnp.where(own[None], jnp.tile(qf, (1, 1, kvh)), 0.0)
+
+
+def _pick_own(out_rows, own, head_dim):
+    """The block-diagonal pick, on (B, H, KVH * D): each head keeps its
+    own lanes of the weighted rows; exact zeros added."""
+    b, h, width = out_rows.shape
+    return jnp.where(own[None], out_rows, 0.0).reshape(
+        b, h, width // head_dim, head_dim).sum(2)
+
+
 def attend_rows(q, k_rows, v_rows, lengths, scale, num_kv_heads):
     """:func:`attend_ctx`'s attention over the context as token rows.
 
@@ -226,22 +282,192 @@ def attend_rows(q, k_rows, v_rows, lengths, scale, num_kv_heads):
     as whole rows, and each head keeps its own lanes of them.  The added
     terms are exact zeros, so this is :func:`attend_ctx`'s mathematics
     with the float additions in another order: the two agree to float32
-    rounding, not bit for bit."""
+    rounding, not bit for bit.
+
+    This reads a context that was gathered whole, every page of every
+    table; it is the reference :func:`paged_attend_rows` is tested
+    against and what int8 pools, rows under 128 lanes and the CPU read."""
     b, h, d = q.shape
-    width = k_rows.shape[-1]
-    kvh = int(num_kv_heads)
-    g = h // kvh
-    # own[h, e]: lane e belongs to the KV head that head h reads
-    own = (jnp.arange(width, dtype=jnp.int32)[None, :] // d
-           == jnp.arange(h, dtype=jnp.int32)[:, None] // g)
-    qf = q.astype(jnp.float32) * scale
-    q_rows = jnp.where(own[None], jnp.tile(qf, (1, 1, kvh)), 0.0)
-    logits = jnp.einsum("bhe,bce->bhc", q_rows, k_rows.astype(jnp.float32))
+    own = _own_lanes(h, int(num_kv_heads), d)
+    logits = jnp.einsum("bhe,bce->bhc", _q_rows(q, scale, own),
+                        k_rows.astype(jnp.float32))
     p = _masked_softmax(logits, lengths)
     out_rows = jnp.einsum("bhc,bce->bhe", p, v_rows.astype(jnp.float32))
-    # the block-diagonal pick, on (B, H, KVH * D): exact zeros added
-    out = jnp.where(own[None], out_rows, 0.0).reshape(b, h, kvh, d).sum(2)
-    return out.astype(q.dtype)
+    return _pick_own(out_rows, own, d).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# the decode step's kernel: the walk over each lane's live pages
+# ---------------------------------------------------------------------------
+_MASKED = -1e30         # a score past the length; finite, so no inf - inf
+_BLOCK_TOKENS = 128     # tokens a block of pages holds (8 pages of 16)
+
+
+def rows_kernel_mode(pool):
+    """:func:`_mode` for :func:`paged_attend_rows` over ``pool``, by what
+    the code sees: a float array in rows form ``(L, P, S, KVH * D)`` whose
+    page is whole ``(8, 128)`` tiles of its dtype (the row a multiple of
+    128 lanes, the page a multiple of the dtype's sublane tile), so that a
+    page is one contiguous slab a copy can take as it lies.  None for
+    everything else: int8 :class:`QPages`, a ``tp`` shard's row under 128
+    lanes, a page of 4 tokens."""
+    if isinstance(pool, QPages) or not jnp.issubdtype(pool.dtype,
+                                                      jnp.floating):
+        return None
+    S, width = pool.shape[2:]
+    if width % 128 or S % (32 // pool.dtype.itemsize):
+        return None
+    return _mode()
+
+
+def _rows_kernel(li_ref, len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
+                 k_buf, v_buf, sems, slot_ref, *, page, block_pages,
+                 pages_per_seq):
+    """One lane a grid step: flash-decoding's recurrence over the lane's
+    live pages, a block of ``block_pages`` of them at a time, each page
+    copied out of the pools as it lies.  Two buffers a pool: while a
+    block is attended the next one is in flight, and the last block of a
+    lane is attended while the first of the next lane comes in (the
+    buffer the next grid step starts in is handed over in ``slot_ref``).
+    Pages past a length get no copy and no product."""
+    lane, lanes = pl.program_id(0), pl.num_programs(0)
+    tokens = block_pages * page
+    li = li_ref[0]
+
+    def each_copy(of, block, slot, do):
+        live = (len_ref[of] + page - 1) // page
+        first = block * block_pages
+
+        def one(j, carry):
+            pid = tab_ref[of * pages_per_seq + first + j]
+            do(pltpu.make_async_copy(
+                k_hbm.at[li, pid], k_buf.at[slot, j], sems.at[0, slot]))
+            do(pltpu.make_async_copy(
+                v_hbm.at[li, pid], v_buf.at[slot, j], sems.at[1, slot]))
+            return carry
+        jax.lax.fori_loop(0, jnp.clip(live - first, 0, block_pages), one, 0)
+
+    def start(of, block, slot):
+        each_copy(of, block, slot, lambda copy: copy.start())
+
+    @pl.when(lane == 0)
+    def _():
+        slot_ref[0] = 0
+        start(0, 0, 0)
+
+    n = len_ref[lane]
+    blocks = (n + tokens - 1) // tokens
+    first = slot_ref[0]
+    q = q_ref[...]                                      # (H, KVH * D)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, tokens), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (tokens, 1), 0)
+
+    def attend(i, carry):
+        m, l, acc = carry
+        slot = (first + i) % 2
+
+        @pl.when(i + 1 < blocks)
+        def _():
+            start(lane, i + 1, 1 - slot)
+
+        @pl.when((i + 1 == blocks) & (lane + 1 < lanes))
+        def _():
+            start(lane + 1, 0, 1 - slot)
+
+        each_copy(lane, i, slot, lambda copy: copy.wait())
+        left = n - i * tokens                           # live tokens here
+        s = jax.lax.dot_general(
+            q, k_buf[slot].reshape(tokens, -1).astype(jnp.float32),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)         # (H, tokens)
+        s = jnp.where(col < left, s, _MASKED)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        # what lies past the length in the buffer was never copied:
+        # zeroed, since 0 * NaN is no zero
+        v = jnp.where(row < left, v_buf[slot].reshape(tokens, -1).astype(
+            jnp.float32), 0.0)
+        return (m_new, alpha * l + p.sum(axis=-1, keepdims=True),
+                alpha * acc + jnp.dot(p, v,
+                                      preferred_element_type=jnp.float32))
+
+    h, width = q_ref.shape
+    m, l, acc = jax.lax.fori_loop(0, blocks, attend, (
+        jnp.full((h, 1), _MASKED, jnp.float32),
+        jnp.zeros((h, 1), jnp.float32), jnp.zeros((h, width), jnp.float32)))
+    # a length of 0 attended nothing: its zeros over 1, not 0 / 0
+    o_ref[...] = acc / jnp.where(l > 0.0, l, 1.0)
+
+    @pl.when((blocks == 0) & (lane + 1 < lanes))
+    def _():
+        start(lane + 1, 0, first)
+    slot_ref[0] = (first + blocks) % 2
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _rows_call(li, lengths, tables, q_rows, k_pool, v_pool, *, interpret):
+    """The kernel over (1,) layer index, (B,) lengths, (B * pps,) page
+    table, (B, H, KVH * D) query rows and the two pools.  A jitted
+    function of its own so that the layers of a step program, which
+    differ in ``li``'s value alone, share one trace of the kernel and one
+    lowering of it to Mosaic: traced and lowered a layer at a time they
+    took 8 s of every process's warm-up at 12 layers, whatever the
+    compile cache holds (PERF.md, PR 36)."""
+    b, hp, width = q_rows.shape
+    S = k_pool.shape[2]
+    pps = tables.shape[0] // b
+    block_pages = max(1, min(pps, _BLOCK_TOKENS // S))
+    lane = pl.BlockSpec((None, hp, width), lambda i, *_: (i, 0, 0))
+    buf = pltpu.VMEM((2, block_pages, S, width), k_pool.dtype)
+    return pl.pallas_call(
+        functools.partial(_rows_kernel, page=S, block_pages=block_pages,
+                          pages_per_seq=pps),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b,),
+            in_specs=[lane, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=lane,
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((b, hp, width), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_attend_rows",
+    )(li, lengths, tables, q_rows, k_pool, v_pool)
+
+
+def paged_attend_rows(q, k_pool, v_pool, li, lengths, tables, scale,
+                      num_kv_heads):
+    """:func:`attend_rows` over layer ``li`` of the pools without the
+    gather: one query token a lane, each lane's page table walked up to
+    its length and those pages read out of the pools once.
+
+    q: (B, H, D); k_pool/v_pool: the pools whole, rows form
+    ``(L, P, S, KVH * D)`` float, left where they are (the kernel copies
+    pages out of them; the layer goes in as a scalar: slicing it out
+    would make XLA copy a layer's slab before the call); lengths: (B,)
+    valid keys, 0 for an inactive lane, which reads nothing and gives
+    zeros; tables: (B, pages_per_seq).  The products are
+    :func:`attend_rows`' two, over a block of pages as they lie, at the
+    default precision (on the TPU one bfloat16 pass for float32
+    operands, in Mosaic as in XLA: measured, PERF.md PR 36; float32 in
+    the interpreter, as on the CPU), the softmax's maximum and sum
+    carried in float32 from block to block.  Only where
+    :func:`rows_kernel_mode` selects it."""
+    global last_path
+    mode = rows_kernel_mode(k_pool)
+    b, h, d = q.shape
+    own = _own_lanes(h, int(num_kv_heads), d)
+    hp = -(-h // 8) * 8                 # whole sublane tiles of heads
+    q_rows = jnp.pad(_q_rows(q, scale, own), ((0, 0), (0, hp - h), (0, 0)))
+    out_rows = _rows_call(
+        jnp.asarray(li, jnp.int32).reshape(1), lengths.astype(jnp.int32),
+        tables.astype(jnp.int32).reshape(-1), q_rows, k_pool, v_pool,
+        interpret=mode == "interpret")
+    last_path = "pallas" if mode == "compiled" else "pallas-interpret"
+    return _pick_own(out_rows[:, :h], own, d).astype(q.dtype)
 
 
 def paged_attention_reference(q, k_pages, v_pages, lengths, page_indices,

@@ -1623,6 +1623,7 @@ class DecodeEngine:
             tokens[s.idx] = s.pending
             positions[s.idx] = s.pos
             active[s.idx] = True
+        self._count_decode_pages(positions, active)
         t0 = time.perf_counter()
         if self._t_force_end is not None:
             # host gap: wall time this step spent on scheduling between
@@ -1652,6 +1653,16 @@ class DecodeEngine:
         self.metrics.observe_decode_step(
             self.name, now - t0, now - t0, len(live), self.slots,
             len(live))
+
+    def _count_decode_pages(self, positions, active):
+        """What the staged launch's attention has to read: the pages
+        under ``position + 1`` of each active lane, beside the whole
+        table the program is handed."""
+        live = -(-(positions[active] + 1) // self.page_size)
+        self.metrics.count(self.name, "decode_pages_live_total",
+                           int(live.sum()))
+        self.metrics.count(self.name, "decode_pages_table_total",
+                           self.slots * self.pages_per_seq)
 
     # -- async decode pipeline --------------------------------------------
     def _decode_async(self):
@@ -1739,6 +1750,7 @@ class DecodeEngine:
                     chain = True
                 else:
                     st[s.idx] = s.pending
+            self._count_decode_pages(sp, sa)
             # reused staging buffers: upload must COPY (_upload) — the
             # dispatch reads host memory asynchronously and we refill these
             # arrays before it completes
@@ -2114,6 +2126,7 @@ class DecodeEngine:
                 st[s.idx] = s.pending
                 sp[s.idx] = s.pos
                 sa[s.idx] = True
+            self._count_decode_pages(sp, sa)
             t0 = time.perf_counter()
             self._kp, self._vp, out, _ = self._decode_fn(
                 self.params, self._kp, self._vp, _upload(st),

@@ -118,7 +118,12 @@ class ModelMetrics:
                 # cause: a page a lane grew into with the pool held by
                 # live sequences and sessions, a worker op
                 "kv_reclaimed_pages_total", "pipe_flushes_total",
-                "pipe_flushes_page_pressure_total", "pipe_flushes_ops_total")
+                "pipe_flushes_page_pressure_total", "pipe_flushes_ops_total",
+                # what a decode step has to read (PR 36), counted where a
+                # launch is staged: the pages under ``position + 1`` of
+                # its active lanes, and the page table whole (slots x
+                # pages a sequence may hold)
+                "decode_pages_live_total", "decode_pages_table_total")
 
     #: parts of an engine step, host wall seconds summed over the window
     #: (DecodeEngine._step): ``ops`` worker ops + expiry, ``admit``,

@@ -218,7 +218,9 @@ def test_continuous_admit_evict_per_step(lm):
         long = eng.submit([2, 3], max_new_tokens=48)
         watch("med", med)
         watch("long", long)
-        time.sleep(0.05)
+        # no pause here: admission is in order of submission, and a test
+        # thread held up on a loaded machine for longer than the long
+        # request's 48 steps would submit the shorts too late
         short1 = eng.submit([4, 5], max_new_tokens=2)
         short2 = eng.submit([5, 6], max_new_tokens=2)
         watch("short1", short1)

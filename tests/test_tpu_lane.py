@@ -474,6 +474,47 @@ def test_paged_attention_kernel_on_chip(head_dim):
                                 rtol=2e-2, atol=2e-2)
 
 
+@pytest.mark.parametrize("heads, kv_heads, head_dim",
+                         [(12, 12, 64), (32, 8, 128)])
+def test_paged_attend_rows_kernel_on_chip(heads, kv_heads, head_dim):
+    """The decode step's own kernel (PR 36) at the serving cells' cache
+    geometry (32 slots x 64 pages of 16) against the gather it replaced,
+    selected by ``_decode_attention`` itself: idle lanes give zeros, and
+    a pool whose pages past every length hold NaN gives the same finite
+    answer."""
+    from mxnet_tpu.models import decoder
+    from mxnet_tpu.ops.pallas import paged_attention as paged
+    rs = onp.random.RandomState(0)
+    B, S, pps, L, li = 32, 16, 64, 2, 1
+    P = B * pps + 1
+    pools = [jax.random.normal(jax.random.key(i),
+                               (L, P, S, kv_heads * head_dim), jnp.float32)
+             for i in range(2)]
+    q = jnp.asarray(rs.randn(B, heads, head_dim).astype("float32"))
+    lengths = rs.randint(1, pps * S, size=B).astype("int32")
+    lengths[::5] = 0
+    lengths[1], lengths[2] = pps * S, 1
+    tables = onp.arange(1, P, dtype="int32").reshape(B, pps)
+    args = (li, jnp.asarray(lengths), jnp.asarray(tables), kv_heads)
+    ref = paged.attend_rows(
+        q, decoder._gather_rows(pools[0], li, args[2]),
+        decoder._gather_rows(pools[1], li, args[2]), args[1],
+        1.0 / head_dim ** 0.5, kv_heads)
+    paged.last_path = None
+    out = jax.jit(decoder._decode_attention, static_argnums=(3, 6))(
+        q, *pools, *args)
+    assert paged.last_path == "pallas"
+    onp.testing.assert_allclose(onp.asarray(out), onp.asarray(ref),
+                                rtol=2e-2, atol=2e-2)
+    assert not onp.asarray(out)[lengths == 0].any()
+    dead = onp.concatenate(
+        [[0]] + [tables[b, -(-int(n) // S):] for b, n in enumerate(lengths)])
+    poisoned = [p.at[:, dead].set(jnp.nan) for p in pools]
+    again = jax.jit(decoder._decode_attention, static_argnums=(3, 6))(
+        q, *poisoned, *args)
+    assert onp.asarray(again).tobytes() == onp.asarray(out).tobytes()
+
+
 def test_lstm_sequence_kernel_on_chip():
     """The persistent LSTM cell at the word-LM width (H=650: not a
     multiple of the 128-lane tile), forward and backward against the
