@@ -23,8 +23,9 @@ GQA layout: ``num_heads`` query heads grouped onto ``num_kv_heads`` KV
 heads (head ``h`` reads KV head ``h // (H // KVH)``) — the grouping the
 TPU paged-attention kernel expects, consistent across all three paths.
 
-A second block, state-space layers with attention layers among them
-(:mod:`.hybrid`, builder :func:`hybrid_lm`), is served by the same
+A second block, recurrent layers with attention layers among them or none
+(:mod:`.hybrid`, builders :func:`hybrid_lm`, :func:`routed_delta_lm`,
+:func:`retention_lm`), is served by the same
 factories: ``make_decode_step``, ``make_prefill_chunk``, ``fresh_pool``
 and ``full_forward`` dispatch on the config (:func:`is_hybrid`), and its
 pools carry a recurrent state paged beside the KV rows.
@@ -68,7 +69,8 @@ __all__ = ["DecoderConfig", "CausalLM", "full_forward", "make_decode_step",
            "fn_cache_stats", "decode_launch_stats",
            "verify_launch_stats", "decode_collective_stats", "tp_plan",
            "TPPlan", "causal_lm", "decoder_tiny", "decoder_tiny_lm",
-           "decoder_draft", "hybrid_lm", "routed_delta_lm", "is_hybrid"]
+           "decoder_draft", "hybrid_lm", "routed_delta_lm", "retention_lm",
+           "is_hybrid"]
 
 
 # ---------------------------------------------------------------------------
@@ -243,9 +245,10 @@ def pool_shape(cfg, total_pages, page_size):
 
 
 def is_hybrid(cfg):
-    """True for a model with recurrent layers, state-space or delta-rule
-    (:class:`~.hybrid.HybridConfig`): its pools carry a recurrent state
-    beside the KV rows and its programs come from :mod:`.hybrid`."""
+    """True for a model with recurrent layers, state-space, delta-rule or
+    retention (:class:`~.hybrid.HybridConfig`): its pools carry a
+    recurrent state beside the KV rows and its programs come from
+    :mod:`.hybrid`."""
     return hasattr(cfg, "layer_kinds")
 
 
@@ -325,6 +328,8 @@ def fork_page(pool, src, dst):
     if isinstance(pool, _hybrid.HybridPool):
         rows, ssm, conv = jax.tree.map(
             lambda a: a.at[:, dst].set(a[:, src]), tuple(pool[:3]))
+        if isinstance(pool.ssm, tuple):     # retention's S, layer by layer
+            ssm = tuple(a.at[dst].set(a[src]) for a in pool.ssm)
         return pool._replace(rows=rows, ssm=ssm, conv=conv)
     return _paged.copy_page(pool, src, dst)
 
@@ -735,26 +740,32 @@ def full_forward(params, cfg, tokens):
 # ---------------------------------------------------------------------------
 def recurrent_name(cfg):
     """What a refusal calls the layers that keep a paged state."""
-    return ("delta-rule layers" if _hybrid.DELTA in cfg.layer_kinds
+    return ("retention layers" if _hybrid.RETENTION in cfg.layer_kinds
+            else "delta-rule layers" if _hybrid.DELTA in cfg.layer_kinds
             else "state-space layers")
 
 
 def _refuse_hybrid(cfg, what):
-    """The programs of a model with recurrent layers (state-space or
-    delta-rule) exist for one chip, unquantized: anything else is refused
-    by name, never served by a program that does something else."""
+    """The programs of a model with recurrent layers (state-space,
+    delta-rule or retention) exist for one chip, unquantized: anything
+    else is refused by name, never served by a program that does something
+    else."""
     if is_hybrid(cfg):
         raise ValueError(
             "decoder: %s is not supported for a model with %s"
             % (what, recurrent_name(cfg)))
 
 
-def hybrid_program(cfg, sharding, quant, kv_dtype):
+def hybrid_program(cfg, sharding, quant, kv_dtype, page_size=None,
+                   max_ctx=None):
     """True where the programs to build are :mod:`.hybrid`'s; what those
     programs cannot do is refused here (the engine asks before it builds
-    anything, the factories again for whoever calls them directly)."""
+    anything, the factories again for whoever calls them directly, and
+    the programs a retention model's page table wider than one page)."""
     if not is_hybrid(cfg):
         return False
+    if page_size is not None:
+        _hybrid._one_page(cfg, -(-int(max_ctx) // int(page_size)))
     if sharding is not None and int(sharding.axis_size("tp")) > 1:
         _refuse_hybrid(cfg, "a tp sharding")
     if quant is not None:
@@ -1362,6 +1373,38 @@ def routed_delta_lm(seed=0, *, vocab_size, num_layers, units, num_heads,
         shared_hidden=expert_hidden * shared_experts, norm_topk=norm_topk,
         routed_scale=routed_scale, rms_eps=rms_eps, max_length=max_length,
         kv_dtype=kv_dtype, dtype=dtype)
+
+
+def retention_lm(seed=0, *, vocab_size, num_layers, units, hidden_size,
+                 num_heads, num_kv_heads, head_dim, rope_theta, degree=2,
+                 tied_head=False, rms_eps=1e-6,
+                 max_length=1024, hidden_act="silu", attention_bias=False,
+                 use_sliding_window=False, rope_scaling=None,
+                 dtype="bfloat16"):
+    """A :class:`~.hybrid.HybridLM` of the ``brumby`` model type: every
+    layer power retention of degree 2 (``"retention"``) on ``num_heads``
+    query heads and ``num_kv_heads`` KV heads of ``head_dim``, rotary
+    positions of base ``rope_theta`` and RMS-normed q and k,
+    no attention layer, the dense SiLU-gated MLP of width ``hidden_size``.
+    The arguments are the published keys under this repo's names (a
+    benchmark configuration's ``builder_kwargs`` maps them); what the block
+    has no code for is refused, not ignored."""
+    for what, asked in (("a retention degree other than 2", degree != 2),
+                        ("hidden_act %r" % (hidden_act,),
+                         hidden_act != "silu"),
+                        ("attention_bias", attention_bias),
+                        ("a sliding window", use_sliding_window),
+                        ("rope_scaling", rope_scaling is not None)):
+        if asked:
+            raise ValueError("decoder.retention_lm: %s is not supported"
+                             % what)
+    return _hybrid.hybrid_lm(
+        seed, vocab_size=vocab_size, num_layers=num_layers, units=units,
+        hidden_size=hidden_size, num_heads=num_heads,
+        num_kv_heads=num_kv_heads, head_dim=head_dim,
+        recurrent=_hybrid.RETENTION, rope_theta=rope_theta,
+        tied_head=tied_head, rms_eps=rms_eps,
+        max_length=max_length, dtype=dtype)
 
 
 # at the end: hybrid.py builds on the helpers above

@@ -1,11 +1,13 @@
-"""A hybrid decoder: selective state-space layers with a few attention layers
-among them (the Jamba family's block), on the step programs of
+"""A hybrid decoder: recurrent layers (selective state-space, gated delta
+rule or power retention) with a few attention layers among them or none
+(the Jamba family's block and its kin), on the step programs of
 :mod:`.decoder`.
 
-The block is pre-norm with RMSNorm, no biases and **no positions of any
-kind**; every layer is ``h = x + mixer(rms(x));  x' = h + mlp(rms(h))`` with
-the SiLU-gated ``mlp(u) = W_down (silu(W_gate u) * (W_up u))``.  The mixer
-of a layer is named by ``HybridConfig.layer_kinds``:
+The block is pre-norm with RMSNorm, no biases and no positions outside the
+retention mixer's rotary ones; every layer is ``h = x + mixer(rms(x));
+x' = h + mlp(rms(h))`` with the SiLU-gated ``mlp(u) = W_down (silu(W_gate
+u) * (W_up u))``.  The mixer of a layer is named by
+``HybridConfig.layer_kinds``:
 
 - ``"attention"``: causal softmax attention, ``num_heads`` query heads on
   ``num_kv_heads`` KV heads, no rotary embedding.  Its keys and values live
@@ -25,8 +27,21 @@ of a layer is named by ``HybridConfig.layer_kinds``:
   the state from block to block (:func:`delta_rule_chunk`).  Its **state
   entry** is ``S`` of every head and the last ``K - 1`` inputs of the
   three convolutions, float32, paged as the state-space mixer's
-  (:class:`HybridPool` says how it lies).  A model has state-space layers
-  or delta-rule layers, not both.
+  (:class:`HybridPool` says how it lies).
+- ``"retention"``: power retention of degree 2 (the Brumby family's
+  layer), ``num_heads`` query heads on ``num_kv_heads`` KV heads of
+  ``head_dim``: ``q = RoPE(rms_head(W_q u); t) / sqrt(d)``, ``k =
+  RoPE(rms_head(W_k u); t)``, ``v = W_v u`` (``rms_head`` an RMSNorm over
+  each head, the rotation of base ``rope_theta``); one log gate a KV head
+  and token, ``log g = logsigmoid(W_decay u + b_decay)``; with ``phi(x) =
+  [x_i x_j for i <= j]`` (sqrt(2) where i < j, so ``phi(q).phi(k) =
+  (q.k)^2``):
+  ``S <- g S + phi(k) v^T``, ``z <- g z + phi(k)``, ``o = S^T phi(q) /
+  (z . phi(q))``; ``out = W_o o``.  A decode step is that recurrence; a
+  prefill chunk weighs a block's tokens by ``(q.k)^2 exp(c_t - c_s)`` and
+  hands the state from block to block (:func:`retention_chunk`).  Its
+  state entry is ``S`` and ``z`` of every KV head, float32, no
+  convolution.  A model with it has no attention layer.
 - ``"state_space"``: ``[a, z] = W_in u``; a causal depthwise convolution
   ``c_t = silu(b + sum_j w[:, j] a_{t-(K-1)+j})``; ``[d, B, C] = W_x c_t``,
   each RMS-normalised; ``delta = softplus(W_dt d + b_dt)``;
@@ -35,10 +50,10 @@ of a layer is named by ``HybridConfig.layer_kinds``:
   between tokens is ``h`` (d_inner x d_state) and the last ``K - 1``
   convolution inputs: the **state entry**, float32.
 
-The feed-forward part is ``mlp`` above or, where the config names routed
-experts (``n_experts``), the routed layer of :mod:`.routed`: top
-``experts_per_token`` of all ``n_experts``, of which this chip holds
-``experts_held``, and one shared expert.
+A model has one recurrent kind.  The feed-forward part is ``mlp`` above
+or, where the config names routed experts (``n_experts``), the routed
+layer of :mod:`.routed`: top ``experts_per_token`` of all ``n_experts``,
+of which this chip holds ``experts_held``, and one shared expert.
 
 **The state is paged** (:class:`HybridPool`).  Every page of a sequence has
 one state entry per state-space layer: the state after the last token
@@ -49,7 +64,9 @@ chunk reads the entry before ``pos0``, scans, and writes an entry for every
 page it touches.  A page is then self-contained: preemption by recompute, a
 freed page handed to another sequence and a prefix hit on whole pages need
 no second bookkeeping, at the price of one entry a page instead of one a
-sequence.
+sequence.  A retention entry (34 MB a layer at 8 KV heads of 128) is held
+once a sequence: its page is as long as the context, and a page table of
+more pages is refused.
 
 Precision: the residual stream, every norm, the convolution, ``delta``,
 ``exp``, the recurrence and the logits are float32.  A matrix product
@@ -69,6 +86,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..gluon.block import HybridBlock
 from ..gluon.parameter import Parameter
@@ -78,10 +96,11 @@ from . import routed as _routed
 
 __all__ = ["HybridConfig", "HybridPool", "HybridLM", "hybrid_lm",
            "layer_runs", "fresh_pool", "state_entry_bytes",
-           "recurrent_layers", "delta_rule_chunk",
+           "recurrent_layers", "delta_rule_chunk", "retention_chunk", "phi",
            "full_forward", "build_decode_step", "build_prefill_chunk"]
 
 ATTENTION, STATE_SPACE, DELTA = "attention", "state_space", "delta_rule"
+RETENTION = "retention"
 
 
 class HybridConfig(NamedTuple):
@@ -117,6 +136,7 @@ class HybridConfig(NamedTuple):
     shared_hidden: int = 0          # width of the shared expert
     norm_topk: bool = True
     routed_scale: float = 1.0
+    rope_theta: float = 0.0         # the retention mixer's rotary base
 
 
 class HybridPool(NamedTuple):
@@ -139,6 +159,13 @@ class HybridPool(NamedTuple):
     and ``conv`` the three convolutions' inputs, ``(K - 1) * 3 * heads *
     d_v / 2`` floats a page and layer, as a tuple of arrays ``(layers, P,
     n)`` where one row would be longer than 32 768 (:func:`_conv_parts`).
+    For retention layers ``ssm`` holds ``S`` as a tuple of one array a
+    layer, ``(P, KVH / 2, F, d)``, ``F = d (d + 1) / 2`` (the K pool KV
+    heads ``[0, KVH / 2)``): the decode step updates a layer's S of every
+    page in place and reads it whole, which XLA does with no copy only
+    where the array is that layer's alone; ``conv`` holds the normaliser
+    ``z`` of the same heads, ``KVH / 2 * F`` floats flat (in parts, as
+    above).  A sequence holds one page (:func:`_one_page`).
 
     ``counts``, only of a model that routes (else None, no leaf): what the
     routed layers counted, summed over layers and launches, uint32
@@ -169,8 +196,14 @@ def _count(cfg, kind):
 
 
 def recurrent_layers(cfg):
-    """The layers that keep a state entry: state-space or delta-rule."""
+    """The layers that keep a state entry: state-space, delta-rule or
+    retention."""
     return cfg.num_layers - _count(cfg, ATTENTION)
+
+
+def phi_width(d):
+    """Features of :func:`phi` of a vector of ``d``: ``d (d + 1) / 2``."""
+    return d * (d + 1) // 2
 
 
 def _entry_dims(cfg):
@@ -178,7 +211,12 @@ def _entry_dims(cfg):
     two pools halve, and the convolution inputs' (rows, channels): ``h``
     (d_state, d_inner) halved on the channels and K - 1 inputs, or ``S``
     (heads * d_k, d_v), a head's rows together, halved on the heads, and
-    K - 1 inputs of each of three convolutions."""
+    K - 1 inputs of each of three convolutions, or retention's ``S`` (KVH *
+    F, d) halved on the KV heads and ``z`` (KVH * F) as one row.  A model
+    has one recurrent kind."""
+    if RETENTION in cfg.layer_kinds:
+        rows = cfg.num_kv_heads * phi_width(cfg.head_dim)
+        return (rows, cfg.head_dim), 0, (1, rows)
     if DELTA in cfg.layer_kinds:
         rows = cfg.delta_heads * cfg.delta_head_dim
         return (rows, cfg.delta_head_dim), 0, (3 * (cfg.d_conv - 1), rows)
@@ -202,11 +240,16 @@ def fresh_pool(cfg, total_pages, page_size, kv_dtype):
     parts = _conv_parts(width)
     rows = [jnp.zeros((layers, P, width // parts), jnp.float32)
             for _ in range(parts)]
+    ssm = jnp.zeros((layers, P) + half, jnp.float32)
+    if RETENTION in cfg.layer_kinds:
+        ssm = tuple(jnp.zeros((P, cfg.num_kv_heads // 2,
+                               phi_width(cfg.head_dim), cfg.head_dim),
+                              jnp.float32) for _ in range(layers))
     return HybridPool(
         rows=jnp.zeros((_count(cfg, ATTENTION), P, int(page_size),
                         cfg.num_kv_heads * cfg.head_dim),
                        jnp.dtype(kv_dtype)),
-        ssm=jnp.zeros((layers, P) + half, jnp.float32),
+        ssm=ssm,
         conv=rows[0] if parts == 1 else tuple(rows),
         counts=(jnp.zeros(len(_routed.COUNTS), jnp.uint32)
                 if cfg.n_experts else None))
@@ -460,6 +503,217 @@ def _delta_step(u, lp, cfg, halves, conv):
             ext[:, 1:].reshape(conv.shape))
 
 
+# ---------------------------------------------------------------------------
+# the retention mixer
+# ---------------------------------------------------------------------------
+#: Tokens that the blocked retention weighs together: a chunk of 256 is one
+#: block, whose weights are 256 x 256 a query head.
+_RET_BLOCK = 256
+
+
+def phi(x):
+    """The symmetric square of ``x`` (..., d): ``x_i x[i:]`` for every
+    ``i`` (static slices), times sqrt(2) off the diagonal, so that ``phi(q)
+    . phi(k) = (q . k)^2`` in ``d (d + 1) / 2`` features, half of the outer
+    product's ``d^2``."""
+    d = x.shape[-1]
+    scale = np.concatenate([np.r_[1.0, np.full(d - 1 - i, 2 ** 0.5)]
+                            for i in range(d)]).astype(np.float32)
+    return jnp.concatenate([x[..., i:i + 1] * x[..., i:] for i in range(d)],
+                           axis=-1) * scale
+
+
+def _rope(x, positions, theta):
+    """``x`` (..., heads, d) rotated at its token's position (``positions``
+    of shape ``x.shape[:-2]``): the halves ``[x1 cos - x2 sin, x2 cos + x1
+    sin]`` at angles ``t / theta^(2i / d)``."""
+    half = x.shape[-1] // 2
+    inv = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) * 2
+                          / x.shape[-1])
+    ang = positions.astype(jnp.float32)[..., None, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def _retention_inputs(u, lp, cfg, positions):
+    """From the normed input ``u`` (.., units) at ``positions`` (..): ``q``
+    (.., H, d) normed, rotated and over sqrt(d); ``k`` normed and rotated,
+    ``v`` (.., KVH, d); the log gate (.., KVH)."""
+    q, k, v = _qkv(u, lp, cfg)
+    q = _rope(_rms(q, lp["q_norm"], cfg.rms_eps), positions, cfg.rope_theta)
+    k = _rope(_rms(k, lp["k_norm"], cfg.rms_eps), positions, cfg.rope_theta)
+    log_g = jax.nn.log_sigmoid(_mm_hi(u, lp["w_decay"])
+                               + lp["b_decay"].astype(jnp.float32))
+    return q / cfg.head_dim ** 0.5, k, v, log_g
+
+
+def _quotient(num, den):
+    """``num / den``; a row whose weights are all 0 reads 0, not NaN (a NaN
+    in a padded token's row would reach the others through 0 x NaN)."""
+    return num / jnp.where(den > 0, den, 1.0)[..., None]
+
+
+def retention_chunk(q, k, v, log_g, S0, z0, marks, block=_RET_BLOCK):
+    """Degree-2 power retention over a chunk from the state ``(S0, z0)``:
+    ``o_t = sum_{s<=t} w_ts v_s / sum_{s<=t} w_ts`` with ``w_ts = (q_t .
+    k_s)^2 exp(c_t - c_s)`` and ``c`` the cumulative log gate.  q: (T, H,
+    d); k, v: (T, KVH, d), query head ``h`` on KV head ``h // (H / KVH)``;
+    log_g: (T, KVH) <= 0; S0: (KVH, F, d); z0: (KVH, F); T a multiple of the
+    block (or less than one).  Returns ``o`` (T, H, d) and the state after
+    the chunk indices ``marks`` (K,): S (K, KVH, F, d), z (K, KVH, F).
+
+    Blocked: inside a block of ``C`` tokens the weights themselves, masked
+    before the exponential; from the state before the block ``exp(c_t)
+    phi(q_t)^T S_in`` and the same over ``z_in``; a scan over the blocks
+    hands the state on (``S_out = exp(c_C) S_in + sum_s exp(c_C - c_s)
+    phi(k_s) v_s^T``).  Every decay is the exponential of a difference
+    that is never positive."""
+    T, H, d = q.shape
+    KVH = k.shape[1]
+    C = min(block, T)
+    nb = T // C
+    q = q.reshape(nb, C, KVH, H // KVH, d)
+    k, v = (a.reshape(nb, C, KVH, d) for a in (k, v))
+    c = jnp.cumsum(log_g.reshape(nb, C, KVH), axis=1)   # from block start
+    later = (jnp.arange(C)[:, None] >= jnp.arange(C)[None, :])[..., None]
+    decay = jnp.exp(jnp.where(later, c[:, :, None] - c[:, None], -jnp.inf))
+    w = (jnp.square(jnp.einsum("btkgd,bskd->bkgts", q, k, precision=_HI))
+         * decay.transpose(0, 3, 1, 2)[:, :, None])    # (nb, KVH, G, t, s)
+    num = jnp.einsum("bkgts,bskd->btkgd", w, v, precision=_HI)
+    den = w.sum(-1).transpose(0, 3, 1, 2)               # (nb, t, KVH, G)
+    pk = phi(k)                                         # (nb, C, KVH, F)
+    k_out = pk * jnp.exp(c[:, -1:] - c)[..., None]      # to the block's end
+    own = (jnp.einsum("bskf,bskd->bkfd", k_out, v, precision=_HI),
+           k_out.sum(1))
+
+    def hand(carry, x):
+        (S, z), (last, own_S, own_z) = carry, x
+        return (last[:, None, None] * S + own_S, last[:, None] * z + own_z), (
+            S, z)
+    _, (S_in, z_in) = jax.lax.scan(hand, (S0, z0),
+                                   (jnp.exp(c[:, -1]),) + own)
+    pq = phi(q) * jnp.exp(c)[..., None, None]           # (nb, C, KVH, G, F)
+    num = num + jnp.einsum("btkgf,bkfd->btkgd", pq, S_in, precision=_HI)
+    den = den + jnp.einsum("btkgf,bkf->btkg", pq, z_in, precision=_HI)
+    o = _quotient(num, den).reshape(T, H, d)
+    # the state after a marked token: the block's state decayed to it and
+    # the block's tokens up to it
+    b, j = marks // C, marks % C
+    cm = c[b, j]                                        # (K, KVH)
+    upto = (jnp.arange(C)[None, :] <= j[:, None])[..., None]
+    km = pk[b] * jnp.where(upto, jnp.exp(jnp.minimum(
+        cm[:, None] - c[b], 0.0)), 0.0)[..., None]      # (K, C, KVH, F)
+    S_m = (jnp.exp(cm)[..., None, None] * S_in[b]
+           + jnp.einsum("nskf,nskd->nkfd", km, v[b], precision=_HI))
+    return o, S_m, jnp.exp(cm)[..., None] * z_in[b] + km.sum(1)
+
+
+def _retention_chunk(u, lp, cfg, h_in, conv_in, valid, marks, pos0=0):
+    """The retention mixer over one sequence's chunk ``u`` (T, units) at
+    positions ``pos0 ..`` from the state entry ``(h_in, conv_in)``: S (KVH
+    F, d) and z as (1, KVH F).  Tokens beyond ``valid`` come after every
+    valid one and the marks, so they reach neither.  Returns the mixer's
+    output and the entries after the chunk indices ``marks``: (K, KVH F,
+    d) and (K, 1, KVH F)."""
+    T = u.shape[0]
+    H, KVH, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    q, k, v, log_g = _retention_inputs(
+        u, lp, cfg, pos0 + jnp.arange(T, dtype=jnp.int32))
+    pad = -T % min(_RET_BLOCK, T)   # whole blocks: the tail changes no state
+    if pad:
+        q, k, v, log_g = (jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
+                          for a in (q, k, v, log_g))
+    o, S_m, z_m = retention_chunk(q, k, v, log_g,
+                                  h_in.reshape(KVH, -1, d),
+                                  conv_in.reshape(KVH, -1), marks)
+    return (_mm(o[:T].reshape(T, H * d), lp["wo"]),
+            S_m.reshape(marks.shape + h_in.shape),
+            z_m.reshape(marks.shape + conv_in.shape))
+
+
+def _retention_pool_step(u, lp, cfg, kp, vp, li, pages, fresh, positions):
+    """The decode step's retention, a sequence's entry on its one page: the
+    page a lane reads is the page it writes, so every page of layer ``li``
+    is updated where it lies, in one pass over the pool, the lanes' inputs
+    scattered to their pages (a page that no lane writes takes ``g = 1``,
+    ``phi(k) = 0``: unchanged).  Each lane's output is read off the state
+    before the write, ``phi(q)^T S = g phi(q)^T S_old + (phi(q) . phi(k))
+    v`` and the same over ``z``; a layer's S is an array of its own
+    (:class:`HybridPool`), so the product reads it as it lies and the
+    update writes it in place.  (A layer sliced out of one array of every
+    layer, or read back after the write, was copied whole first: 0.62 of 4
+    traced seconds on the chip, PERF.md, PR 37.)  ``pages`` (B,): the page
+    each lane writes, the scratch page for an idle lane."""
+    B, KVH, d = u.shape[0], cfg.num_kv_heads, cfg.head_dim
+    P = kp.ssm[li].shape[0]
+    q, k, v, log_g = _retention_inputs(u, lp, cfg, positions)
+    owner = jnp.full((P,), B, jnp.int32).at[pages].set(
+        jnp.arange(B, dtype=jnp.int32))
+
+    def by_page(a, fill):                       # (B, ..) -> (P, ..)
+        pad = jnp.full((1,) + a.shape[1:], fill, a.dtype)
+        return jnp.concatenate([a, pad])[owner]
+    # a lane that starts at p = 0 begins from the zero state
+    g = jnp.where(fresh[:, None], 0.0, jnp.exp(log_g))     # (B, KVH)
+    pk = phi(k)                                             # (B, KVH, F)
+    pq = phi(q).reshape(B, KVH, -1, pk.shape[-1])           # (B, KVH, G, F)
+    own = jnp.einsum("bkgf,bkf->bkg", pq, pk, precision=_HI)
+    pg, ppk, pv = by_page(g, 1.0), by_page(pk, 0.0), by_page(v, 0.0)
+    ppq = by_page(pq, 0.0)
+    num, den, pools = [], [], []
+    for part, pool in enumerate((kp, vp)):
+        of = slice(part * KVH // 2, (part + 1) * KVH // 2)
+        parts = pool.conv if isinstance(pool.conv, tuple) else (pool.conv,)
+        z = jnp.concatenate([c[li] for c in parts], -1).reshape(
+            P, KVH // 2, -1)
+        S = pool.ssm[li]                        # (P, KVH / 2, F, d)
+        num.append(jnp.einsum("pkgf,pkfd->pkgd", ppq[:, of], S,
+                              precision=_HI)[pages])
+        den.append(jnp.einsum("pkgf,pkf->pkg", ppq[:, of], z,
+                              precision=_HI)[pages])
+        pools.append(_put_layer(
+            pool, li, pg[:, of, None, None] * S
+            + ppk[:, of, :, None] * pv[:, of, None, :],
+            pg[:, of, None] * z + ppk[:, of]))
+    g = g[..., None]
+    o = _quotient(g[..., None] * jnp.concatenate(num, 1)
+                  + own[..., None] * v[:, :, None, :],
+                  g * jnp.concatenate(den, 1) + own)       # (B, KVH, G, d)
+    return _mm(o.reshape(B, -1), lp["wo"]), pools[0], pools[1]
+
+
+def _put_layer(pool, li, S, z):
+    """Layer ``li`` of a pool, every page's S and z, written whole in place
+    (:func:`_retention_pool_step`)."""
+    parts = pool.conv if isinstance(pool.conv, tuple) else (pool.conv,)
+    pieces = jnp.split(z.reshape(z.shape[0], -1), len(parts), -1)
+    conv = tuple(c.at[li].set(x) for c, x in zip(parts, pieces))
+    return pool._replace(
+        ssm=_set(pool.ssm, li, S),
+        conv=conv if isinstance(pool.conv, tuple) else conv[0])
+
+
+def _flat(a):
+    """A layer of retention's S, (P, KVH / 2, F, d), as a one-layer pool of
+    entries (1, P, KVH / 2 F, d): a view (F rows are whole 8-row tiles)."""
+    return a.reshape((1, a.shape[0], -1, a.shape[-1]))
+
+
+def _set(layers, li, value):
+    """The tuple ``layers`` with layer ``li`` replaced by ``value``."""
+    return layers[:li] + (value,) + layers[li + 1:]
+
+
+def _chunk_fn(cfg, pos0):
+    """The recurrent mixer over one sequence's chunk that starts at
+    ``pos0``: ``(u, lp, cfg, h_in, conv_in, valid, marks) -> (out,
+    entries, conv entries)``."""
+    if RETENTION in cfg.layer_kinds:
+        return functools.partial(_retention_chunk, pos0=pos0)
+    return _delta_chunk if DELTA in cfg.layer_kinds else _ssm_chunk
+
+
 def _scan_block(T):
     """Tokens a block of the blocked scan holds: the largest power of two
     up to 32 that divides ``T``."""
@@ -538,20 +792,24 @@ def _ssm_chunk(u, lp, cfg, h_in, conv_in, valid, marks):
 def _pieces(a, page):
     """Into how many row blocks a page's slice of the state array ``a``
     (layers, P, rows, d) is cut when many pages are read or written at
-    once: blocks of at most 512 KB.  The TPU's gather cuts a larger slice
+    once: the fewest blocks of at most 512 KB whose rows are a multiple of
+    the 8 rows of a float32 tile.  The TPU's gather cuts a larger slice
     in two by first copying out both halves of its whole operand, the
     pool (1.2 GB a pool and decode step for the delta rule's 2 MB
     slices); the state-space block's 164 KB slices are not cut.  (rows / n,
-    d) blocks of a (rows, d) slice lie as they lay, so the view is free;
-    the convolution inputs' flat rows are cut where the pool is made,
-    :func:`_conv_parts`, because a view of those is not.)"""
+    d) blocks of a (rows, d) slice lie as they lay, so the view is free
+    where rows / n fills whole tiles (retention's 33 024 rows in 64 blocks
+    of 516 did not, and the view became a relayout of the pool: 43 blocks
+    of 768 do); the convolution inputs' flat rows are cut where the pool
+    is made, :func:`_conv_parts`, because a view of those is not.)"""
     if jnp.ndim(page) == 0:         # one page: a dynamic slice
         return 1
     rows, nbytes = a.shape[2], a.shape[2] * a.shape[3] * a.dtype.itemsize
-    pieces = 1
-    while nbytes // pieces > 2 ** 19 and rows % (2 * pieces) == 0:
-        pieces *= 2
-    return pieces
+    for pieces in range(1, rows // 8 + 1):
+        if nbytes // pieces <= 2 ** 19 and rows % pieces == 0 and (
+                pieces == 1 or rows // pieces % 8 == 0):
+            return pieces
+    return 1
 
 
 def _conv_parts(width):
@@ -566,7 +824,10 @@ def _conv_parts(width):
 
 
 def _take_pages(a, li, page):
-    """``a[li, page]`` (:func:`_pieces`)."""
+    """``a[li, page]`` (:func:`_pieces`); of a tuple of layers, layer
+    ``li`` (static) alike."""
+    if isinstance(a, tuple):
+        a, li = _flat(a[li]), 0
     n = _pieces(a, page)
     if n == 1:
         return a[li, page]
@@ -577,7 +838,11 @@ def _take_pages(a, li, page):
 
 
 def _put_pages(a, li, pages, value):
-    """``a.at[li, pages].set(value)`` (:func:`_pieces`)."""
+    """``a.at[li, pages].set(value)`` (:func:`_pieces`); of a tuple of
+    layers, layer ``li`` (static) alike."""
+    if isinstance(a, tuple):
+        return _set(a, li, _put_pages(_flat(a[li]), 0, pages, value)
+                    .reshape(a[li].shape))
     n = _pieces(a, pages)
     if n == 1:
         return a.at[li, pages].set(value)
@@ -635,9 +900,10 @@ def _write_entry(kp, vp, li, pages, h, conv, cfg):
 def _over_layers(params, cfg, x, kp, vp, attention, recurrent, live=None):
     """The layers in order: a ``lax.scan`` over each run's stacked
     parameters, the residual stream and both pools carried.  ``attention``
-    and ``recurrent`` (the state-space or the delta-rule mixer) are ``(u,
-    lp, kp, vp, li) -> (out, kp, vp)`` with ``li`` the layer's index among
-    its own kind (its row in the pool).  Returns the stream, the pools and
+    and ``recurrent`` (the state-space, delta-rule or retention mixer) are
+    ``(u, lp, kp, vp, li) -> (out, kp, vp)`` with ``li`` the layer's index
+    among its own kind (its row in the pool; a Python int for a run of one
+    layer and for retention, whose S is a tuple of layers).  Returns the stream, the pools and
     what the routed layers counted (None where the model has none); a
     run's routed experts stay out of the scan and go to the grouped
     product whole (:func:`.routed.routed_feed_forward`)."""
@@ -646,6 +912,9 @@ def _over_layers(params, cfg, x, kp, vp, attention, recurrent, live=None):
               if cfg.n_experts else None)
     for (kind, lo, hi), run in zip(layer_runs(cfg), params["runs"]):
         mixer = attention if kind == ATTENTION else recurrent
+        # a static layer index: the page-wise writes; retention's S, a
+        # tuple of layers
+        static = hi - lo == 1 or kind == RETENTION
         kind = ATTENTION if kind == ATTENTION else "recurrent"
         experts = {k: run[k] for k in _routed.EXPERT_LEAVES if k in run}
         run = {k: v for k, v in run.items() if k not in experts}
@@ -660,10 +929,12 @@ def _over_layers(params, cfg, x, kp, vp, attention, recurrent, live=None):
                 counts = counts + counted
             return (x, kp, vp, counts), None
 
-        if hi - lo == 1:        # a static layer index: the page-wise writes
-            (x, kp, vp, counts), _ = layer(
-                (x, kp, vp, counts),
-                (jax.tree.map(lambda a: a[0], run), seen[kind], 0))
+        if static:
+            for j in range(hi - lo):
+                (x, kp, vp, counts), _ = layer(
+                    (x, kp, vp, counts),
+                    (jax.tree.map(lambda a, j=j: a[j], run), seen[kind] + j,
+                     j))
         else:
             steps = jnp.arange(hi - lo, dtype=jnp.int32)
             (x, kp, vp, counts), _ = jax.lax.scan(
@@ -731,7 +1002,7 @@ def full_forward(params, cfg, tokens):
                               u, lp, cfg), kp, vp
 
     state, _, conv = _entry_dims(cfg)
-    chunk = _delta_chunk if DELTA in cfg.layer_kinds else _ssm_chunk
+    chunk = _chunk_fn(cfg, 0)
 
     def recurrent(u, lp, kp, vp, li):
         h0 = jnp.zeros(state, jnp.float32)
@@ -749,12 +1020,23 @@ def full_forward(params, cfg, tokens):
 # ---------------------------------------------------------------------------
 # the step programs (decoder.make_decode_step / make_prefill_chunk)
 # ---------------------------------------------------------------------------
+def _one_page(cfg, pages):
+    """A retention model's state is one entry a sequence: its page tables
+    hold one page (``page_size`` >= ``max_ctx``), anything else is refused
+    by name (:func:`~.decoder.hybrid_program`)."""
+    if RETENTION in cfg.layer_kinds and pages != 1:
+        _dec._refuse_hybrid(cfg, "a page table of %d pages a sequence (a "
+                            "retention entry is held once a sequence: "
+                            "page_size >= max_ctx)" % pages)
+
+
 def build_decode_step(cfg, page_size):
     """``step(params, k_pool, v_pool, tokens, positions, page_tables,
     active)`` of :func:`~.decoder.make_decode_step` for a hybrid model."""
     S = int(page_size)
 
     def step(params, kp, vp, tokens, positions, page_tables, active):
+        _one_page(cfg, page_tables.shape[1])
         B = tokens.shape[0]
         page_of = jnp.take_along_axis(
             page_tables, (positions // S)[:, None], axis=1)[:, 0]
@@ -799,10 +1081,15 @@ def build_decode_step(cfg, page_size):
             kp, vp = _write_entry(kp, vp, li, wp, h, conv, cfg)
             return out, kp, vp
 
+        def retention(u, lp, kp, vp, li):
+            return _retention_pool_step(u, lp, cfg, kp, vp, li, wp, fresh,
+                                        positions)
+
+        recurrent = (retention if RETENTION in cfg.layer_kinds
+                     else delta if DELTA in cfg.layer_kinds else state_space)
         x = params["embed"][tokens].astype(jnp.float32)
         x, kp, vp, counts = _over_layers(
-            params, cfg, x, kp, vp, attention,
-            delta if DELTA in cfg.layer_kinds else state_space, live=active)
+            params, cfg, x, kp, vp, attention, recurrent, live=active)
         if counts is not None:      # the decode steps' counts: the V pool's
             vp = vp._replace(counts=vp.counts + counts)
         logits = _logits(x, params, cfg)
@@ -818,6 +1105,7 @@ def build_prefill_chunk(cfg, page_size, chunk):
     n_pages = -(-T // S) + 1            # a chunk may start inside a page
 
     def prefill(params, kp, vp, tokens, pos0, n_valid, page_row):
+        _one_page(cfg, page_row.shape[0])
         idx = pos0 + jnp.arange(T, dtype=jnp.int32)
         valid = jnp.arange(T) < n_valid
         wp = jnp.where(valid, page_row[idx // S], 0)
@@ -847,7 +1135,7 @@ def build_prefill_chunk(cfg, page_size, chunk):
             att = _attend(q[None], kc, vc, seen[None], cfg)
             return _attention_out(att[0], u, lp, cfg), kp, vp
 
-        chunk_of = _delta_chunk if DELTA in cfg.layer_kinds else _ssm_chunk
+        chunk_of = _chunk_fn(cfg, pos0)
 
         def recurrent(u, lp, kp, vp, li):
             h_in, conv_in = _read_entry(kp, vp, li, before, pos0 == 0,
@@ -893,6 +1181,12 @@ def _run_shapes(cfg, kind):
                  "w_f1": (r, C), "w_f2": (H * d, r), "a_log": (H,),
                  "dt_bias": (H * d,), "w_b": (H, C), "w_g1": (r, C),
                  "w_g2": (H * d, r), "norm_o": (d,), "wo": (C, H * d)}
+    elif kind == RETENTION:
+        KVH, d = cfg.num_kv_heads, cfg.head_dim
+        mixer = {"wq": (cfg.num_heads * d, C), "wk": (kvu, C),
+                 "wv": (kvu, C), "wo": (C, cfg.num_heads * d),
+                 "w_decay": (KVH, C), "b_decay": (KVH,),
+                 "q_norm": (d,), "k_norm": (d,)}
     else:
         mixer = {"w_in": (2 * di, C), "conv_w": (di, cfg.d_conv),
                  "conv_b": (di,), "w_x": (R + 2 * N, di), "norm_dt": (R,),
@@ -933,7 +1227,10 @@ class HybridLM(HybridBlock):
     The defaults are the state-space block with a tied output embedding.
     ``attention_layers`` names the attention layers where a period and an
     offset do not; ``recurrent="delta_rule"`` takes ``delta_heads``,
-    ``delta_head_dim`` and ``delta_rank``; ``n_experts`` > 0 makes every
+    ``delta_head_dim`` and ``delta_rank``; ``recurrent="retention"`` makes
+    every layer the retention mixer on the attention's heads (no attention
+    layer), q and k normed per head and rotated by ``rope_theta``;
+    ``n_experts`` > 0 makes every
     layer's feed-forward part the routed layer of :mod:`.routed`, of which
     this model holds the ``experts_held`` experts of share ``expert_share``
     (``[experts_held * expert_share, experts_held * (expert_share + 1))``).
@@ -950,12 +1247,18 @@ class HybridLM(HybridBlock):
                  delta_head_dim=0, delta_rank=0, n_experts=0,
                  experts_held=None, expert_share=0, experts_per_token=0,
                  expert_hidden=0, shared_hidden=0, norm_topk=True,
-                 routed_scale=1.0, kv_dtype=None):
+                 routed_scale=1.0, kv_dtype=None, rope_theta=0.0):
         super().__init__()
         assert num_heads % num_kv_heads == 0
         assert head_dim is not None or units % num_heads == 0
         assert (expand * units) % 2 == 0
-        assert recurrent in (STATE_SPACE, DELTA)
+        assert recurrent in (STATE_SPACE, DELTA, RETENTION)
+        if recurrent == RETENTION:      # the pools halve the KV heads
+            assert not attention_layers and num_kv_heads % 2 == 0
+            assert rope_theta > 0
+            attention_layers = []
+        else:                           # only the retention mixer rotates
+            assert not rope_theta
         if attention_layers is None:
             attention_layers = [i for i in range(int(num_layers))
                                 if i % attn_layer_period == attn_layer_offset]
@@ -983,7 +1286,7 @@ class HybridLM(HybridBlock):
             experts_per_token=int(experts_per_token),
             expert_hidden=int(expert_hidden),
             shared_hidden=int(shared_hidden), norm_topk=bool(norm_topk),
-            routed_scale=float(routed_scale))
+            routed_scale=float(routed_scale), rope_theta=float(rope_theta))
         self.eos_id = eos_id
         self.dtype = jnp.dtype(dtype)
         self.embed = Parameter("embed", shape=(vocab_size, units),
@@ -1045,6 +1348,10 @@ def _initial(name, p, cfg, normal):
         dt = jnp.exp(jnp.linspace(jnp.log(1e-3), jnp.log(1e-1),
                                   cfg.delta_head_dim, dtype=f32))
         value = jnp.tile(dt + jnp.log(-jnp.expm1(-dt)), cfg.delta_heads)
+    elif name == "b_decay":     # retention: gates near 0.97 .. 0.9985
+        value = jnp.linspace(3.5, 6.5, cfg.num_kv_heads, dtype=f32)
+    elif name == "w_decay":     # normal(0, 0.01): W u spreads by some 0.7
+        return normal(p.shape) * jnp.asarray(0.5, p.dtype)
     elif len(p.shape) >= 3:                     # a run of matrices
         return normal(p.shape)
     else:
@@ -1061,7 +1368,10 @@ def hybrid_lm(seed=0, **kw):
     convolution's and ``delta``'s biases 0, as the family's published code
     sets them before training (the delta rule's ``a_log`` the logarithm of
     1 .. 16 over the heads, its ``dt_bias`` the inverse softplus of 0.001
-    .. 0.1 over a head's channels, the router's selection bias 0).  The
+    .. 0.1 over a head's channels, the router's selection bias 0; the
+    retention gate's ``W_decay`` normal(0, 0.01) and its bias 3.5 .. 6.5
+    over the KV heads, so that a token's gate ``sigmoid(W u + b)`` lies
+    mostly between 0.9 and 0.999 and the state carries across a chunk).  The
     importable builder of a replica spec or a benchmark configuration
     (``mxnet_tpu.models.decoder:hybrid_lm``)."""
     net = HybridLM(**kw)

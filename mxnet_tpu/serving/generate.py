@@ -261,9 +261,9 @@ class DecodeEngine:
     ``kv_dtype`` is float32, bfloat16 or int8; left out it is the
     model's own (``config.kv_dtype``: a model published in bfloat16
     caches in bfloat16), else float32.  A model with recurrent layers,
-    state-space or delta-rule (:mod:`mxnet_tpu.models.hybrid`), keeps its
-    recurrent state paged beside the KV rows, one entry a page and
-    recurrent layer; for such a model speculation,
+    state-space, delta-rule or retention (:mod:`mxnet_tpu.models.hybrid`),
+    keeps its recurrent state paged beside the KV rows, one entry a page
+    and recurrent layer; for such a model speculation,
     a tp sharding, int8 KV, weight quantisation, session migration and
     the prefill / decode roles are refused at construction
     (``ValueError``), session export / import when called,
@@ -311,16 +311,6 @@ class DecodeEngine:
         if self.kv_dtype not in ("float32", "bfloat16", "int8"):
             raise ValueError("kv_dtype must be float32, bfloat16 or int8, "
                              "got %r" % (self.kv_dtype,))
-        # a model with recurrent layers (state-space, delta-rule) keeps a
-        # recurrent state beside its KV rows, one entry a page
-        # (models/hybrid.py); what the engine cannot keep exact for it is
-        # refused here, by name
-        self.hybrid = _decoder.hybrid_program(self.cfg, sharding,
-                                              self.quant, self.kv_dtype)
-        if self.hybrid:
-            self._refuse_for_hybrid(speculate=speculate, migrate=migrate,
-                                    pagestore=pagestore, role=role)
-        self.params = model.jax_params()
         self.slots = int(slots if slots is not None
                          else _config.get("MXNET_GEN_SLOTS"))
         self.page_size = int(page_size if page_size is not None
@@ -329,6 +319,17 @@ class DecodeEngine:
                            or self.cfg.max_length)
         self.max_ctx = min(self.max_ctx, self.cfg.max_length)
         self.pages_per_seq = pages_for(self.max_ctx, self.page_size)
+        # a model with recurrent layers (state-space, delta-rule,
+        # retention) keeps a recurrent state beside its KV rows, one entry
+        # a page (models/hybrid.py); what the engine cannot keep exact for
+        # it is refused here, by name
+        self.hybrid = _decoder.hybrid_program(
+            self.cfg, sharding, self.quant, self.kv_dtype,
+            page_size=self.page_size, max_ctx=self.max_ctx)
+        if self.hybrid:
+            self._refuse_for_hybrid(speculate=speculate, migrate=migrate,
+                                    pagestore=pagestore, role=role)
+        self.params = model.jax_params()
         total = int(total_pages if total_pages is not None
                     else _config.get("MXNET_GEN_PAGES"))
         if not total:
@@ -350,6 +351,8 @@ class DecodeEngine:
         #: bytes of one page's state entries (0 without recurrent layers)
         self.state_entry_bytes = (_hybrid.state_entry_bytes(cfg)
                                   if self.hybrid else 0)
+        #: bytes of state entries the staged launches read and write
+        self._state_bytes = {"decode": 0, "prefill": 0}
         self.alloc = PageAllocator(
             total, self.page_size, kv_dtype=self.kv_dtype,
             page_bytes=elems * self.page_size
@@ -508,10 +511,11 @@ class DecodeEngine:
     # -- a model with state-space layers: what is refused ------------------
     def _refuse_for_hybrid(self, speculate, migrate, pagestore, role):
         """Raise a ``ValueError`` that names the first thing asked of this
-        engine which it cannot keep exact for a model with state-space
-        layers or delta-rule layers (ROADMAP, "What the system cannot run
-        yet"); what the programs cannot do (a tp sharding, quantisation,
-        int8 KV) ``decoder.hybrid_program`` has refused already."""
+        engine which it cannot keep exact for a model with recurrent layers
+        (state-space, delta-rule or retention; ROADMAP, "What the system
+        cannot run yet"); what the programs cannot do (a tp sharding,
+        quantisation, int8 KV) ``decoder.hybrid_program`` has refused
+        already."""
         def want(arg, knob):
             return bool(arg if arg is not None else _config.get(knob))
         refused = [
@@ -1536,10 +1540,14 @@ class DecodeEngine:
                                touched)
             if slot.pos == 0:
                 self.metrics.count(self.name, "state_starts_total")
+        # the entries it writes, and the one before pos0 that it reads
+        state_bytes = self._count_state("prefill",
+                                        touched + (slot.pos > 0))
         with span("engine.prefill_launch", rid=rid, slot=slot.idx,
                   tokens=n, pos=slot.pos,
                   state_pages=touched if self.hybrid else 0,
-                  expert_pairs=n * self._pairs_per_token):
+                  expert_pairs=n * self._pairs_per_token,
+                  state_bytes=state_bytes):
             chunk = slot.prompt[slot.done:slot.done + n]
             padded = onp.zeros(self.prefill_chunk, onp.int32)
             padded[:n] = chunk
@@ -1624,6 +1632,7 @@ class DecodeEngine:
             positions[s.idx] = s.pos
             active[s.idx] = True
         self._count_decode_pages(positions, active)
+        self._count_state("decode", 2 * len(live))
         t0 = time.perf_counter()
         if self._t_force_end is not None:
             # host gap: wall time this step spent on scheduling between
@@ -1653,6 +1662,17 @@ class DecodeEngine:
         self.metrics.observe_decode_step(
             self.name, now - t0, now - t0, len(live), self.slots,
             len(live))
+
+    def _count_state(self, phase, entries):
+        """Bytes of the state ``entries`` (a page's, over the recurrent
+        layers) that a staged ``phase`` launch reads and writes, known to
+        the host; counted as ``state_bytes_<phase>_total`` where the model
+        has recurrent layers, and returned."""
+        n = int(entries) * self.state_entry_bytes
+        if n:
+            self._state_bytes[phase] += n
+            self.metrics.count(self.name, "state_bytes_%s_total" % phase, n)
+        return n
 
     def _count_decode_pages(self, positions, active):
         """What the staged launch's attention has to read: the pages
@@ -1731,8 +1751,11 @@ class DecodeEngine:
         live = [s for s in live if s.state == "decode"]
         if not live:
             return False
+        # a lane reads the entry before its position and writes its own
+        state_bytes = self._count_state("decode", 2 * len(live))
         with span("engine.decode_launch", lanes=len(live), depth=depth0,
-                  expert_pairs=len(live) * self._pairs_per_token):
+                  expert_pairs=len(live) * self._pairs_per_token,
+                  state_bytes=state_bytes):
             st = self._stage_tokens
             sp = self._stage_positions
             sa = self._stage_active
@@ -2662,5 +2685,7 @@ class DecodeEngine:
                 "entry_bytes": self.state_entry_bytes,
                 "pool_bytes": (self.state_entry_bytes
                                * self.alloc.total_pages),
-                "pages_with_state_peak": out["kv"]["peak_used_pages"]}
+                "pages_with_state_peak": out["kv"]["peak_used_pages"],
+                "bytes_decode_total": self._state_bytes["decode"],
+                "bytes_prefill_total": self._state_bytes["prefill"]}
         return out

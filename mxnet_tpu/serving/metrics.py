@@ -105,6 +105,9 @@ class ModelMetrics:
                 # their state entries) attached on a prefix hit
                 "state_entries_written_total", "state_starts_total",
                 "state_prefix_pages_shared_total",
+                # bytes of state entries the staged decode and prefill
+                # launches read and write (PR 37), known to the host
+                "state_bytes_decode_total", "state_bytes_prefill_total",
                 # a model that routes (PR 35), from the programs' own
                 # counts, folded in when the engine's stats are asked:
                 # token-expert pairs on experts held here and on other

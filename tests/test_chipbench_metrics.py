@@ -40,22 +40,24 @@ CONFIG_OF = {w["name"]: w["config"] for w in BENCH["workloads"]}
 
 def engine_kind(config):
     """Which of the tiny engines below stands for a configuration's: the
-    classic block's, one with state-space layers, or one that routes over
-    delta-rule layers.  A metric reads the facts of every kind of engine
-    among the cells that report it."""
+    classic block's, one with state-space layers, one that routes over
+    delta-rule layers, or one of retention layers.  A metric reads the
+    facts of every kind of engine among the cells that report it."""
     builder = harness.load("configs", config + ".json").get("builder", "")
     return ("routed" if "routed_delta_lm" in builder
+            else "retention" if "retention_lm" in builder
             else "hybrid" if "hybrid_lm" in builder else "classic")
 
 
 KIND_OF = {c["name"]: engine_kind(c["name"]) for c in BENCH["configs"]}
 
 
-def served_facts(lm):
+def served_facts(lm, page_size=8):
     """What chipbench/serve.py hands the readers under ``stats``, from a
-    tiny engine: three requests of two prefill chunks each."""
-    engine = serving.DecodeEngine(lm, slots=4, page_size=8, max_ctx=64,
-                                  prefill_chunk=8)
+    tiny engine: three requests of two prefill chunks each (a retention
+    model holds a sequence in one page)."""
+    engine = serving.DecodeEngine(lm, slots=4, page_size=page_size,
+                                  max_ctx=64, prefill_chunk=8)
     server = serving.ModelServer()
     try:
         server.attach_engine("lm", engine)
@@ -82,7 +84,11 @@ def facts():
                 linear_attn={"num_heads": 4, "head_dim": 8,
                              "short_conv_kernel_size": 4},
                 experts_held=4, expert_shares=2, experts_per_token=2,
-                expert_hidden=16, max_length=128))}
+                expert_hidden=16, max_length=128)),
+            "retention": served_facts(decoder.retention_lm(
+                seed=0, vocab_size=128, num_layers=2, units=32,
+                hidden_size=48, num_heads=4, num_kv_heads=2, head_dim=8,
+                rope_theta=1e4, max_length=128), page_size=64)}
 
 
 def test_every_per_layer_metric_has_its_file():

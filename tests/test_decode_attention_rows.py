@@ -497,3 +497,94 @@ def test_decode_step_compiled_for_the_chip_walks_the_pages(one_chip,
                             "2049,16,768", "1,2049,16,768"), (name, shape)
         if shape == pool:
             assert any(k in name for k in in_place), (name, shape)
+
+
+# ---------------------------------------------------------------------------
+# the retention block's two programs compiled for the chip (PR 37)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_retention_programs_compiled_for_the_chip_move_no_pool(one_chip,
+                                                               program):
+    """Two layers at ``brumby-14b-l4-serve``'s state geometry (8 KV heads of
+    128: S 4 x 8 256 rows of 128 a page, layer and pool, z 33 024 floats,
+    21 pages of 1 024, 20 slots, chunks of 256) through the v5e's
+    compiler.  Nothing with a layer's S of every page as its shape, ``(..,
+    21, 4, 8256, 128)`` or ``(.., 21, 33024, 128)`` whatever leads it, nor
+    the prefill chunk's view of it in 768-row blocks, is made but in place:
+    the prefill chunk's writes are scatters into the view (43 blocks of 768
+    rows, a bitcast: 64 blocks of 516 made two relayouts of the whole pool
+    a layer), and the decode step updates each layer's S where it lies
+    and keeps no temporary as large as one (read back after the write, or
+    sliced out of a pool of every layer, XLA copied each updated layer
+    first: 0.62 of 4 traced seconds on the chip, PERF.md, PR 37).  The z
+    pool's parts are 5.5 MB: the compiler may stage them in the chip's own
+    memory around the layers (copy-start / copy-done), and does nothing
+    else with them."""
+    from mxnet_tpu.models import hybrid
+    layers, S, B, T, total = 2, 1024, 20, 256, 21
+    net = hybrid.HybridLM(vocab_size=512, num_layers=layers, units=256,
+                          hidden_size=512, num_heads=40, num_kv_heads=8,
+                          head_dim=128, recurrent="retention",
+                          rope_theta=1e6, tied_head=False,
+                          max_length=S)
+    cfg = net.config
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+    params = {"embed": sds(net.embed.shape, net.dtype),
+              "norm_f": sds(net.norm_f.shape, net.dtype),
+              "head": sds(net.head.shape, net.dtype),
+              "runs": [{k: sds(getattr(r, k).shape, net.dtype)
+                        for k in r.leaves} for r in net.runs]}
+    pool = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        lambda: decoder.fresh_pool(cfg, total, S)))
+    assert [a.shape for a in pool.ssm] == [(total, 4, 8256, 128)] * layers
+    assert [a.shape for a in pool.conv] == [(layers, total, 16512)] * 2
+
+    def i32(*shape):
+        return sds(shape, jnp.int32)
+    if program == "decode":
+        fn, rest = decoder.make_decode_step(cfg, S), (
+            i32(B), i32(B), i32(B, 1), sds((B,), jnp.bool_))
+    else:
+        fn, rest = decoder.make_prefill_chunk(cfg, S, T), (
+            i32(T), i32(), i32(), i32(1))
+    compiled = fn.lower(params, pool, pool, *rest).compile()
+    hlo = compiled.as_text()
+    # what the program makes: the computations a fusion calls left out
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", hlo))
+    made = [m for comp in re.split(r"\n(?=\S)", hlo)
+            if comp.split(" ", 2)[int(comp.startswith("ENTRY"))].lstrip("%")
+            not in fused
+            for m in re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = "
+                                r"f32\[([\d,]+)\]\S* ([\w\-]+)\(", comp,
+                                re.M)]
+    slab = re.compile(r"^(\d+,)*(21,4,8256|21,33024|903,768),128$")
+    z = "%d,21,16512" % layers
+    writes = {"scatter", "dynamic-update-slice"}
+    in_place = {"parameter", "get-tuple-element", "bitcast",
+                "fusion"} | writes
+    seen = [(name, op) for name, dims, op in made if slab.match(dims)]
+    assert {op for _, op in seen} <= in_place, seen
+    if program == "decode":
+        # the update of each layer's two halves, in the pools' own buffers
+        assert sum(op == "fusion" for _, op in seen) == 2 * layers, seen
+        assert compiled.memory_analysis().temp_size_in_bytes < (
+            total * 33024 * 128 * 4)
+    else:
+        # every fusion with a slab's shape is an in-place write
+        assert {op for _, op in seen} & writes or any(
+            op == "fusion" for _, op in seen)
+        for name, op in seen:
+            if op != "fusion":
+                continue
+            called = re.search(r"%" + re.escape(name) + r" = [^\n]*calls=%?"
+                               r"([\w.\-]+)", hlo).group(1)
+            body = hlo[hlo.index("%" + called + " ("):]
+            body = body[:body.index("\n}")]
+            root = re.search(r"ROOT %?\S+ = \S+ ([\w\-]+)\(",
+                             body).group(1)
+            assert root in writes or (root == "tuple" and any(
+                "%s(" % w in body for w in writes)), (name, root)
+    moves = {op for _, dims, op in made if dims == z} - in_place
+    assert moves <= {"copy-done", "custom-call"}, moves

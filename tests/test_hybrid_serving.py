@@ -320,9 +320,16 @@ def test_counters_and_stats_read_what_a_scripted_run_implies(lm, monkeypatch):
                 for a in launches] == [(0, 8, 2), (8, 3, 1)]
         state = engine.stats()["state"]
         entry = 4 * 128 * (16 + 3) * 4
+        # the entries the launches read and write: a chunk its pages and
+        # the entry before pos0, a decode step two a lane (PR 37)
+        assert [a["state_bytes"] for a in launches] == [2 * entry, 2 * entry]
         assert state == {"layers": 4, "entry_bytes": entry,
                          "pool_bytes": entry * 31,
-                         "pages_with_state_peak": 4}
+                         "pages_with_state_peak": 4,
+                         "bytes_prefill_total": 4 * entry,
+                         "bytes_decode_total": 2 * 2 * entry}
+        assert (c["state_bytes_prefill_total"],
+                c["state_bytes_decode_total"]) == (4 * entry, 4 * entry)
         kv = engine.stats()["kv"]
         assert kv["pool_bytes"] == 30 * (entry + 2 * 2 * 16 * 4 * 4)
     finally:

@@ -527,7 +527,11 @@ def test_counters_and_stats_read_what_a_scripted_run_implies(lm, monkeypatch):
         entry = 3 * (4 * 8 * 8 + 9 * 32) * 4
         assert stats["state"] == {"layers": 3, "entry_bytes": entry,
                                   "pool_bytes": entry * 31,
-                                  "pages_with_state_peak": 4}
+                                  "pages_with_state_peak": 4,
+                                  "bytes_prefill_total": 4 * entry,
+                                  "bytes_decode_total": 2 * 2 * entry}
+        assert [a["state_bytes"] for a in launches] == [2 * entry, 2 * entry]
+        assert all(a["state_bytes"] == 2 * entry * a["lanes"] for a in steps)
         assert stats["kv"]["pool_bytes"] == 30 * (entry + 2 * 1 * 32 * 4 * 4)
         ex = stats["experts"]
         assert (ex["held"], ex["of"], ex["per_token"]) == (4, 16, 3)
