@@ -632,33 +632,71 @@ def _retention_chunk(u, lp, cfg, h_in, conv_in, valid, marks, pos0=0):
             z_m.reshape(marks.shape + conv_in.shape))
 
 
-def _retention_pool_step(u, lp, cfg, kp, vp, li, pages, fresh, positions):
-    """The decode step's retention, a sequence's entry on its one page: the
-    page a lane reads is the page it writes, so every page of layer ``li``
-    is updated where it lies, in one pass over the pool, the lanes' inputs
-    scattered to their pages (a page that no lane writes takes ``g = 1``,
-    ``phi(k) = 0``: unchanged).  Each lane's output is read off the state
-    before the write, ``phi(q)^T S = g phi(q)^T S_old + (phi(q) . phi(k))
-    v`` and the same over ``z``; a layer's S is an array of its own
-    (:class:`HybridPool`), so the product reads it as it lies and the
-    update writes it in place.  (A layer sliced out of one array of every
-    layer, or read back after the write, was copied whole first: 0.62 of 4
-    traced seconds on the chip, PERF.md, PR 37.)  ``pages`` (B,): the page
-    each lane writes, the scratch page for an idle lane."""
-    B, KVH, d = u.shape[0], cfg.num_kv_heads, cfg.head_dim
-    P = kp.ssm[li].shape[0]
+def _retention_pool_step(u, lp, cfg, kp, vp, li, pages, fresh, positions,
+                         active):
+    """The decode step's retention, a sequence's entry on its one page:
+    each lane's output is read off the state before the write, ``phi(q)^T
+    S = g phi(q)^T S_old + (phi(q) . phi(k)) v`` and the same over ``z``,
+    and the lane's entry is updated where it lies.  ``pages`` (B,): the
+    page each lane writes, the scratch page for an idle lane; ``active``
+    (B,): the lanes that are somebody's.
+
+    On a TPU, over a float32 state whose rows fill whole tiles
+    (:func:`~..ops.pallas.retention_state.kernel_mode`), a Pallas kernel
+    reads and writes each active lane's entry once and touches no other
+    page.  Elsewhere the XLA form: every page of layer ``li`` is updated
+    in one pass over the pool, the lanes' inputs scattered to their pages
+    (a page that no lane writes takes ``g = 1``, ``phi(k) = 0``:
+    unchanged); a layer's S is an array of its own (:class:`HybridPool`),
+    so the product reads it as it lies and the update writes it in place.
+    (A layer sliced out of one array of every layer, or read back after
+    the write, was copied whole first: 0.62 of 4 traced seconds on the
+    chip, PERF.md section 6.)"""
+    from ..ops.pallas import retention_state as _rs
+    B, KVH = u.shape[0], cfg.num_kv_heads
     q, k, v, log_g = _retention_inputs(u, lp, cfg, positions)
+    # a lane that starts at p = 0 begins from the zero state
+    g = jnp.where(fresh[:, None], 0.0, jnp.exp(log_g))     # (B, KVH)
+    pk = phi(k)                                             # (B, KVH, F)
+    pq = phi(q).reshape(B, KVH, -1, pk.shape[-1])           # (B, KVH, G, F)
+    own = jnp.einsum("bkgf,bkf->bkg", pq, pk, precision=_HI)
+    S = (kp.ssm[li], vp.ssm[li])
+    z = tuple(p.conv if isinstance(p.conv, tuple) else (p.conv,)
+              for p in (kp, vp))
+    mode = _rs.kernel_mode(S[0], z[0])
+    if mode is not None:
+        num, den, S, z = _rs.decode_state_step(
+            mode, li, pages, active, fresh, g, pq, pk, v, S, z)
+        pools = [_put_state(p, li, s, c) for p, s, c in zip((kp, vp), S, z)]
+    else:
+        _rs.last_path = "xla"
+        num, den, pools = _retention_pool_xla(kp, vp, li, pages, g, pq, pk,
+                                              v, KVH)
+    g = g[..., None]
+    o = _quotient(g[..., None] * num + own[..., None] * v[:, :, None, :],
+                  g * den + own)                            # (B, KVH, G, d)
+    return _mm(o.reshape(B, -1), lp["wo"]), pools[0], pools[1]
+
+
+def retention_decode_path():
+    """Which form the retention decode step built last took: "pallas",
+    "pallas-interpret" (the kernel) or "xla" (None before one was
+    built)."""
+    from ..ops.pallas import retention_state as _rs
+    return _rs.last_path
+
+
+def _retention_pool_xla(kp, vp, li, pages, g, pq, pk, v, KVH):
+    """:func:`_retention_pool_step`'s XLA form: ``num`` (B, KVH, G, d),
+    ``den`` (B, KVH, G) and the two pools with layer ``li`` updated."""
+    B = pages.shape[0]
+    P = kp.ssm[li].shape[0]
     owner = jnp.full((P,), B, jnp.int32).at[pages].set(
         jnp.arange(B, dtype=jnp.int32))
 
     def by_page(a, fill):                       # (B, ..) -> (P, ..)
         pad = jnp.full((1,) + a.shape[1:], fill, a.dtype)
         return jnp.concatenate([a, pad])[owner]
-    # a lane that starts at p = 0 begins from the zero state
-    g = jnp.where(fresh[:, None], 0.0, jnp.exp(log_g))     # (B, KVH)
-    pk = phi(k)                                             # (B, KVH, F)
-    pq = phi(q).reshape(B, KVH, -1, pk.shape[-1])           # (B, KVH, G, F)
-    own = jnp.einsum("bkgf,bkf->bkg", pq, pk, precision=_HI)
     pg, ppk, pv = by_page(g, 1.0), by_page(pk, 0.0), by_page(v, 0.0)
     ppq = by_page(pq, 0.0)
     num, den, pools = [], [], []
@@ -676,19 +714,21 @@ def _retention_pool_step(u, lp, cfg, kp, vp, li, pages, fresh, positions):
             pool, li, pg[:, of, None, None] * S
             + ppk[:, of, :, None] * pv[:, of, None, :],
             pg[:, of, None] * z + ppk[:, of]))
-    g = g[..., None]
-    o = _quotient(g[..., None] * jnp.concatenate(num, 1)
-                  + own[..., None] * v[:, :, None, :],
-                  g * jnp.concatenate(den, 1) + own)       # (B, KVH, G, d)
-    return _mm(o.reshape(B, -1), lp["wo"]), pools[0], pools[1]
+    return jnp.concatenate(num, 1), jnp.concatenate(den, 1), pools
 
 
 def _put_layer(pool, li, S, z):
     """Layer ``li`` of a pool, every page's S and z, written whole in place
-    (:func:`_retention_pool_step`)."""
+    (:func:`_retention_pool_xla`)."""
     parts = pool.conv if isinstance(pool.conv, tuple) else (pool.conv,)
     pieces = jnp.split(z.reshape(z.shape[0], -1), len(parts), -1)
-    conv = tuple(c.at[li].set(x) for c, x in zip(parts, pieces))
+    return _put_state(pool, li, S, tuple(
+        c.at[li].set(x) for c, x in zip(parts, pieces)))
+
+
+def _put_state(pool, li, S, conv):
+    """A pool with layer ``li``'s S and every layer's z (``conv``, a tuple
+    of the pool's parts) replaced."""
     return pool._replace(
         ssm=_set(pool.ssm, li, S),
         conv=conv if isinstance(pool.conv, tuple) else conv[0])
@@ -1083,7 +1123,7 @@ def build_decode_step(cfg, page_size):
 
         def retention(u, lp, kp, vp, li):
             return _retention_pool_step(u, lp, cfg, kp, vp, li, wp, fresh,
-                                        positions)
+                                        positions, active)
 
         recurrent = (retention if RETENTION in cfg.layer_kinds
                      else delta if DELTA in cfg.layer_kinds else state_space)

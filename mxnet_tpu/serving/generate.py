@@ -2688,4 +2688,7 @@ class DecodeEngine:
                 "pages_with_state_peak": out["kv"]["peak_used_pages"],
                 "bytes_decode_total": self._state_bytes["decode"],
                 "bytes_prefill_total": self._state_bytes["prefill"]}
+            if _hybrid.RETENTION in self.cfg.layer_kinds:
+                # which form the decode step's state update took
+                out["state"]["decode_path"] = _hybrid.retention_decode_path()
         return out

@@ -588,3 +588,61 @@ def test_retention_programs_compiled_for_the_chip_move_no_pool(one_chip,
                 "%s(" % w in body for w in writes)), (name, root)
     moves = {op for _, dims, op in made if dims == z} - in_place
     assert moves <= {"copy-done", "custom-call"}, moves
+
+
+def test_retention_decode_step_compiled_for_the_chip_runs_the_state_kernel(
+        one_chip, monkeypatch):
+    """The same decode step with the state kernel selected as on the chip
+    (`ops/pallas/retention_state.py`): the v5e's compiler takes
+    one kernel a layer, the layer's two S halves and the pools' z parts
+    passed whole and written in place; nothing else has a layer's S or a
+    z array as its result but parameters, tuple elements and bitcasts (and
+    the compiler's staging of z in the chip's own memory), and the
+    program's temporaries are under the XLA form's."""
+    from mxnet_tpu.models import hybrid
+    from mxnet_tpu.ops.pallas import retention_state as rs
+    layers, S, B, total = 2, 1024, 20, 21
+    net = hybrid.HybridLM(vocab_size=512, num_layers=layers, units=256,
+                          hidden_size=512, num_heads=40, num_kv_heads=8,
+                          head_dim=128, recurrent="retention",
+                          rope_theta=1e6, tied_head=False, max_length=S)
+    cfg = net.config
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+    params = {"embed": sds(net.embed.shape, net.dtype),
+              "norm_f": sds(net.norm_f.shape, net.dtype),
+              "head": sds(net.head.shape, net.dtype),
+              "runs": [{k: sds(getattr(r, k).shape, net.dtype)
+                        for k in r.leaves} for r in net.runs]}
+    pool = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        lambda: decoder.fresh_pool(cfg, total, S)))
+    rest = (sds((B,), jnp.int32), sds((B,), jnp.int32),
+            sds((B, 1), jnp.int32), sds((B,), jnp.bool_))
+
+    def compiled():
+        return hybrid.build_decode_step(cfg, S).lower(
+            params, pool, pool, *rest).compile()
+    xla = compiled()
+    assert rs.last_path == "xla"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kernel = compiled()
+    assert rs.last_path == "pallas"
+    hlo = kernel.as_text()
+    assert len(re.findall(r"%retention_state_step[.\d]* = \(.*\) custom-call\(",
+                          hlo)) == layers
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", hlo))
+    made = [m for comp in re.split(r"\n(?=\S)", hlo)
+            if comp.split(" ", 2)[int(comp.startswith("ENTRY"))].lstrip("%")
+            not in fused
+            for m in re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = "
+                                r"f32\[([\d,]+)\]\S* ([\w\-]+)\(", comp,
+                                re.M)]
+    slab = re.compile(r"^(\d+,)*(21,4,8256|21,33024|903,768),128$")
+    z = re.compile(r"^(%d,21|21,%d),16512$" % (layers, layers))
+    as_it_lies = {"parameter", "get-tuple-element", "bitcast"}
+    assert {op for _, dims, op in made if slab.match(dims)} <= as_it_lies
+    assert {op for _, dims, op in made if z.match(dims)} <= as_it_lies | {
+        "copy-done"}
+    assert kernel.memory_analysis().temp_size_in_bytes < (
+        xla.memory_analysis().temp_size_in_bytes)

@@ -323,7 +323,7 @@ def test_a_large_state_is_read_and_written_in_whole_tiles():
 H = hybrid
 REAL = {n: getattr(H, n) for n in ("_retention_inputs", "phi", "_quotient",
                                    "_read_entry", "_write_entry",
-                                   "_put_layer", "_rope",
+                                   "_put_layer", "_put_state", "_rope",
                                    "_retention_pool_step")}
 
 
@@ -355,13 +355,14 @@ def _prefill_from_zero(kp, vp, li, page, fresh, cfg, join=True):
     return REAL["_read_entry"](kp, vp, li, page, True, cfg, join)
 
 
-def _misread(u, lp, cfg, kp, vp, li, pages, fresh, positions):
+def _misread(u, lp, cfg, kp, vp, li, pages, fresh, positions, active):
     """Each lane's output off the page of the lane before it (lane 0: the
     last lane's, the scratch page); the writes are the sound ones."""
     step = REAL["_retention_pool_step"]
-    _, kp2, vp2 = step(u, lp, cfg, kp, vp, li, pages, fresh, positions)
+    _, kp2, vp2 = step(u, lp, cfg, kp, vp, li, pages, fresh, positions,
+                       active)
     out, _, _ = step(u, lp, cfg, kp, vp, li, jnp.roll(pages, 1), fresh,
-                     positions)
+                     positions, active)
     return out, kp2, vp2
 
 
@@ -379,7 +380,8 @@ def _bf16_layer(pool, li, S, z):
 
 
 #: what each fault replaces in models/hybrid.py (the cell's decode step
-#: updates the pool where it lies, ``_put_layer``; a prefill chunk writes
+#: hands a layer's new state to the pool, ``_put_state``, in the XLA form
+#: through ``_put_layer``; a prefill chunk writes
 #: its entries, ``_write_entry``)
 FAULTS = {
     "gate_left_out": {"_retention_inputs": _no_gate},
@@ -387,7 +389,7 @@ FAULTS = {
     "sqrt2_left_out": {"phi": _no_sqrt2},
     "normaliser_left_out": {"_quotient": lambda num, den: num},
     "prefill_hand_over_left_out": {"_read_entry": _prefill_from_zero},
-    "decode_writes_no_entry": {"_put_layer": lambda pool, li, S, z: pool},
+    "decode_writes_no_entry": {"_put_state": lambda pool, li, S, z: pool},
     "a_lane_reads_another_page": {"_retention_pool_step": _misread},
     "rope_left_out": {"_rope": lambda x, pos, theta: x},
     "qk_norm_left_out": {"_retention_inputs": _no_qk_norm},
@@ -429,6 +431,29 @@ def test_each_planted_fault_moves_the_rows_past_the_limit(lm, sound, fault,
         assert err.max() > 1e3 * np.abs(got - ref).max() / ref.std()
     else:
         assert err.max() > limit, (fault, err.max())
+
+
+@pytest.mark.parametrize("fault", ["decode_writes_no_entry",
+                                   "a_lane_reads_another_page"])
+def test_the_decode_steps_faults_reach_the_state_kernel(lm, sound, fault,
+                                                        monkeypatch):
+    """With the decode step's state kernel selected (in the Pallas
+    interpreter, `ops/pallas/retention_state.py`), the sound programs read
+    the reference's rows as the XLA form's do, and the decode step's two
+    faults move them past the limit."""
+    from mxnet_tpu.ops.pallas import retention_state as rs
+    limit = harness.load("configs", CONFIG + ".json")["check"]["limits"][
+        "program_logits_max_err"]
+    prompt, fed, got, ref = sound
+    monkeypatch.setattr(rs, "kernel_mode", lambda S, z: "interpret")
+    runs, _ = drive(lm, 64, 16, [prompt], 30, [0], fed=fed)
+    assert rs.last_path == "pallas-interpret"
+    assert np.abs(runs[0][1] - ref).max() / ref.std() < limit / 1000
+    for attr, fn in FAULTS[fault].items():
+        monkeypatch.setattr(H, attr, fn)
+    runs, _ = drive(lm, 64, 16, [prompt], 30, [0], fed=fed)
+    err = np.abs(runs[0][1] - ref).max(-1) / ref.std()
+    assert err.max() > limit, (fault, err.max())
 
 
 # ---------------------------------------------------------------------------
@@ -486,12 +511,33 @@ def test_state_bytes_are_counted_by_launch(lm, monkeypatch):
     assert state == {"layers": 2, "entry_bytes": entry,
                      "pool_bytes": 4 * entry, "pages_with_state_peak": 1,
                      "bytes_prefill_total": 3 * entry,
-                     "bytes_decode_total": 4 * entry}
+                     "bytes_decode_total": 4 * entry, "decode_path": "xla"}
     assert (c["state_bytes_prefill_total"],
             c["state_bytes_decode_total"]) == (3 * entry, 4 * entry)
     # a page is all state: no layer caches keys or values
     assert engine.alloc.stats()["pool_bytes"] == 3 * entry
     assert "state" not in make_engine(decoder.decoder_tiny_lm()).stats()
+
+
+def test_the_engine_names_the_decode_steps_state_path(lm, monkeypatch):
+    """With the state kernel selected the engine's answers are the
+    reference's, `stats()["state"]["decode_path"]` says the kernel ran, and
+    the decode launches still count two entries a live lane."""
+    from mxnet_tpu.ops.pallas import retention_state as rs
+    monkeypatch.setattr(rs, "kernel_mode", lambda S, z: "interpret")
+    decoder._fn_cache.clear()           # the programs traced anew
+    engine = make_engine(lm, total_pages=4, async_decode=True)
+    try:
+        prompt = ids(12, 11)
+        got = engine.submit(prompt, max_new_tokens=3).result(300)["tokens"]
+        c, state = counters(engine), engine.stats()["state"]
+    finally:
+        engine.stop()
+        decoder._fn_cache.clear()
+    assert got == ref_greedy(lm, prompt, 3)
+    assert state["decode_path"] == "pallas-interpret"
+    entry = hybrid.state_entry_bytes(lm.config)
+    assert c["state_bytes_decode_total"] == 4 * entry
 
 
 def tp2():
