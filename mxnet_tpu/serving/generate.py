@@ -124,15 +124,6 @@ _log = logging.getLogger(__name__)
 #: one identifier per request of this process, on every span of the request
 next_rid = itertools.count(1).__next__
 
-def _upload(host):
-    """``host`` (a numpy array the engine goes on to reuse) on the device,
-    from a private copy.  ``jnp.asarray`` may alias aligned numpy memory on
-    the CPU backend, and ``jnp.array`` then copies on the device,
-    asynchronously: a launch would read a staging buffer that the next
-    launch has refilled by then (seen as a fresh lane fed token 0, in the
-    processes whose buffer happened to be aligned)."""
-    return jnp.asarray(onp.array(host))
-
 
 # a request's phases on the engine's clock (_Request.mark)
 _QUEUE, _PREFILL, _DECODE = range(3)
@@ -438,12 +429,20 @@ class DecodeEngine:
         self._seq = 0                 # admission counter (owner ids)
         self._prefill_rr = 0
         self.steps = 0
-        # the current step's seconds by part and its prefill launches,
-        # plain floats of the worker thread, handed to the metrics in one
-        # call at the step's end (_step, _lap, _device_wait)
+        # the current step's seconds by part, its host seconds in launches
+        # and uploads, its blocking reads by what was read, and its
+        # counts: plain numbers of the worker thread, handed to the
+        # metrics in one call at the step's end (_step, _lap,
+        # _device_wait, _dispatch, _upload)
         self._parts = dict.fromkeys(ModelMetrics.STEP_PARTS, 0.0)
+        self._host_s = dict.fromkeys(ModelMetrics.HOST_PARTS, 0.0)
+        self._wait_s = dict.fromkeys(ModelMetrics.WAIT_PARTS, 0.0)
+        self._counts = dict.fromkeys(ModelMetrics.STEP_COUNTERS, 0)
         self._inner_s = 0.0
-        self._prefill_launches = 0
+        # the newest launch's first output that is no pool: the next
+        # launch finds it ready where the device has run dry (_dispatch);
+        # None after the worker waited for work
+        self._last_out = None
         self._reclaimed_seen = 0   # of alloc's "reclaimed", already counted
 
         # prefix caching + session migration + role specialization
@@ -484,7 +483,6 @@ class DecodeEngine:
             else _config.get("MXNET_GEN_DISPATCH_AHEAD")))
         self._pipe = collections.deque()  # in-flight _Flight entries
         self._flight_owners = {}          # owner -> in-flight refcount
-        self._t_force_end = None          # last forced-read end (host gap)
         self._t_last_retire = None        # retire cadence (decode_step)
         # pinned staging buffers, reused every step: batch formation
         # fills these in place instead of allocating fresh numpy arrays,
@@ -706,6 +704,9 @@ class DecodeEngine:
                        and not self._ops and not self._pipe
                        and not any(s.active for s in self._slots)):
                     self._cond.wait(0.1)
+                    # the device idled for want of work, not for the
+                    # host: the next launch counts as no drained one
+                    self._last_out = None
                     self._expire_sessions_locked()
                 if self._stopping:
                     busy = (any(s.active for s in self._slots)
@@ -723,7 +724,9 @@ class DecodeEngine:
         t0 = t = time.perf_counter()
         parts = self._parts
         parts["device_wait"] = parts["retire_host"] = self._inner_s = 0.0
-        self._prefill_launches = 0
+        for acc in (self._host_s, self._wait_s, self._counts):
+            for k in acc:
+                acc[k] = 0
         with span("engine.step", step=self.steps):
             with span("engine.ops"):
                 if self._pipe:
@@ -745,7 +748,8 @@ class DecodeEngine:
             t = self._lap("admit", t)
             with span("engine.prefill") as sp:
                 self._prefill_phase()
-                sp.set_metadata(launches=self._prefill_launches)
+                sp.set_metadata(
+                    launches=self._counts["prefill_launches_total"])
             t = self._lap("prefill_host", t)
             with span("engine.decode"):
                 self._decode()
@@ -766,7 +770,8 @@ class DecodeEngine:
             t = self._lap("account", t)
             self.steps += 1
             self.metrics.observe_engine_step(self.name, t - t0, parts,
-                                             self._prefill_launches)
+                                             self._host_s, self._wait_s,
+                                             self._counts)
 
     def _lap(self, part, t_from):
         """End one top-level part of the step: its wall since ``t_from``
@@ -781,13 +786,52 @@ class DecodeEngine:
         self._inner_s = inner
         return now
 
-    def _device_wait(self, read, value, name="engine.device_wait", **args):
+    def _device_wait(self, part, read, value, name="engine.device_wait",
+                     **args):
         """``read(value)``, a blocking read of a device result, under a
-        span; its wall is the step's ``device_wait``."""
+        span; its wall is the step's ``device_wait`` and its ``part`` of
+        ``ModelMetrics.WAIT_PARTS``."""
         t0 = time.perf_counter()
         with span(name, **args):
             out = read(value)
-        self._parts["device_wait"] += time.perf_counter() - t0
+        dt = time.perf_counter() - t0
+        self._parts["device_wait"] += dt
+        self._wait_s[part] += dt
+        return out
+
+    def _dispatch(self, fn, *args):
+        """``fn(*args)``, one program of the step launched: the host's
+        wall until the call returns is the step's ``host_s.dispatch``.
+        Where the previous launch's output is ready already, the device
+        ran dry before this launch (it runs its programs in order), and
+        the launch is counted as drained.  The step programs hand back
+        the K and V pools first, which the next launch donates, so what
+        is polled is their first output after them."""
+        counts = self._counts
+        if self._last_out is not None and self._last_out.is_ready():
+            counts["launches_drained_total"] += 1
+        t0 = time.perf_counter()
+        out = fn(*args)
+        self._host_s["dispatch"] += time.perf_counter() - t0
+        counts["launches_total"] += 1
+        self._last_out = out[2] if isinstance(out, tuple) else out
+        return out
+
+    def _upload(self, host):
+        """``host`` (a numpy array or scalar the engine may go on to
+        reuse) on the device, from a private copy: the step's
+        ``host_s.upload``, counted with its bytes.  ``jnp.asarray`` may
+        alias aligned numpy memory on the CPU backend, and ``jnp.array``
+        then copies on the device, asynchronously: a launch would read a
+        staging buffer that the next launch has refilled by then (seen as
+        a fresh lane fed token 0, in the processes whose buffer happened
+        to be aligned)."""
+        t0 = time.perf_counter()
+        host = onp.array(host)
+        out = jnp.asarray(host)
+        self._host_s["upload"] += time.perf_counter() - t0
+        self._counts["host_uploads_total"] += 1
+        self._counts["host_upload_bytes_total"] += host.nbytes
         return out
 
     def _drain_ops(self):
@@ -1376,7 +1420,7 @@ class DecodeEngine:
             # _upload, not asarray: the device copy must be a real
             # copy — an in-flight launch keeps reading it after the
             # host mutates self._tables for the next step
-            self._tables_dev = _upload(self._tables)
+            self._tables_dev = self._upload(self._tables)
         return self._tables_dev
 
     def _active_device(self, mask):
@@ -1384,7 +1428,7 @@ class DecodeEngine:
         membership actually changes (steady-state steps reuse it)."""
         key = mask.tobytes()
         if self._active_key != key:
-            self._active_dev = _upload(mask)
+            self._active_dev = self._upload(mask)
             self._active_key = key
         return self._active_dev
 
@@ -1553,11 +1597,12 @@ class DecodeEngine:
             padded[:n] = chunk
             # a copy: the launch reads it after _sync_table has rewritten
             # the host row for the next chunk's pages
-            row = _upload(self._tables[slot.idx])
-            self._kp, self._vp, next_tok, _ = self._prefill_fn(
-                self.params, self._kp, self._vp, jnp.asarray(padded),
-                jnp.int32(slot.pos), jnp.int32(n), row)
-        self._prefill_launches += 1
+            row = self._upload(self._tables[slot.idx])
+            self._kp, self._vp, next_tok, _ = self._dispatch(
+                self._prefill_fn, self.params, self._kp, self._vp,
+                self._upload(padded), self._upload(onp.int32(slot.pos)),
+                self._upload(onp.int32(n)), row)
+        self._counts["prefill_launches_total"] += 1
         slot.history.extend(chunk)
         slot.pos += n
         slot.done += n
@@ -1579,8 +1624,8 @@ class DecodeEngine:
                      if self.hybrid else len(slot.history))
             self.prefix_cache.insert(list(slot.history[:whole]),
                                      self.alloc.pages(slot.owner))
-        tok = self._device_wait(int, next_tok, "engine.first_token_read",
-                                rid=rid)
+        tok = self._device_wait("first_token", int, next_tok,
+                                "engine.first_token_read", rid=rid)
         now = time.perf_counter()
         slot.req.mark(_PREFILL, now)
         if not slot.req.ttft_recorded:
@@ -1634,22 +1679,15 @@ class DecodeEngine:
         self._count_decode_pages(positions, active)
         self._count_state("decode", 2 * len(live))
         t0 = time.perf_counter()
-        if self._t_force_end is not None:
-            # host gap: wall time this step spent on scheduling between
-            # the previous result landing and this launch going out (the
-            # quantity async mode hides behind the in-flight step)
-            self.metrics.observe_host_gap(
-                self.name, max(0.0, t0 - self._t_force_end))
         # staging buffers are reused next step: uploads must copy
         # (_upload), never alias (jnp.asarray aliases host memory on
         # CPU and the dispatch reads it after we mutate)
-        self._kp, self._vp, next_tokens, _ = self._decode_fn(
-            self.params, self._kp, self._vp, _upload(tokens),
-            _upload(positions), self._tables_device(),
-            self._active_device(active))
-        next_tokens = self._device_wait(onp.asarray, next_tokens)
+        self._kp, self._vp, next_tokens, _ = self._dispatch(
+            self._decode_fn, self.params, self._kp, self._vp,
+            self._upload(tokens), self._upload(positions),
+            self._tables_device(), self._active_device(active))
+        next_tokens = self._device_wait("sync", onp.asarray, next_tokens)
         now = time.perf_counter()
-        self._t_force_end = now
         for s in live:
             tok = int(next_tokens[s.idx])
             s.history.append(s.pending)
@@ -1659,9 +1697,8 @@ class DecodeEngine:
             self.metrics.observe_inter_token(self.name, now - s.t_last)
             s.t_last = now
             self._maybe_finish(s, now)
-        self.metrics.observe_decode_step(
-            self.name, now - t0, now - t0, len(live), self.slots,
-            len(live))
+        self.metrics.observe_decode_step(self.name, now - t0, self.slots,
+                                         len(live))
 
     def _count_state(self, phase, entries):
         """Bytes of the state ``entries`` (a page's, over the recurrent
@@ -1785,20 +1822,17 @@ class DecodeEngine:
                 # outputs nobody retires).
                 tokens = self._pipe[-1].out
             elif chain:
-                tokens = _decoder.make_token_combine(self.slots)(
-                    self._pipe[-1].out, _upload(st), _upload(carry))
+                tokens = self._dispatch(
+                    _decoder.make_token_combine(self.slots),
+                    self._pipe[-1].out, self._upload(st),
+                    self._upload(carry))
             else:
-                tokens = _upload(st)
+                tokens = self._upload(st)
             t0 = time.perf_counter()
-            if self._t_force_end is not None:
-                # with lanes in flight the host gap is hidden (0 by
-                # construction); an empty pipe exposes it like sync mode
-                self.metrics.observe_host_gap(
-                    self.name,
-                    0.0 if depth0 else max(0.0, t0 - self._t_force_end))
-            self._kp, self._vp, out, _ = self._decode_fn(
-                self.params, self._kp, self._vp, tokens, _upload(sp),
-                self._tables_device(), self._active_device(sa))
+            self._kp, self._vp, out, _ = self._dispatch(
+                self._decode_fn, self.params, self._kp, self._vp, tokens,
+                self._upload(sp), self._tables_device(),
+                self._active_device(sa))
         fl = _Flight("plain", out, t0, [(s, s.admit_seq) for s in live],
                      set(s.owner for s in live))
         for s in live:
@@ -1806,15 +1840,15 @@ class DecodeEngine:
         with self._cond:
             self._pipe.append(fl)
             self._pin_owners(fl)
-        self.metrics.observe_dispatch_depth(self.name, len(self._pipe))
         return True
 
-    def _retire_one(self):
+    def _retire_one(self, part="retire"):
         """Force the oldest in-flight step's tokens to the host and run
         its bookkeeping (history/pos advance, emission, inter-token +
         decode-step metrics, EOS/length/deadline finishes).  Lanes whose
         slot was recycled since launch (admit-seq mismatch) are
-        discarded — their tokens were never promised to anyone."""
+        discarded — their tokens were never promised to anyone.  The
+        read is ``part`` of the step's blocking reads."""
         with self._cond:
             if not self._pipe:
                 return
@@ -1822,20 +1856,19 @@ class DecodeEngine:
         parts = self._parts
         t0, waited = time.perf_counter(), parts["device_wait"]
         with span("engine.retire", lanes=len(fl.lanes)):
-            self._retire_flight(fl)
+            self._retire_flight(fl, part)
         # the bookkeeping alone: the blocking read is device_wait's
         parts["retire_host"] += (time.perf_counter() - t0
                                  - (parts["device_wait"] - waited))
 
-    def _retire_flight(self, fl):
+    def _retire_flight(self, fl, part):
         try:
             faults.check("engine.retire")
         except Exception as e:
             self._retire_poisoned(fl, e)
             return
-        toks = self._device_wait(jax.device_get, fl.out)
+        toks = self._device_wait(part, jax.device_get, fl.out)
         now = time.perf_counter()
-        self._t_force_end = now
         self.metrics.count(self.name, "deferred_reads_total")
         with self._cond:
             self._unpin_owners(fl)
@@ -1861,9 +1894,8 @@ class DecodeEngine:
             # true per-step time); first retire after an idle pipe falls
             # back to its own launch→retire wall
             base = max(fl.t_launch, self._t_last_retire or 0.0)
-            self.metrics.observe_decode_step(
-                self.name, now - base, now - base, live,
-                self.slots, live)
+            self.metrics.observe_decode_step(self.name, now - base,
+                                             self.slots, live)
         self._t_last_retire = now
 
     def _retire_poisoned(self, fl, exc):
@@ -1895,7 +1927,7 @@ class DecodeEngine:
             self.metrics.count(self.name, "pipe_flushes_%s_total" % cause)
         while self._pipe:
             if not discard:
-                self._retire_oldest()
+                self._retire_oldest("flush")
                 continue
             with self._cond:
                 if not self._pipe:
@@ -1908,13 +1940,13 @@ class DecodeEngine:
             for cb in fl.on_retire:
                 cb()
 
-    def _retire_oldest(self):
+    def _retire_oldest(self, part):
         if self._spec is not None:
-            rec = self._retire_spec()
+            rec = self._retire_spec(part)
             if rec is not None:
                 self._run_spec_deferred(rec)
             return
-        self._retire_one()
+        self._retire_one(part)
 
     def _grow_pages_inflight(self, s):
         """Page growth for an async launch: the slot's cache must cover
@@ -1957,13 +1989,14 @@ class DecodeEngine:
         if rec is not None:
             self._run_spec_deferred(rec)
 
-    def _retire_spec(self):
+    def _retire_spec(self, part="retire"):
         """Retire the in-flight spec step: force the wide output, run
         longest-prefix acceptance, advance slot state, roll back
         rejected cache positions, feed adaptive-k, validate the
         pre-draft, and DECIDE finishes (slots park in ``finishing``
         state so the next launch skips them).  Returns the deferred
-        record for :meth:`_run_spec_deferred`, or None."""
+        record for :meth:`_run_spec_deferred`, or None.  The read is
+        ``part`` of the step's blocking reads."""
         with self._cond:
             if not self._pipe:
                 return None
@@ -1973,9 +2006,8 @@ class DecodeEngine:
         except Exception as e:
             self._retire_poisoned(fl, e)
             return None
-        out = self._device_wait(jax.device_get, fl.out)
+        out = self._device_wait(part, jax.device_get, fl.out)
         now = time.perf_counter()
-        self._t_force_end = now
         self.metrics.count(self.name, "deferred_reads_total")
         with self._cond:
             self._unpin_owners(fl)
@@ -2111,10 +2143,6 @@ class DecodeEngine:
         if not live:
             return False
         fed = {}
-        t0 = time.perf_counter()
-        if self._t_force_end is not None:
-            self.metrics.observe_host_gap(
-                self.name, max(0.0, t0 - self._t_force_end))
         if plan:
             width = 1 + max(len(d) for d in plan.values())
             verify_fn = _decoder.make_verify_step(
@@ -2132,10 +2160,11 @@ class DecodeEngine:
                 n_valid[s.idx] = len(row)
                 active[s.idx] = True
             t0 = time.perf_counter()
-            self._kp, self._vp, out = verify_fn(
-                self.params, self._kp, self._vp, _upload(tokens),
-                _upload(positions), _upload(n_valid),
-                self._tables_device(), _upload(active))
+            self._kp, self._vp, out = self._dispatch(
+                verify_fn, self.params, self._kp, self._vp,
+                self._upload(tokens), self._upload(positions),
+                self._upload(n_valid), self._tables_device(),
+                self._upload(active))
             kind = "verify"
         else:
             st = self._stage_tokens
@@ -2151,9 +2180,9 @@ class DecodeEngine:
                 sa[s.idx] = True
             self._count_decode_pages(sp, sa)
             t0 = time.perf_counter()
-            self._kp, self._vp, out, _ = self._decode_fn(
-                self.params, self._kp, self._vp, _upload(st),
-                _upload(sp), self._tables_device(),
+            self._kp, self._vp, out, _ = self._dispatch(
+                self._decode_fn, self.params, self._kp, self._vp,
+                self._upload(st), self._upload(sp), self._tables_device(),
                 self._active_device(sa))
             kind = "plain"
         fl = _Flight(kind, out, t0, [(s, s.admit_seq) for s in live],
@@ -2163,7 +2192,6 @@ class DecodeEngine:
         with self._cond:
             self._pipe.append(fl)
             self._pin_owners(fl)
-        self.metrics.observe_dispatch_depth(self.name, len(self._pipe))
         # overlapped drafting: propose the NEXT step's continuation from
         # the current confirmed context while this launch runs on
         # device.  The proposal covers this step's maximum emission plus
@@ -2202,9 +2230,8 @@ class DecodeEngine:
         if rec["kind"] == "verify":
             self.metrics.observe_verify(self.name, now - rec["t_launch"])
             self.metrics.count(self.name, "spec_verify_steps_total")
-        self.metrics.observe_decode_step(
-            self.name, now - rec["t_launch"], now - rec["t_launch"],
-            len(rec["lanes"]), self.slots, rec["emitted_total"])
+        self.metrics.observe_decode_step(self.name, now - rec["t_launch"],
+                                         self.slots, rec["emitted_total"])
 
     # -- speculative decoding ---------------------------------------------
     def _build_spec(self, drafter, draft_model, spec_k):
@@ -2319,11 +2346,12 @@ class DecodeEngine:
             n_valid[s.idx] = len(row)
             active[s.idx] = True
         t0 = time.perf_counter()
-        self._kp, self._vp, out = verify_fn(
-            self.params, self._kp, self._vp, jnp.asarray(tokens),
-            jnp.asarray(positions), jnp.asarray(n_valid),
-            self._tables_device(), jnp.asarray(active))
-        out = self._device_wait(onp.asarray, out)
+        self._kp, self._vp, out = self._dispatch(
+            verify_fn, self.params, self._kp, self._vp,
+            self._upload(tokens), self._upload(positions),
+            self._upload(n_valid), self._tables_device(),
+            self._upload(active))
+        out = self._device_wait("sync", onp.asarray, out)
         now = time.perf_counter()
         self.metrics.observe_verify(self.name, now - t0)
         self.metrics.count(self.name, "spec_verify_steps_total")
@@ -2365,9 +2393,8 @@ class DecodeEngine:
                                    "spec_accepted_tokens_total", accepted)
             self._rollback_kv(s, pos0 + nv)
             self._maybe_finish(s, now)
-        self.metrics.observe_decode_step(
-            self.name, now - t0, now - t0, len(live), self.slots,
-            emitted_total)
+        self.metrics.observe_decode_step(self.name, now - t0, self.slots,
+                                         emitted_total)
         return True
 
     def _rollback_kv(self, slot, written_end):

@@ -126,7 +126,18 @@ class ModelMetrics:
                 # launch is staged: the pages under ``position + 1`` of
                 # its active lanes, and the page table whole (slots x
                 # pages a sequence may hold)
-                "decode_pages_live_total", "decode_pages_table_total")
+                "decode_pages_live_total", "decode_pages_table_total",
+                # the step's programs (DecodeEngine._dispatch): launches,
+                # and those that found the previous launch's output ready,
+                # so the device had run dry; transfers of their inputs
+                # (DecodeEngine._upload) and the bytes moved
+                "launches_total", "launches_drained_total",
+                "host_uploads_total", "host_upload_bytes_total")
+
+    #: counters an engine step hands over at its end, not one by one
+    STEP_COUNTERS = ("prefill_launches_total", "launches_total",
+                     "launches_drained_total", "host_uploads_total",
+                     "host_upload_bytes_total")
 
     #: parts of an engine step, host wall seconds summed over the window
     #: (DecodeEngine._step): ``ops`` worker ops + expiry, ``admit``,
@@ -137,6 +148,15 @@ class ModelMetrics:
     #: gauges at the step's end.  They add up to ``step``.
     STEP_PARTS = ("ops", "admit", "prefill_host", "launch", "device_wait",
                   "retire_host", "account")
+    #: host seconds inside the parts other than ``device_wait``: the jitted
+    #: calls of the step's launches until they return, and the transfers
+    #: of their inputs
+    HOST_PARTS = ("dispatch", "upload")
+    #: ``device_wait`` by what was read: a prompt's first token, the oldest
+    #: launch of the decode pipeline, a launch retired by a flush of the
+    #: whole pipeline, the synchronous engine's step result.  They add up
+    #: to ``device_wait``.
+    WAIT_PARTS = ("first_token", "retire", "flush", "sync")
 
     def __init__(self):
         self.counters = dict.fromkeys(self.COUNTERS, 0)
@@ -150,7 +170,9 @@ class ModelMetrics:
         # generation-path histograms (empty unless a DecodeEngine serves
         # this model): TTFT = submit -> first generated token; inter-token
         # = gap between consecutive tokens of one sequence; decode_step =
-        # device time of one whole-batch decode step
+        # the host's clock between whole-batch decode steps retired (a
+        # launch to its read where nothing else is in flight), not
+        # device time
         self.ttft = LatencyHistogram()
         self.inter_token = LatencyHistogram()
         self.decode_step = LatencyHistogram()
@@ -164,18 +186,14 @@ class ModelMetrics:
         # seconds in each of its parts
         self.engine_step = LatencyHistogram()
         self.phase_s = dict.fromkeys(("step",) + self.STEP_PARTS, 0.0)
+        self.host_s = dict.fromkeys(self.HOST_PARTS, 0.0)
+        self.wait_s = dict.fromkeys(self.WAIT_PARTS, 0.0)
         # speculative decoding: tokens EMITTED per decode step (a wide
         # verify can land several — this is where >1 token/step shows),
         # plus the draft/verify latency split
         self.tokens_per_step = LatencyHistogram()
         self.draft_step = LatencyHistogram()
         self.verify_step = LatencyHistogram()
-        # async decode engine: host gap = wall time the device sat with
-        # no decode work queued between steps (the async win is this
-        # collapsing toward zero); dispatch_depth = launched-but-
-        # unretired steps at each launch (achieved pipelining depth)
-        self.host_gap = LatencyHistogram()
-        self.dispatch_depth = LatencyHistogram()
         self.kv_cache = {"used_pages": 0, "total_pages": 0,
                          "peak_used_pages": 0, "shared_pages": 0,
                          "leaked_pages": 0, "tokens_resident": 0,
@@ -220,6 +238,8 @@ class ModelMetrics:
                     {k: round(v, 6) for k, v in self.phase_s.items()},
                     host_self=round(self.phase_s["step"]
                                     - self.phase_s["device_wait"], 6)),
+                "host_s": {k: round(v, 6) for k, v in self.host_s.items()},
+                "wait_s": {k: round(v, 6) for k, v in self.wait_s.items()},
                 "tokens_per_s": round(self.tokens_per_s, 2),
                 # fraction of dispatched decode-slot work that produced a
                 # real token — the continuous-batching win over static
@@ -238,10 +258,6 @@ class ModelMetrics:
             }
             out["generate"]["tokens_per_step"] = (
                 self.tokens_per_step.snapshot(scale=1, suffix=""))
-            out["generate"]["host_gap_us"] = self.host_gap.snapshot(
-                scale=1e6, suffix="_us")
-            out["generate"]["dispatch_depth"] = (
-                self.dispatch_depth.snapshot(scale=1, suffix=""))
             drafted = self.counters["spec_draft_tokens_total"]
             if drafted or self.counters["spec_verify_steps_total"]:
                 out["generate"]["speculative"] = {
@@ -328,19 +344,22 @@ class ServingMetrics:
         with self._lock:
             self._model(name).http_self.observe(self_s)
 
-    def observe_engine_step(self, name, wall_s, parts, prefill_launches):
+    def observe_engine_step(self, name, wall_s, parts, host_s, wait_s,
+                            counts):
         """One engine step: its host wall, the seconds of each of
-        ``ModelMetrics.STEP_PARTS`` in it, and the prefill-chunk programs
-        it launched."""
+        ``ModelMetrics.STEP_PARTS``, ``HOST_PARTS`` and ``WAIT_PARTS`` in
+        it, and its ``STEP_COUNTERS``."""
         with self._lock:
             m = self._model(name)
             m.counters["engine_steps_total"] += 1
-            m.counters["prefill_launches_total"] += prefill_launches
+            for counter in m.STEP_COUNTERS:
+                m.counters[counter] += counts[counter]
             m.engine_step.observe(wall_s)
-            phase_s = m.phase_s
-            phase_s["step"] += wall_s
-            for part in m.STEP_PARTS:
-                phase_s[part] += parts[part]
+            m.phase_s["step"] += wall_s
+            for acc, new in ((m.phase_s, parts), (m.host_s, host_s),
+                             (m.wait_s, wait_s)):
+                for part in new:
+                    acc[part] += new[part]
 
     def observe_ttft(self, name, ttft_s):
         with self._lock:
@@ -350,34 +369,20 @@ class ServingMetrics:
         with self._lock:
             self._model(name).inter_token.observe(gap_s)
 
-    def observe_decode_step(self, name, device_s, wall_s, active, slots,
-                            new_tokens):
-        """One whole-batch decode step: ``active`` of ``slots`` decode
-        slots produced ``new_tokens`` tokens in ``device_s`` seconds."""
+    def observe_decode_step(self, name, step_s, slots, new_tokens):
+        """One whole-batch decode step of ``slots`` decode slots retired,
+        ``step_s`` host seconds after the one before it (or after its
+        launch), with ``new_tokens`` tokens."""
         with self._lock:
             m = self._model(name)
             m.counters["decode_steps_total"] += 1
             m.counters["decode_slot_steps_total"] += slots
             m.counters["tokens_generated_total"] += new_tokens
-            m.decode_step.observe(device_s)
+            m.decode_step.observe(step_s)
             m.tokens_per_step.observe(float(new_tokens))
-            rate = new_tokens / max(wall_s, 1e-9)
+            rate = new_tokens / max(step_s, 1e-9)
             m.tokens_per_s = (rate if m.tokens_per_s == 0.0
                               else 0.9 * m.tokens_per_s + 0.1 * rate)
-
-    def observe_host_gap(self, name, gap_s):
-        """Device-idle gap before one decode launch: wall time since the
-        engine last blocked on (and received) a step result with nothing
-        left in flight.  Zero when the launch went out while a previous
-        step was still unretired — the pipelined steady state."""
-        with self._lock:
-            self._model(name).host_gap.observe(gap_s)
-
-    def observe_dispatch_depth(self, name, depth):
-        """Launched-but-unretired decode steps right after one launch
-        (the achieved dispatch-ahead depth, histogrammed)."""
-        with self._lock:
-            self._model(name).dispatch_depth.observe(float(depth))
 
     def observe_draft(self, name, draft_s):
         """Wall time of one slot's draft proposal (speculative path)."""

@@ -444,17 +444,21 @@ class ModelServer:
             gen = stats.get("generate")
             if gen:
                 for hist in ("ttft", "inter_token", "decode_step",
-                             "tokens_per_step", "host_gap_us",
-                             "dispatch_depth", "request_prefill",
+                             "tokens_per_step", "request_prefill",
                              "request_decode", "engine_step"):
                     for k, v in sorted((gen.get(hist) or {}).items()):
                         if k == "count":
                             continue
                         lines.append("mxtpu_serving_%s_%s{%s} %g"
                                      % (hist, k, labels, v))
-                for k, v in sorted((gen.get("phase_s") or {}).items()):
-                    lines.append("mxtpu_serving_engine_phase_seconds"
-                                 "{%s,phase=\"%s\"} %g" % (labels, k, v))
+                for key, name, label in (
+                        ("phase_s", "phase", "phase"),
+                        ("host_s", "host", "part"),
+                        ("wait_s", "wait", "read")):
+                    for k, v in sorted((gen.get(key) or {}).items()):
+                        lines.append("mxtpu_serving_engine_%s_seconds"
+                                     "{%s,%s=\"%s\"} %g"
+                                     % (name, labels, label, k, v))
                 # (kv_tokens_resident / kv_bytes_per_token ride the
                 # kv_cache loop below — one sample per name)
                 for gauge in ("tokens_per_s", "decode_occupancy",

@@ -196,6 +196,17 @@ def check_phases(results, snap):
     assert phase_s["device_wait"] > 0.0
     assert gen["engine_step"]["count"] == snap["counters"][
         "engine_steps_total"]
+    # the blocking reads by what was read add up to device_wait; the
+    # launches' calls and uploads lie inside the rest (each rounded to
+    # 1e-6 s in the snapshot)
+    wait_s, host_s = gen["wait_s"], gen["host_s"]
+    assert set(wait_s) == set(serving.ModelMetrics.WAIT_PARTS)
+    assert abs(sum(wait_s.values()) - phase_s["device_wait"]) < 1e-5, (
+        wait_s, phase_s)
+    assert wait_s["first_token"] > 0.0
+    assert min(wait_s.values()) >= 0.0 and min(host_s.values()) > 0.0
+    assert host_s["dispatch"] + host_s["upload"] <= (
+        phase_s["host_self"] + 2e-6), (host_s, phase_s)
 
 
 def test_request_phases_add_up_to_its_total(served):
@@ -247,7 +258,15 @@ def test_stats_endpoints_carry_the_new_numbers(served):
                    "mxtpu_serving_request_decode_p50_ms{",
                    "mxtpu_serving_engine_step_p50_ms{",
                    'mxtpu_serving_engine_phase_seconds{model="lm",'
-                   'phase="host_self"}'):
+                   'phase="host_self"}',
+                   "mxtpu_serving_launches_total{",
+                   "mxtpu_serving_launches_drained_total{",
+                   "mxtpu_serving_host_uploads_total{",
+                   "mxtpu_serving_host_upload_bytes_total{",
+                   'mxtpu_serving_engine_host_seconds{model="lm",'
+                   'part="dispatch"}',
+                   'mxtpu_serving_engine_wait_seconds{model="lm",'
+                   'read="first_token"}'):
         assert needle in prom, needle
 
 

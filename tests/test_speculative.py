@@ -494,7 +494,7 @@ def test_tokens_per_step_feeds_throughput_ema(lm):
     m = ServingMetrics()
     # one step, four tokens: the EMA must credit all four, and the
     # tokens-per-step histogram must see the multi-token step
-    m.observe_decode_step("x", 0.01, 0.01, 1, 4, 4)
+    m.observe_decode_step("x", 0.01, 4, 4)
     snap = m.snapshot()["models"]["x"]["generate"]
     assert snap["tokens_per_s"] == pytest.approx(400.0, rel=0.01)
     assert snap["tokens_per_step"]["max"] == 4
