@@ -19,6 +19,9 @@ LLM server needs that an encoder never does:
   (``ops/pallas/paged_attention``), greedy next token.  KV page arrays
   are donated, so the cache is updated in place on accelerators.
 
+Both step programs take a launch's host inputs as ONE int32 buffer
+(:func:`decode_buffer`, :func:`prefill_buffer`): one transfer a launch.
+
 GQA layout: ``num_heads`` query heads grouped onto ``num_kv_heads`` KV
 heads (head ``h`` reads KV head ``h // (H // KVH)``) — the grouping the
 TPU paged-attention kernel expects, consistent across all three paths.
@@ -44,6 +47,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as onp
 
 from .. import config as _config
 from ..gluon import nn
@@ -62,8 +66,9 @@ warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable")
 
 __all__ = ["DecoderConfig", "CausalLM", "full_forward", "make_decode_step",
-           "make_prefill_chunk",
-           "make_verify_step", "make_token_combine",
+           "make_prefill_chunk", "StepProgram", "decode_buffer",
+           "prefill_buffer", "DECODE_COLUMNS",
+           "make_verify_step",
            "pool_shape", "fresh_pool", "rows_from_pages", "pages_from_rows",
            "fork_page", "put_pages",
            "fn_cache_stats", "decode_launch_stats",
@@ -775,6 +780,110 @@ def hybrid_program(cfg, sharding, quant, kv_dtype, page_size=None,
     return True
 
 
+# ---------------------------------------------------------------------------
+# a launch's host inputs: one int32 buffer, one transfer
+# ---------------------------------------------------------------------------
+# A transfer to the device costs the host about as much for 150 bytes as
+# for 1 500 (PERF.md, section 5), so a step program takes every host input
+# of its launch in one int32 buffer and takes it apart with static slices.
+# A decode step's is (B, DECODE_COLUMNS + pages_per_seq): a column each of
+# the staged token, the carry mask, the position and the active mask, then
+# the lane's page table.  A prefill chunk's is (chunk + 2 + pages_per_seq,):
+# the padded tokens, pos0, n_valid, then the sequence's page row.
+
+#: the columns of a decode step's buffer before the page table
+DECODE_COLUMNS = 4
+
+
+def decode_buffer(staged, positions, page_tables, active, carry=None,
+                  xp=onp):
+    """One decode launch's buffer (numpy; ``xp=jnp`` traces it).  A lane
+    marked in ``carry`` takes its token from the step's ``chained``
+    argument (the in-flight launch's output), the others from ``staged``;
+    none by default."""
+    if carry is None:
+        carry = xp.zeros_like(staged)
+    cols = [xp.asarray(a).astype(xp.int32)
+            for a in (staged, carry, positions, active)]
+    return xp.concatenate([xp.stack(cols, axis=1),
+                           xp.asarray(page_tables).astype(xp.int32)], axis=1)
+
+
+def prefill_buffer(tokens, pos0, n_valid, page_row, xp=onp):
+    """One prefill chunk's buffer from ``tokens`` padded to the chunk
+    (numpy; ``xp=jnp`` traces it)."""
+    scalars = xp.stack([xp.asarray(pos0), xp.asarray(n_valid)])
+    return xp.concatenate([xp.asarray(a).astype(xp.int32).reshape(-1)
+                           for a in (tokens, scalars, page_row)])
+
+
+@jax.jit
+def _pack_decode(tokens, positions, page_tables, active):
+    return tokens, decode_buffer(tokens, positions, page_tables, active,
+                                 xp=jnp)
+
+
+@jax.jit
+def _pack_prefill(tokens, pos0, n_valid, page_row):
+    return (prefill_buffer(tokens, pos0, n_valid, page_row, xp=jnp),)
+
+
+class StepProgram:
+    """A step program as :func:`make_decode_step` and
+    :func:`make_prefill_chunk` hand it out.  ``program`` is the jitted
+    step over one launch's buffer, the form the engine calls; called with
+    the four separate inputs the factories document instead, the step
+    lays them into that buffer with a small jitted pack and runs the same
+    executable, so a caller of either form drives what the engine runs.
+    ``lower`` takes either form's shapes and lowers ``program``.
+    ``body`` is the step over the separate inputs, unjitted."""
+
+    def __init__(self, program, pack, body):
+        self.program, self._pack, self.body = program, pack, body
+
+    def __call__(self, params, k_pages, v_pages, *inputs):
+        if len(inputs) == 4:            # the separate form
+            inputs = self._pack(*inputs)
+        return self.program(params, k_pages, v_pages, *inputs)
+
+    def lower(self, params, k_pages, v_pages, *inputs):
+        if len(inputs) == 4:
+            inputs = jax.eval_shape(self._pack, *inputs)
+        return self.program.lower(params, k_pages, v_pages, *inputs)
+
+
+def _decode_program(body, plan=None):
+    """``body(params, k_pool, v_pool, tokens, positions, page_tables,
+    active)`` as ``step(params, k_pool, v_pool, chained, buffer)``
+    (:func:`decode_buffer`): a carried lane's token is ``chained``'s, the
+    merge of chained and staged tokens the step's first operation."""
+    def step(params, k_pages, v_pages, chained, buf):
+        staged, carry, positions, active = (
+            jax.lax.index_in_dim(buf, i, axis=1, keepdims=False)
+            for i in range(DECODE_COLUMNS))
+        tokens = jnp.where(carry != 0, chained, staged)
+        tables = jax.lax.slice_in_dim(buf, DECODE_COLUMNS, buf.shape[1],
+                                      axis=1)
+        return body(params, k_pages, v_pages, tokens, positions, tables,
+                    active != 0)
+    return StepProgram(_pool_program(step, plan, n_rest=2, n_out_rest=2),
+                       _pack_decode, body)
+
+
+def _prefill_program(body, chunk, plan=None):
+    """``body(params, k_pool, v_pool, tokens, pos0, n_valid, page_row)``
+    as ``prefill(params, k_pool, v_pool, buffer)`` (:func:`prefill_buffer`)."""
+    def prefill(params, k_pages, v_pages, buf):
+        tokens = jax.lax.slice_in_dim(buf, 0, chunk)
+        pos0, n_valid = (jax.lax.index_in_dim(buf, i, keepdims=False)
+                         for i in (chunk, chunk + 1))
+        page_row = jax.lax.slice_in_dim(buf, chunk + 2, buf.shape[0])
+        return body(params, k_pages, v_pages, tokens, pos0, n_valid,
+                    page_row)
+    return StepProgram(_pool_program(prefill, plan, n_rest=1, n_out_rest=2),
+                       _pack_prefill, body)
+
+
 def make_decode_step(cfg, page_size, sharding=None, quant=None,
                      kv_dtype=None):
     """Build (or fetch) the jitted batched decode step for
@@ -788,7 +897,12 @@ def make_decode_step(cfg, page_size, sharding=None, quant=None,
     the KV dtype key the same way — a quantized engine never shares a
     program with an fp one even at identical geometry.
 
-    fn(params, k_pages, v_pages, tokens, positions, page_tables, active)
+    fn(params, k_pages, v_pages, tokens, positions, page_tables, active),
+    or, as the engine calls it, fn(params, k_pages, v_pages, chained,
+    buffer): the same inputs in one :func:`decode_buffer`, with the lanes
+    its carry column marks fed ``chained`` (B,) int32 (the in-flight
+    launch's next_tokens) in place of their staged token
+    (a :class:`StepProgram`; one executable either way)
       k_pages/v_pages: the pools (:func:`fresh_pool`), rows form
                        (layers, total_pages, page_size, KVH * head_dim),
                        donated and updated in place; with
@@ -855,7 +969,7 @@ def _build_decode_step(cfg, page_size, plan=None):
         return (k_pages, v_pages,
                 jnp.argmax(logits, axis=-1).astype(jnp.int32), logits)
 
-    return _pool_program(step, plan, n_rest=4, n_out_rest=2)
+    return _decode_program(step, plan)
 
 
 def _pool_program(fn, plan, n_rest, n_out_rest):
@@ -867,25 +981,6 @@ def _pool_program(fn, plan, n_rest, n_out_rest):
     return plan.wrap(fn, n_rest, n_out_rest)
 
 
-def make_token_combine(slots):
-    """Build (or fetch) the async engine's lane-merge program: the next
-    step's input tokens without a host read.
-
-    Continuing lanes chain on the in-flight step's on-device
-    ``next_tokens`` (``carry`` true); lanes that joined the batch since
-    (fresh prefills) feed their host-staged pending token.  Keeping the
-    merge on-device is what lets the launch half of a pipelined step go
-    out before anyone has forced the previous step's result — the decode
-    program itself is untouched, so the static launch census is too.
-
-    fn(chained (B,) int32, staged (B,) int32, carry (B,) bool)
-      -> (B,) int32
-    """
-    key = ("combine", int(slots))
-    return _fn_cache.get(key, lambda: jax.jit(
-        lambda chained, staged, carry: jnp.where(carry, chained, staged)))
-
-
 def _kv_structs(cfg, page_size, total_pages, kv_dtype="float32"):
     """ShapeDtypeStruct of one pool (:func:`fresh_pool`'s, unallocated)."""
     return jax.eval_shape(lambda: fresh_pool(cfg, total_pages, page_size,
@@ -894,17 +989,17 @@ def _kv_structs(cfg, page_size, total_pages, kv_dtype="float32"):
 
 def _decode_step_structs(params, cfg, page_size, slots, pages_per_seq,
                          total_pages, kv_dtype="float32"):
-    """ShapeDtypeStruct argument tuple of one decode step (census
-    tracing/lowering without touching real buffers).  Quantized param
-    leaves (QuantW8/QuantW4 pytrees) map leaf-wise like raw arrays."""
+    """ShapeDtypeStruct argument tuple of one decode step as the engine
+    launches it (census tracing/lowering without touching real buffers).
+    Quantized param leaves (QuantW8/QuantW4 pytrees) map leaf-wise like
+    raw arrays."""
     kp = _kv_structs(cfg, page_size, total_pages, kv_dtype)
     return (jax.tree.map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params),
             kp, kp,
             jax.ShapeDtypeStruct((slots,), jnp.int32),
-            jax.ShapeDtypeStruct((slots,), jnp.int32),
-            jax.ShapeDtypeStruct((slots, pages_per_seq), jnp.int32),
-            jax.ShapeDtypeStruct((slots,), jnp.bool_))
+            jax.ShapeDtypeStruct((slots, DECODE_COLUMNS + pages_per_seq),
+                                 jnp.int32))
 
 
 def decode_launch_stats(params, cfg, page_size, slots, pages_per_seq,
@@ -924,7 +1019,7 @@ def decode_launch_stats(params, cfg, page_size, slots, pages_per_seq,
                           kv_dtype=kv_dtype)
     args = _decode_step_structs(params, cfg, S, slots, pages_per_seq,
                                 total_pages, kv_dtype=kv_dtype)
-    jaxpr = jax.make_jaxpr(fn)(*args)
+    jaxpr = jax.make_jaxpr(fn.program)(*args)
     return {"launches_per_step": int(_fused.count_launches(jaxpr)),
             "pallas_per_step": int(_fused.count_pallas_calls(jaxpr))}
 
@@ -961,7 +1056,9 @@ def make_prefill_chunk(cfg, page_size, chunk, sharding=None, quant=None,
     """Build (or fetch) the jitted single-sequence chunk prefill for
     (cfg, page_size, chunk) — cached in the bounded per-geometry LRU.
 
-    fn(params, k_pages, v_pages, tokens, pos0, n_valid, page_row)
+    fn(params, k_pages, v_pages, tokens, pos0, n_valid, page_row), or,
+    as the engine calls it, fn(params, k_pages, v_pages, buffer) with the
+    same inputs in one :func:`prefill_buffer` (a :class:`StepProgram`)
       tokens:  (chunk,) int32 — prompt slice, padded past n_valid
       pos0:    () int32 — absolute cache position of tokens[0]
       n_valid: () int32 — valid tokens in this chunk
@@ -1043,7 +1140,7 @@ def _build_prefill_chunk(cfg, page_size, chunk, plan=None):
         return (k_pages, v_pages,
                 jnp.argmax(last_logits).astype(jnp.int32), last_logits)
 
-    return _pool_program(prefill, plan, n_rest=4, n_out_rest=2)
+    return _prefill_program(prefill, P, plan)
 
 
 def make_verify_step(cfg, page_size, width, sharding=None, quant=None,

@@ -1072,7 +1072,8 @@ def _one_page(cfg, pages):
 
 def build_decode_step(cfg, page_size):
     """``step(params, k_pool, v_pool, tokens, positions, page_tables,
-    active)`` of :func:`~.decoder.make_decode_step` for a hybrid model."""
+    active)`` of :func:`~.decoder.make_decode_step` for a hybrid model, as
+    the :class:`~.decoder.StepProgram` over one launch's buffer."""
     S = int(page_size)
 
     def step(params, kp, vp, tokens, positions, page_tables, active):
@@ -1135,12 +1136,13 @@ def build_decode_step(cfg, page_size):
         logits = _logits(x, params, cfg)
         return kp, vp, jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
 
-    return jax.jit(step, donate_argnums=(1, 2))
+    return _dec._decode_program(step)
 
 
 def build_prefill_chunk(cfg, page_size, chunk):
     """``prefill(params, k_pool, v_pool, tokens, pos0, n_valid, page_row)``
-    of :func:`~.decoder.make_prefill_chunk` for a hybrid model."""
+    of :func:`~.decoder.make_prefill_chunk` for a hybrid model, as the
+    :class:`~.decoder.StepProgram` over one launch's buffer."""
     S, T = int(page_size), int(chunk)
     n_pages = -(-T // S) + 1            # a chunk may start inside a page
 
@@ -1197,7 +1199,7 @@ def build_prefill_chunk(cfg, page_size, chunk):
         return (kp, vp, jnp.argmax(last_logits).astype(jnp.int32),
                 last_logits)
 
-    return jax.jit(prefill, donate_argnums=(1, 2))
+    return _dec._prefill_program(prefill, T)
 
 
 # ---------------------------------------------------------------------------

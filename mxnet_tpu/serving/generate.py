@@ -64,8 +64,9 @@ The Orca + vLLM serving recipe, grown onto this repo's serving stack:
   depth-``MXNET_GEN_DISPATCH_AHEAD`` in-flight queue.  JAX dispatch is
   asynchronous: a launched step returns device futures immediately, so
   the sampled tokens stay on-device and the next step's token input
-  CHAINS on them (``decoder.make_token_combine``) — the host forces a
-  result only once the next launch is already in flight.  Admission,
+  CHAINS on them (the decode step's ``chained`` input, merged with the
+  host-staged tokens inside the step) — the host forces a result only
+  once the next launch is already in flight.  Admission,
   eviction, EOS, emission, and metrics shift to retire time; deadlines
   are checked at launch time so pipelining never extends one; pages an
   in-flight step writes are pinned (frees defer to that step's retire).
@@ -377,7 +378,6 @@ class DecodeEngine:
             for _ in range(2))
         self._tables = onp.zeros((self.slots, self.pages_per_seq),
                                  onp.int32)
-        self._tables_dev = None  # device copy, rebuilt when rows change
         self._decode_fn = _decoder.make_decode_step(
             cfg, self.page_size, sharding=self.sharding,
             quant=self.quant, kv_dtype=self.kv_dtype)
@@ -485,17 +485,17 @@ class DecodeEngine:
         self._flight_owners = {}          # owner -> in-flight refcount
         self._t_last_retire = None        # retire cadence (decode_step)
         # pinned staging buffers, reused every step: batch formation
-        # fills these in place instead of allocating fresh numpy arrays,
-        # and the device active mask re-uploads only when it changes.
-        # Uploads go through _upload (a private host copy): jnp.asarray
-        # zero-copy-aliases numpy memory on CPU, and a buffer an
-        # in-flight launch still reads must never be mutated in place.
+        # fills these in place, and a launch sends them with the page
+        # table as one buffer (_launch_decode_step).  Uploads go through
+        # _upload (a private host copy): jnp.asarray zero-copy-aliases
+        # numpy memory on CPU, and a buffer an in-flight launch still
+        # reads must never be mutated in place.
         self._stage_tokens = onp.zeros(self.slots, onp.int32)
         self._stage_positions = onp.zeros(self.slots, onp.int32)
         self._stage_active = onp.zeros(self.slots, bool)
         self._stage_carry = onp.zeros(self.slots, bool)
-        self._active_dev = None
-        self._active_key = None
+        #: the decode step's ``chained`` input where no lane chains
+        self._no_chain = jnp.zeros(self.slots, jnp.int32)
         #: a model that routes: the token-expert pairs a token makes over
         #: its routed layers, and what the programs counted so far (the
         #: pools' ``counts``, folded in whenever ``stats()`` is asked)
@@ -1413,24 +1413,23 @@ class DecodeEngine:
         self._tables[slot.idx, :] = 0
         if row:
             self._tables[slot.idx, :len(row)] = row
-        self._tables_dev = None  # invalidate the device copy
 
-    def _tables_device(self):
-        if self._tables_dev is None:
-            # _upload, not asarray: the device copy must be a real
-            # copy — an in-flight launch keeps reading it after the
-            # host mutates self._tables for the next step
-            self._tables_dev = self._upload(self._tables)
-        return self._tables_dev
-
-    def _active_device(self, mask):
-        """Device copy of the active mask, re-uploaded only when the
-        membership actually changes (steady-state steps reuse it)."""
-        key = mask.tobytes()
-        if self._active_key != key:
-            self._active_dev = self._upload(mask)
-            self._active_key = key
-        return self._active_dev
+    def _launch_decode_step(self, chained=None):
+        """Dispatch the decode step over the staged lanes (``_stage_*``)
+        with every host input, the whole page table among them, in one
+        buffer: one transfer (``decoder.decode_buffer``).  With
+        ``chained``, the newest in-flight launch's next tokens, a lane
+        marked in ``_stage_carry`` takes its token from there.  Returns
+        the launch's next tokens, on the device."""
+        carry = None if chained is None else self._stage_carry
+        buf = _decoder.decode_buffer(
+            self._stage_tokens, self._stage_positions, self._tables,
+            self._stage_active, carry)
+        self._kp, self._vp, out, _ = self._dispatch(
+            self._decode_fn, self.params, self._kp, self._vp,
+            self._no_chain if chained is None else chained,
+            self._upload(buf))
+        return out
 
     # -- in-flight page pinning (async pipeline) --------------------------
     def _pin_owners(self, fl):
@@ -1595,13 +1594,12 @@ class DecodeEngine:
             chunk = slot.prompt[slot.done:slot.done + n]
             padded = onp.zeros(self.prefill_chunk, onp.int32)
             padded[:n] = chunk
-            # a copy: the launch reads it after _sync_table has rewritten
-            # the host row for the next chunk's pages
-            row = self._upload(self._tables[slot.idx])
+            # every host input in one buffer, one transfer
+            buf = _decoder.prefill_buffer(padded, slot.pos, n,
+                                          self._tables[slot.idx])
             self._kp, self._vp, next_tok, _ = self._dispatch(
                 self._prefill_fn, self.params, self._kp, self._vp,
-                self._upload(padded), self._upload(onp.int32(slot.pos)),
-                self._upload(onp.int32(n)), row)
+                self._upload(buf))
         self._counts["prefill_launches_total"] += 1
         slot.history.extend(chunk)
         slot.pos += n
@@ -1679,14 +1677,8 @@ class DecodeEngine:
         self._count_decode_pages(positions, active)
         self._count_state("decode", 2 * len(live))
         t0 = time.perf_counter()
-        # staging buffers are reused next step: uploads must copy
-        # (_upload), never alias (jnp.asarray aliases host memory on
-        # CPU and the dispatch reads it after we mutate)
-        self._kp, self._vp, next_tokens, _ = self._dispatch(
-            self._decode_fn, self.params, self._kp, self._vp,
-            self._upload(tokens), self._upload(positions),
-            self._tables_device(), self._active_device(active))
-        next_tokens = self._device_wait("sync", onp.asarray, next_tokens)
+        next_tokens = self._device_wait("sync", onp.asarray,
+                                        self._launch_decode_step())
         now = time.perf_counter()
         for s in live:
             tok = int(next_tokens[s.idx])
@@ -1726,8 +1718,8 @@ class DecodeEngine:
         """Double-buffered decode: launch step N+1 while step N's result
         is still materializing on device, then retire launches down to
         the configured dispatch depth.  Sampled tokens stay on device as
-        jax.Arrays and chain into the next launch through a jitted
-        ``where(carry, chained, staged)`` — the host reads a step's
+        jax.Arrays and chain into the next launch, whose step takes
+        ``where(carry, chained, staged)`` first — the host reads a step's
         result (one ``jax.device_get``) only once the next launch is
         already in flight, so scheduling overhead hides behind device
         compute instead of serializing with it."""
@@ -1811,28 +1803,11 @@ class DecodeEngine:
                 else:
                     st[s.idx] = s.pending
             self._count_decode_pages(sp, sa)
-            # reused staging buffers: upload must COPY (_upload) — the
-            # dispatch reads host memory asynchronously and we refill these
-            # arrays before it completes
-            if chain and onp.array_equal(carry, sa):
-                # steady state: every live lane chains, so the combine is
-                # the identity — feed the in-flight output straight in.
-                # Inactive lanes see that step's garbage rows, which the
-                # active mask already quarantines (scratch-page writes,
-                # outputs nobody retires).
-                tokens = self._pipe[-1].out
-            elif chain:
-                tokens = self._dispatch(
-                    _decoder.make_token_combine(self.slots),
-                    self._pipe[-1].out, self._upload(st),
-                    self._upload(carry))
-            else:
-                tokens = self._upload(st)
             t0 = time.perf_counter()
-            self._kp, self._vp, out, _ = self._dispatch(
-                self._decode_fn, self.params, self._kp, self._vp, tokens,
-                self._upload(sp), self._tables_device(),
-                self._active_device(sa))
+            # a carried lane's token is the in-flight output's, merged
+            # with the staged ones inside the step
+            out = self._launch_decode_step(
+                self._pipe[-1].out if chain else None)
         fl = _Flight("plain", out, t0, [(s, s.admit_seq) for s in live],
                      set(s.owner for s in live))
         for s in live:
@@ -2163,7 +2138,7 @@ class DecodeEngine:
             self._kp, self._vp, out = self._dispatch(
                 verify_fn, self.params, self._kp, self._vp,
                 self._upload(tokens), self._upload(positions),
-                self._upload(n_valid), self._tables_device(),
+                self._upload(n_valid), self._upload(self._tables),
                 self._upload(active))
             kind = "verify"
         else:
@@ -2180,10 +2155,7 @@ class DecodeEngine:
                 sa[s.idx] = True
             self._count_decode_pages(sp, sa)
             t0 = time.perf_counter()
-            self._kp, self._vp, out, _ = self._dispatch(
-                self._decode_fn, self.params, self._kp, self._vp,
-                self._upload(st), self._upload(sp), self._tables_device(),
-                self._active_device(sa))
+            out = self._launch_decode_step()
             kind = "plain"
         fl = _Flight(kind, out, t0, [(s, s.admit_seq) for s in live],
                      set(s.owner for s in live), fed)
@@ -2349,7 +2321,7 @@ class DecodeEngine:
         self._kp, self._vp, out = self._dispatch(
             verify_fn, self.params, self._kp, self._vp,
             self._upload(tokens), self._upload(positions),
-            self._upload(n_valid), self._tables_device(),
+            self._upload(n_valid), self._upload(self._tables),
             self._upload(active))
         out = self._device_wait("sync", onp.asarray, out)
         now = time.perf_counter()
@@ -2513,7 +2485,6 @@ class DecodeEngine:
         slot.flight = 0
         slot.predraft = None
         self._tables[slot.idx, :] = 0
-        self._tables_dev = None
 
     # -- lifecycle / stats ------------------------------------------------
     def warmup(self):
@@ -2523,27 +2494,18 @@ class DecodeEngine:
         (``runtime.enable_compile_cache``) these become cache reads on
         replica restart, like the registry's bucket warmup."""
         import jax
-        zrow = jnp.zeros(self.pages_per_seq, jnp.int32)
+        zrow = onp.zeros(self.pages_per_seq, onp.int32)
         self._kp, self._vp, tok, _ = self._prefill_fn(
             self.params, self._kp, self._vp,
-            jnp.zeros(self.prefill_chunk, jnp.int32), jnp.int32(0),
-            jnp.int32(1), zrow)
+            jnp.asarray(_decoder.prefill_buffer(
+                onp.zeros(self.prefill_chunk, onp.int32), 0, 1, zrow)))
+        idle = onp.zeros(self.slots, onp.int32)
         self._kp, self._vp, toks, _ = self._decode_fn(
-            self.params, self._kp, self._vp,
-            jnp.zeros(self.slots, jnp.int32),
-            jnp.zeros(self.slots, jnp.int32),
-            jnp.zeros((self.slots, self.pages_per_seq), jnp.int32),
-            jnp.zeros(self.slots, bool))
+            self.params, self._kp, self._vp, self._no_chain,
+            jnp.asarray(_decoder.decode_buffer(
+                idle, idle, onp.zeros_like(self._tables), idle)))
         jax.block_until_ready(toks)
         compiled = 2
-        if self.async_decode:
-            # the chaining combine is part of the steady-state launch
-            # sequence — compile it now too
-            combo = _decoder.make_token_combine(self.slots)(
-                toks, jnp.zeros(self.slots, jnp.int32),
-                jnp.zeros(self.slots, bool))
-            jax.block_until_ready(combo)
-            compiled += 1
         if self._spec is not None:
             # pre-compile every verify width the adaptive-k controller
             # can reach (2 .. k_cap + 1) so acceptance swings never pay

@@ -166,6 +166,7 @@ class DraftModelDrafter(Drafter):
                                                     self.page_size)
         self._prefill_fn = _decoder.make_prefill_chunk(
             self.cfg, self.page_size, self.prefill_chunk)
+        self._no_chain = jnp.zeros(1, jnp.int32)
 
     def _row(self, owner):
         row = onp.zeros(self.pages_per_seq, onp.int32)
@@ -210,21 +211,20 @@ class DraftModelDrafter(Drafter):
             padded = onp.zeros(self.prefill_chunk, onp.int32)
             padded[:n] = context[st:st + n]
             self._kp, self._vp, _, _ = self._prefill_fn(
-                self.params, self._kp, self._vp, jnp.asarray(padded),
-                jnp.int32(st), jnp.int32(n),
-                jnp.asarray(self._row(owner)))
+                self.params, self._kp, self._vp,
+                jnp.asarray(_decoder.prefill_buffer(padded, st, n,
+                                                    self._row(owner))))
             st += n
         self._pos[owner] = want
         toks = []
         last = int(context[-1])
         pos = want
-        row = jnp.asarray(self._row(owner)[None])
+        row = self._row(owner)[None]
         for _ in range(k):          # greedy k-step lookahead, B=1
             self._kp, self._vp, nxt, _ = self._decode_fn(
-                self.params, self._kp, self._vp,
-                jnp.asarray([last], jnp.int32),
-                jnp.asarray([pos], jnp.int32), row,
-                jnp.ones((1,), bool))
+                self.params, self._kp, self._vp, self._no_chain,
+                jnp.asarray(_decoder.decode_buffer([last], [pos], row,
+                                                   [True])))
             last = int(nxt[0])
             toks.append(last)
             pos += 1

@@ -33,9 +33,10 @@ def test_engine_counts_the_pages_under_the_positions_it_staged(async_decode):
                                   prefix_cache=False)
     staged, step = [], engine._decode_fn
 
-    def recording(params, kp, vp, tokens, positions, tables, active):
-        staged.append((onp.array(positions), onp.array(active)))
-        return step(params, kp, vp, tokens, positions, tables, active)
+    def recording(params, kp, vp, chained, buf):
+        buf = onp.array(buf)            # decoder.decode_buffer's columns
+        staged.append((buf[:, 2], buf[:, 3] != 0))
+        return step(params, kp, vp, chained, buf)
     engine._decode_fn = recording
     try:
         prompts = [[1, 2, 3], list(range(1, 10)), [5] * 6]

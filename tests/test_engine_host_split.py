@@ -49,16 +49,14 @@ def counted(fn, calls, key):
 
 
 @pytest.mark.parametrize("async_decode", [True, False])
-def test_launches_total_counts_every_program(lm, monkeypatch, async_decode):
-    """Prefill chunks, decode steps and the async engine's token combines,
-    each counted where it is called, add up to ``launches_total``.  The
-    long prompt joins the batch two steps late, so the async engine
-    combines carried and staged tokens."""
+def test_launches_total_counts_every_program(lm, async_decode):
+    """Prefill chunks and decode steps, each counted where it is called,
+    add up to ``launches_total``: no other program is launched.  The long
+    prompt joins the batch two steps late, so the async engine launches
+    steps whose lanes mix carried and staged tokens, merged inside the
+    decode step."""
     prompts = [list(range(1, 20))] + PROMPTS
-    calls = dict.fromkeys(("prefill", "decode", "combine"), 0)
-    make_combine = decoder.make_token_combine
-    monkeypatch.setattr(decoder, "make_token_combine", lambda slots: counted(
-        make_combine(slots), calls, "combine"))
+    calls = dict.fromkeys(("prefill", "decode"), 0)
     eng = make_engine(lm, async_decode=async_decode, prefix_cache=False)
     eng._prefill_fn = counted(eng._prefill_fn, calls, "prefill")
     eng._decode_fn = counted(eng._decode_fn, calls, "decode")
@@ -74,16 +72,15 @@ def test_launches_total_counts_every_program(lm, monkeypatch, async_decode):
     # the async engine reads every decode launch once, at its retire
     assert calls["decode"] == counters[
         "deferred_reads_total" if async_decode else "decode_steps_total"] > 0
-    assert (calls["combine"] > 0) == async_decode
-    assert counters["launches_total"] == sum(calls.values())
+    assert counters["launches_total"] == calls["prefill"] + calls["decode"]
 
 
 def test_host_uploads_are_counted_exactly(lm):
     """The synchronous engine, one prompt of three tokens in one page, four
-    tokens: a prefill chunk uploads its page-table row, the padded chunk
-    and two scalars; each of the three decode steps uploads its tokens, its
-    positions and the page table (re-staged by every step's page check),
-    and the first of them the active mask besides."""
+    tokens: one upload a launch.  The prefill chunk's holds the padded
+    chunk, pos0, n_valid and the page-table row; each of the three decode
+    steps' its tokens, carry mask, positions and active mask, a column
+    each, and the whole page table."""
     eng = make_engine(lm, async_decode=False, prefix_cache=False)
     try:
         assert len(eng.submit([1, 2, 3], max_new_tokens=4).result(
@@ -93,10 +90,10 @@ def test_host_uploads_are_counted_exactly(lm):
     counters = eng.metrics.snapshot()["models"]["llm"]["counters"]
     slots, pages = eng._tables.shape
     i32 = onp.dtype(onp.int32).itemsize
-    prefill = [pages * i32, eng.prefill_chunk * i32, i32, i32]
-    decode = [slots * i32, slots * i32, slots * pages * i32]
-    active = [slots * onp.dtype(bool).itemsize]
-    uploads = prefill + 3 * decode + active
+    prefill = [(eng.prefill_chunk + 2 + pages) * i32]
+    decode = [slots * (decoder.DECODE_COLUMNS + pages) * i32]
+    uploads = prefill + 3 * decode
+    assert decoder.DECODE_COLUMNS == 4
     assert counters["launches_total"] == 4
     assert counters["host_uploads_total"] == len(uploads)
     assert counters["host_upload_bytes_total"] == sum(uploads)

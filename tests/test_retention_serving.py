@@ -741,8 +741,10 @@ def test_the_cell_rehearses_on_the_cpu(trace_flag, tmp_path):
 # the other recurrent models' programs are the parent's
 # ---------------------------------------------------------------------------
 #: sha256 of the lowered text (CPU) of the two step programs of jamba's and
-#: solar's serving configurations at their cells' geometry, taken on the
-#: parent of PR 37 (9e85419): the retention kind adds no operation to them.
+#: solar's serving configurations at their cells' geometry, taken at
+#: 9e85419, before the retention kind: it adds no operation to them.  The
+#: programs have since taken a launch's inputs in one buffer; their bodies
+#: over the separate inputs (``StepProgram.body``) still lower to this text.
 PARENT_LOWERED = {
     "jamba2-3b-serve": (
         "e08a62de2f667845bab5abd4f2d51a8c0891268472b8654a70824e1f30e1460e",
@@ -750,6 +752,15 @@ PARENT_LOWERED = {
     "solar-open2-ep8-serve": (
         "9738871932d49995c4cd550fe0be1e670f0b6d5a5908866c205ff70397368a43",
         "a1d3497f0f02941b8ec3ee6a0b709eb15ab02e9397101d1c8385c948a933f562"),
+}
+#: the same of the programs over one buffer a launch, as the engine runs them
+PACKED_LOWERED = {
+    "jamba2-3b-serve": (
+        "6d366689354938e80fe535a9775bb851627e15eaf4e408cbef22a9c5a0265297",
+        "affa97ec244660c2e8c54053dba065197f1311aab7acbdc3eaf6be4d24cc5469"),
+    "solar-open2-ep8-serve": (
+        "f4338a2dee2b37dbae29496013cd44a727ea28b423297c58d4f061e9fb20c32f",
+        "41127f942eb922b89b10afa0ee3b8f0b6cc19a455762fdb2f1d68833fdc7cc42"),
 }
 
 
@@ -780,11 +791,15 @@ def test_jamba_and_solar_programs_lower_to_the_parents_text(name,
 
     def i32(*s):
         return jax.ShapeDtypeStruct(s, jnp.int32)
-    texts = (
-        decoder.make_decode_step(cfg, S, kv_dtype=kvd).lower(
-            params, pool, pool, i32(B), i32(B), i32(B, pps),
-            jax.ShapeDtypeStruct((B,), jnp.bool_)).as_text(),
-        decoder.make_prefill_chunk(cfg, S, T, kv_dtype=kvd).lower(
-            params, pool, pool, i32(T), i32(), i32(), i32(pps)).as_text())
-    assert tuple(hashlib.sha256(t.encode()).hexdigest()
-                 for t in texts) == PARENT_LOWERED[name]
+    programs = (decoder.make_decode_step(cfg, S, kv_dtype=kvd),
+                decoder.make_prefill_chunk(cfg, S, T, kv_dtype=kvd))
+    inputs = ((i32(B), i32(B), i32(B, pps), jax.ShapeDtypeStruct(
+        (B,), jnp.bool_)), (i32(T), i32(), i32(), i32(pps)))
+
+    def digests(lower):
+        return tuple(hashlib.sha256(lower(p).lower(
+            params, pool, pool, *rest).as_text().encode()).hexdigest()
+            for p, rest in zip(programs, inputs))
+    assert digests(lambda p: jax.jit(p.body, donate_argnums=(1, 2))
+                   ) == PARENT_LOWERED[name]
+    assert digests(lambda p: p) == PACKED_LOWERED[name]
