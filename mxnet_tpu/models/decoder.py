@@ -75,6 +75,7 @@ __all__ = ["DecoderConfig", "CausalLM", "full_forward", "make_decode_step",
            "verify_launch_stats", "decode_collective_stats", "tp_plan",
            "TPPlan", "causal_lm", "decoder_tiny", "decoder_tiny_lm",
            "decoder_draft", "hybrid_lm", "routed_delta_lm", "retention_lm",
+           "latent_routed_lm",
            "is_hybrid"]
 
 
@@ -282,7 +283,10 @@ def fresh_pool(cfg, total_pages, page_size, kv_dtype=None):
     per page and state-space layer** (the recurrent state after the last
     token written into that page, float32).  The K pool carries the first
     half of the channels and the V pool the second, so the two calls
-    return the same structure and neither part stays unread."""
+    return the same structure and neither part stays unread.  For a
+    model with latent attention layers its ``rows`` are the latent rows,
+    the K pool the first half of every page's positions and the V pool
+    the second (:mod:`.latent`)."""
     kv_dtype = _kv_dtype(cfg, kv_dtype)
     if is_hybrid(cfg):
         return _hybrid.fresh_pool(cfg, total_pages, page_size, kv_dtype)
@@ -747,6 +751,8 @@ def recurrent_name(cfg):
     """What a refusal calls the layers that keep a paged state."""
     return ("retention layers" if _hybrid.RETENTION in cfg.layer_kinds
             else "delta-rule layers" if _hybrid.DELTA in cfg.layer_kinds
+            else "latent attention layers"
+            if _hybrid.LATENT in cfg.layer_kinds
             else "state-space layers")
 
 
@@ -1502,6 +1508,76 @@ def retention_lm(seed=0, *, vocab_size, num_layers, units, hidden_size,
         recurrent=_hybrid.RETENTION, rope_theta=rope_theta,
         tied_head=tied_head, rms_eps=rms_eps,
         max_length=max_length, dtype=dtype)
+
+
+def latent_routed_lm(seed=0, *, vocab_size, num_layers, units, hidden_size,
+                     num_heads, num_kv_heads, q_lora_rank, kv_lora_rank,
+                     qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                     rope_theta, rope_scaling, dense_layers, n_experts,
+                     experts_per_token, expert_hidden, shared_experts=1,
+                     norm_topk=True, routed_scale=1.0,
+                     scoring_func="sigmoid", topk_method="noaux_tc",
+                     n_group=1, topk_group=1, moe_layer_freq=1, ep_size=1,
+                     hc_mult=1, hc_sinkhorn_iters=0, hc_eps=0.0,
+                     hc_clamp_min=0.0, hc_clamp_max=0.0, nextn_layers=0,
+                     tied_head=False, rms_eps=1e-6, max_length=4096,
+                     hidden_act="silu", attention_bias=False,
+                     kv_dtype=None, dtype="bfloat16"):
+    """A :class:`~.hybrid.HybridLM` of the ``xing4_0`` model type (the
+    DeepSeek-V3 block with mHC): every layer multi-head latent attention
+    (``"latent_attention"``, :mod:`.latent`) over a paged latent cache,
+    YaRN rotary positions, the first ``dense_layers`` layers with the dense
+    SiLU-gated MLP of width ``hidden_size`` and the others routed (top
+    ``experts_per_token`` of ``n_experts`` by sigmoid scores plus a
+    selection bias, normalised, times ``routed_scale``, and the shared
+    expert), all experts held here; ``hc_mult`` residual streams (mHC).
+    The arguments are the published keys under this repo's names (a
+    benchmark configuration's ``builder_kwargs`` maps them); what the block
+    has no code for is refused, not ignored."""
+    scaling = dict(rope_scaling or {})
+    for what, asked in (
+            ("scoring_func %r" % (scoring_func,), scoring_func != "sigmoid"),
+            ("topk_method %r" % (topk_method,), topk_method != "noaux_tc"),
+            ("grouped routing (n_group %r, topk_group %r)"
+             % (n_group, topk_group), (n_group, topk_group) != (1, 1)),
+            ("moe_layer_freq %r" % (moe_layer_freq,), moe_layer_freq != 1),
+            ("ep_size %r (the experts are held whole)" % (ep_size,),
+             ep_size != 1),
+            ("hidden_act %r" % (hidden_act,), hidden_act != "silu"),
+            ("attention_bias", attention_bias),
+            ("num_key_value_heads %r != num_attention_heads"
+             % (num_kv_heads,), num_kv_heads != num_heads),
+            ("rope_scaling of type %r" % (scaling.get("type"),),
+             scaling.get("type", "yarn") != "yarn"),
+            ("next-n prediction layers (the engine has no drafter from "
+             "them)", nextn_layers),
+            ("an asymmetric clamp of H_res's logits",
+             hc_clamp_min != -hc_clamp_max)):
+        if asked:
+            raise ValueError("decoder.latent_routed_lm: %s is not supported"
+                             % what)
+    from .latent import Latent
+    spec = Latent(
+        q_rank=int(q_lora_rank), kv_rank=int(kv_lora_rank),
+        nope=int(qk_nope_head_dim), rope=int(qk_rope_head_dim),
+        v_dim=int(v_head_dim), theta=float(rope_theta),
+        factor=float(scaling.get("factor", 1.0)),
+        original=int(scaling.get("original_max_position_embeddings", 0)),
+        beta_fast=float(scaling.get("beta_fast", 32)),
+        beta_slow=float(scaling.get("beta_slow", 1)),
+        mscale=float(scaling.get("mscale", 1.0)),
+        mscale_all_dim=float(scaling.get("mscale_all_dim", 0.0)))
+    return _hybrid.hybrid_lm(
+        seed, vocab_size=vocab_size, num_layers=num_layers, units=units,
+        hidden_size=hidden_size, num_heads=num_heads,
+        num_kv_heads=num_kv_heads, head_dim=v_head_dim, latent=spec,
+        dense_layers=dense_layers, n_experts=n_experts,
+        experts_per_token=experts_per_token, expert_hidden=expert_hidden,
+        shared_hidden=expert_hidden * shared_experts, norm_topk=norm_topk,
+        routed_scale=routed_scale, streams=hc_mult,
+        sinkhorn_iters=hc_sinkhorn_iters, hc_eps=hc_eps,
+        hc_clamp=hc_clamp_max, tied_head=tied_head, rms_eps=rms_eps,
+        max_length=max_length, kv_dtype=kv_dtype, dtype=dtype)
 
 
 # at the end: hybrid.py builds on the helpers above

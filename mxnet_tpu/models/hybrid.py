@@ -1,12 +1,12 @@
 """A hybrid decoder: recurrent layers (selective state-space, gated delta
 rule or power retention) with a few attention layers among them or none
-(the Jamba family's block and its kin), on the step programs of
-:mod:`.decoder`.
+(the Jamba family's block and its kin), or latent attention layers alone,
+on the step programs of :mod:`.decoder`.
 
 The block is pre-norm with RMSNorm, no biases and no positions outside the
-retention mixer's rotary ones; every layer is ``h = x + mixer(rms(x));
-x' = h + mlp(rms(h))`` with the SiLU-gated ``mlp(u) = W_down (silu(W_gate
-u) * (W_up u))``.  The mixer of a layer is named by
+retention and latent mixers' rotary ones; every layer is ``h = x +
+mixer(rms(x)); x' = h + mlp(rms(h))`` with the SiLU-gated ``mlp(u) =
+W_down (silu(W_gate u) * (W_up u))``.  The mixer of a layer is named by
 ``HybridConfig.layer_kinds``:
 
 - ``"attention"``: causal softmax attention, ``num_heads`` query heads on
@@ -42,6 +42,12 @@ u) * (W_up u))``.  The mixer of a layer is named by
   hands the state from block to block (:func:`retention_chunk`).  Its
   state entry is ``S`` and ``z`` of every KV head, float32, no
   convolution.  A model with it has no attention layer.
+- ``"latent_attention"``: multi-head latent attention (MLA) over a cache
+  of one compressed row a token and layer, ``[c_kv, k_r]``; its
+  equations, its pool (a page's first half of positions in the K pool,
+  its second half in the V pool) and its two paths, absorbed for a decode
+  step and up-projected in blocks for a prefill chunk, are in
+  :mod:`.latent`.  A model with it has no other mixer.
 - ``"state_space"``: ``[a, z] = W_in u``; a causal depthwise convolution
   ``c_t = silu(b + sum_j w[:, j] a_{t-(K-1)+j})``; ``[d, B, C] = W_x c_t``,
   each RMS-normalised; ``delta = softplus(W_dt d + b_dt)``;
@@ -53,7 +59,24 @@ u) * (W_up u))``.  The mixer of a layer is named by
 A model has one recurrent kind.  The feed-forward part is ``mlp`` above
 or, where the config names routed experts (``n_experts``), the routed
 layer of :mod:`.routed`: top ``experts_per_token`` of all ``n_experts``,
-of which this chip holds ``experts_held``, and one shared expert.
+of which this chip holds ``experts_held``, and one shared expert; the
+first ``dense_layers`` layers keep ``mlp`` (of width ``hidden_size``).
+
+**Residual streams** (``streams`` > 1: manifold-constrained hyper-
+connections, mHC).  The residual is ``x`` (n, units), the embedding
+entering as n copies.  Each sub-layer ``F`` (the mixer, then the
+feed-forward part), with ``x~ = rms(vec x)`` (no gain) and its own
+``phi`` (n (n + 2), n units), ``alpha`` (3,) and ``b``::
+
+    H_pre = sigmoid(alpha_0 x~ phi_pre + b_pre)            (n,)
+    H_post = 2 sigmoid(alpha_1 x~ phi_post + b_post)       (n,)
+    H_res = Sinkhorn(exp(clamp(alpha_2 x~ phi_res + b_res)))   (n, n)
+    x <- H_res x + H_post^T F(rms(H_pre x))
+
+``Sinkhorn`` normalises rows then columns ``sinkhorn_iters`` times (each
+sum plus ``hc_eps``), the logits clamped to ``+-hc_clamp``.  Before the
+final norm the streams are mixed into one by a last ``H_pre``-form mix
+(``hc_head_*``).
 
 **The state is paged** (:class:`HybridPool`).  Every page of a sequence has
 one state entry per state-space layer: the state after the last token
@@ -92,6 +115,7 @@ from ..gluon.block import HybridBlock
 from ..gluon.parameter import Parameter
 from ..ops import attention as _attention
 from . import decoder as _dec
+from . import latent as _latent
 from . import routed as _routed
 
 __all__ = ["HybridConfig", "HybridPool", "HybridLM", "hybrid_lm",
@@ -100,7 +124,7 @@ __all__ = ["HybridConfig", "HybridPool", "HybridLM", "hybrid_lm",
            "full_forward", "build_decode_step", "build_prefill_chunk"]
 
 ATTENTION, STATE_SPACE, DELTA = "attention", "state_space", "delta_rule"
-RETENTION = "retention"
+RETENTION, LATENT = "retention", "latent_attention"
 
 
 class HybridConfig(NamedTuple):
@@ -137,6 +161,12 @@ class HybridConfig(NamedTuple):
     norm_topk: bool = True
     routed_scale: float = 1.0
     rope_theta: float = 0.0         # the retention mixer's rotary base
+    latent: tuple = ()              # the latent mixer: a latent.Latent
+    dense_layers: int = 0           # leading layers with the dense mlp
+    streams: int = 1                # residual streams (mHC's hc_mult)
+    sinkhorn_iters: int = 0
+    hc_eps: float = 0.0
+    hc_clamp: float = 0.0
 
 
 class HybridPool(NamedTuple):
@@ -166,6 +196,10 @@ class HybridPool(NamedTuple):
     where the array is that layer's alone; ``conv`` holds the normaliser
     ``z`` of the same heads, ``KVH / 2 * F`` floats flat (in parts, as
     above).  A sequence holds one page (:func:`_one_page`).
+    For latent attention layers ``rows`` holds the cached latent rows,
+    ``(latent layers, P, S / 2, width)``: the K pool positions ``[0, S /
+    2)`` of every page, the V pool ``[S / 2, S)``
+    (:func:`.latent.write_rows`); ``ssm`` and ``conv`` are None.
 
     ``counts``, only of a model that routes (else None, no leaf): what the
     routed layers counted, summed over layers and launches, uint32
@@ -185,7 +219,8 @@ def layer_runs(cfg):
     programs scan over it, so a run compiles once however long it is."""
     runs, lo = [], 0
     for i in range(1, cfg.num_layers + 1):
-        if i == cfg.num_layers or cfg.layer_kinds[i] != cfg.layer_kinds[lo]:
+        if i == cfg.num_layers or cfg.layer_kinds[i] != cfg.layer_kinds[lo] \
+                or i == cfg.dense_layers:
             runs.append((cfg.layer_kinds[lo], lo, i))
             lo = i
     return runs
@@ -198,7 +233,15 @@ def _count(cfg, kind):
 def recurrent_layers(cfg):
     """The layers that keep a state entry: state-space, delta-rule or
     retention."""
-    return cfg.num_layers - _count(cfg, ATTENTION)
+    return cfg.num_layers - _count(cfg, ATTENTION) - _count(cfg, LATENT)
+
+
+def token_row_elems(cfg):
+    """Elements a token's rows take over both pools: keys and values of
+    every attention layer, or one latent row of every latent layer."""
+    if LATENT in cfg.layer_kinds:
+        return _count(cfg, LATENT) * _latent.row_width(cfg.latent)
+    return 2 * _count(cfg, ATTENTION) * cfg.num_kv_heads * cfg.head_dim
 
 
 def phi_width(d):
@@ -233,6 +276,15 @@ def state_entry_bytes(cfg):
 
 def fresh_pool(cfg, total_pages, page_size, kv_dtype):
     """A zeroed :class:`HybridPool`, its token rows in ``kv_dtype``."""
+    counts = (jnp.zeros(len(_routed.COUNTS), jnp.uint32) if cfg.n_experts
+              else None)
+    if LATENT in cfg.layer_kinds:
+        return HybridPool(
+            rows=jnp.zeros((_count(cfg, LATENT), int(total_pages),
+                            int(page_size) // 2,
+                            _latent.row_width(cfg.latent)),
+                           jnp.dtype(kv_dtype)),
+            ssm=None, conv=None, counts=counts)
     P, layers = int(total_pages), recurrent_layers(cfg)
     state, axis, conv = _entry_dims(cfg)
     half = tuple(n // 2 if i == axis else n for i, n in enumerate(state))
@@ -250,9 +302,7 @@ def fresh_pool(cfg, total_pages, page_size, kv_dtype):
                         cfg.num_kv_heads * cfg.head_dim),
                        jnp.dtype(kv_dtype)),
         ssm=ssm,
-        conv=rows[0] if parts == 1 else tuple(rows),
-        counts=(jnp.zeros(len(_routed.COUNTS), jnp.uint32)
-                if cfg.n_experts else None))
+        conv=rows[0] if parts == 1 else tuple(rows), counts=counts)
 
 
 # ---------------------------------------------------------------------------
@@ -299,21 +349,22 @@ def _rms(x, g, eps):
             * g.astype(jnp.float32))
 
 
-def _mlp(x, lp, eps):
-    u = _rms(x, lp["norm2"], eps)
-    return x + _mm(jax.nn.silu(_mm(u, lp["w_gate"])) * _mm(u, lp["w_up"]),
-                   lp["w_down"])
+def _ffn(x, lp, cfg, experts, j, live):
+    """``ffn(rms(x))`` of the layer's second half and what a routed layer
+    counted (None for the dense mlp, the layer's where it has ``w_gate``).
+    ``experts``: the run's routed experts, all its layers', of which this
+    is layer ``j``; ``live``: the tokens that are somebody's."""
+    u = _rms(x, lp["norm2"], cfg.rms_eps)
+    if "w_gate" in lp:
+        return _mm(jax.nn.silu(_mm(u, lp["w_gate"])) * _mm(u, lp["w_up"]),
+                   lp["w_down"]), None
+    return _routed.routed_feed_forward(u, lp, experts, j, cfg, live)
 
 
 def _feed_forward(x, lp, cfg, experts, j, live):
     """The layer's second half, ``x + ffn(rms(x))``, and what a routed layer
-    counted (None for the dense mlp).  ``experts``: the run's routed
-    experts, all its layers', of which this is layer ``j``; ``live``: the
-    tokens that are somebody's."""
-    if not cfg.n_experts:
-        return _mlp(x, lp, cfg.rms_eps), None
-    u = _rms(x, lp["norm2"], cfg.rms_eps)
-    out, counts = _routed.routed_feed_forward(u, lp, experts, j, cfg, live)
+    counted (:func:`_ffn`)."""
+    out, counts = _ffn(x, lp, cfg, experts, j, live)
     return x + out, counts
 
 
@@ -530,8 +581,17 @@ def _rope(x, positions, theta):
     half = x.shape[-1] // 2
     inv = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) * 2
                           / x.shape[-1])
+    return _rotate(x, positions, inv)
+
+
+def _rotate(x, positions, inv, scale=1.0):
+    """:func:`_rope` at the frequencies ``inv`` (d / 2,), cos and sin times
+    ``scale``."""
+    half = x.shape[-1] // 2
     ang = positions.astype(jnp.float32)[..., None, None] * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
@@ -937,34 +997,108 @@ def _write_entry(kp, vp, li, pages, h, conv, cfg):
     return put(kp, 0), put(vp, 1)
 
 
+def _sinkhorn(m, iters, eps):
+    """Rows then columns of the positive ``m`` (..., n, n) normalised to
+    sums of 1, ``iters`` times; each sum plus ``eps``."""
+    for _ in range(iters):
+        m = m / (m.sum(-1, keepdims=True) + eps)
+        m = m / (m.sum(-2, keepdims=True) + eps)
+    return m
+
+
+def _hc_logits(x, lp, name, cfg):
+    """``alpha * (x~ phi) + b`` of the streams ``x`` (..., n, units),
+    ``x~ = rms(vec x)`` with no gain, the product in float32 at the highest
+    precision: (..., n (n + 2)) logits of ``[H_pre, H_post, H_res]``."""
+    n = cfg.streams
+    flat = x.reshape(x.shape[:-2] + (-1,))
+    flat = flat * jax.lax.rsqrt(jnp.square(flat).mean(-1, keepdims=True)
+                                + cfg.rms_eps)
+    alpha = lp[name + "_alpha"].astype(jnp.float32)
+    sizes = [n, n, n * n][:alpha.shape[-1]]     # the head's mix: one alpha
+    alpha = jnp.repeat(alpha, np.array(sizes), total_repeat_length=sum(sizes))
+    return (alpha * _mm_hi(flat, lp[name + "_phi"])
+            + lp[name + "_bias"].astype(jnp.float32))
+
+
+def _mix(weights, x):
+    """``sum_j weights[.., j] x[.., j, :]``: (..., n) by (..., n, units),
+    elementwise in float32."""
+    return sum(weights[..., j, None] * x[..., j, :]
+               for j in range(x.shape[-2]))
+
+
+def _hc_read(x, lp, name, cfg):
+    """A sub-layer's view of the streams: its input ``H_pre x`` (..., units),
+    ``H_post`` (..., n) and ``H_res`` (..., n, n)."""
+    n = cfg.streams
+    t = _hc_logits(x, lp, name, cfg)
+    pre = jax.nn.sigmoid(t[..., :n])
+    post = 2.0 * jax.nn.sigmoid(t[..., n:2 * n])
+    res = _sinkhorn(jnp.exp(jnp.clip(
+        t[..., 2 * n:].reshape(t.shape[:-1] + (n, n)),
+        -cfg.hc_clamp, cfg.hc_clamp)), cfg.sinkhorn_iters, cfg.hc_eps)
+    return _mix(pre, x), post, res
+
+
+def _hc_write(x, out, post, res):
+    """``H_res x + H_post^T out``: the streams after a sub-layer."""
+    return (sum(res[..., :, j, None] * x[..., None, j, :]
+                for j in range(x.shape[-2]))
+            + post[..., None] * out[..., None, :])
+
+
+def _streams_in(x, cfg):
+    """The embedding as the residual: n copies where the model has n > 1
+    streams, else as it is."""
+    if cfg.streams == 1:
+        return x
+    return jnp.broadcast_to(x[..., None, :], x.shape[:-1]
+                            + (cfg.streams, x.shape[-1]))
+
+
 def _over_layers(params, cfg, x, kp, vp, attention, recurrent, live=None):
     """The layers in order: a ``lax.scan`` over each run's stacked
     parameters, the residual stream and both pools carried.  ``attention``
-    and ``recurrent`` (the state-space, delta-rule or retention mixer) are
+    (the softmax or the latent mixer) and ``recurrent`` (the state-space,
+    delta-rule or retention mixer) are
     ``(u, lp, kp, vp, li) -> (out, kp, vp)`` with ``li`` the layer's index
     among its own kind (its row in the pool; a Python int for a run of one
-    layer and for retention, whose S is a tuple of layers).  Returns the stream, the pools and
+    layer and for retention, whose S is a tuple of layers, and latent
+    attention, whose kernel reads the pool whole).  Returns the stream, the pools and
     what the routed layers counted (None where the model has none); a
     run's routed experts stay out of the scan and go to the grouped
-    product whole (:func:`.routed.routed_feed_forward`)."""
+    product whole (:func:`.routed.routed_feed_forward`).  With residual
+    streams ``x`` is (..., n, units) and each sub-layer reads and writes
+    it through :func:`_hc_read` and :func:`_hc_write`."""
     seen = {ATTENTION: 0, "recurrent": 0}
     counts = (jnp.zeros(len(_routed.COUNTS), jnp.uint32)
               if cfg.n_experts else None)
     for (kind, lo, hi), run in zip(layer_runs(cfg), params["runs"]):
-        mixer = attention if kind == ATTENTION else recurrent
+        mixer = attention if kind in (ATTENTION, LATENT) else recurrent
         # a static layer index: the page-wise writes; retention's S, a
-        # tuple of layers
-        static = hi - lo == 1 or kind == RETENTION
-        kind = ATTENTION if kind == ATTENTION else "recurrent"
+        # tuple of layers; the latent kernel's pool
+        static = hi - lo == 1 or kind in (RETENTION, LATENT)
+        kind = ATTENTION if kind in (ATTENTION, LATENT) else "recurrent"
         experts = {k: run[k] for k in _routed.EXPERT_LEAVES if k in run}
         run = {k: v for k, v in run.items() if k not in experts}
 
         def layer(carry, xs, mixer=mixer, experts=experts):
             x, kp, vp, counts = carry
             lp, li, j = xs
-            out, kp, vp = mixer(_rms(x, lp["norm1"], cfg.rms_eps), lp, kp,
-                                vp, li)
-            x, counted = _feed_forward(x + out, lp, cfg, experts, j, live)
+            if cfg.streams > 1:
+                h, post, res = _hc_read(x, lp, "hc_attn", cfg)
+                out, kp, vp = mixer(_rms(h, lp["norm1"], cfg.rms_eps), lp,
+                                    kp, vp, li)
+                x = _hc_write(x, out, post, res)
+                h, post, res = _hc_read(x, lp, "hc_ffn", cfg)
+                out, counted = _ffn(h, lp, cfg, experts, j, live)
+                x = _hc_write(x, out, post, res)
+            else:
+                out, kp, vp = mixer(_rms(x, lp["norm1"], cfg.rms_eps), lp,
+                                    kp, vp, li)
+                x, counted = _feed_forward(x + out, lp, cfg, experts, j,
+                                           live)
             if counted is not None:
                 counts = counts + counted
             return (x, kp, vp, counts), None
@@ -984,6 +1118,8 @@ def _over_layers(params, cfg, x, kp, vp, attention, recurrent, live=None):
 
 
 def _logits(x, params, cfg):
+    if cfg.streams > 1:     # the streams mixed into one
+        x = _mix(jax.nn.sigmoid(_hc_logits(x, params, "hc_head", cfg)), x)
     return _mm(_rms(x, params["norm_f"], cfg.rms_eps),
                params["embed" if cfg.tied_head else "head"])
 
@@ -1041,6 +1177,15 @@ def full_forward(params, cfg, tokens):
         return _attention_out(att.transpose(0, 2, 1, 3).reshape(B, L, -1),
                               u, lp, cfg), kp, vp
 
+    def latent(u, lp, kp, vp, li):
+        q_nope, q_rope, rows = _latent.latent_inputs(
+            u, lp, cfg, jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32),
+                                         (B, L)))
+        att = _latent.full_attention(q_nope, q_rope, rows, lp, cfg)
+        return _mm(att, lp["wo"]), kp, vp
+
+    if LATENT in cfg.layer_kinds:
+        attention = latent
     state, _, conv = _entry_dims(cfg)
     chunk = _chunk_fn(cfg, 0)
 
@@ -1051,7 +1196,7 @@ def full_forward(params, cfg, tokens):
                                         last)[0])(u)
         return out, kp, vp
 
-    x = params["embed"][tokens].astype(jnp.float32)
+    x = _streams_in(params["embed"][tokens].astype(jnp.float32), cfg)
     x, _, _, _ = _over_layers(params, cfg, x, None, None, attention,
                               recurrent, live=jnp.ones((B, L), bool))
     return _logits(x, params, cfg)
@@ -1126,9 +1271,19 @@ def build_decode_step(cfg, page_size):
             return _retention_pool_step(u, lp, cfg, kp, vp, li, wp, fresh,
                                         positions, active)
 
+        def latent(u, lp, kp, vp, li):
+            q_nope, q_rope, row = _latent.latent_inputs(u, lp, cfg,
+                                                        positions)
+            kp, vp = _latent.write_rows(kp, vp, li, wp, ws, row)
+            att = _latent.absorbed_step(q_nope, q_rope, kp, vp, li, lengths,
+                                        page_tables, lp, cfg)
+            return _mm(att, lp["wo"]), kp, vp
+
+        if LATENT in cfg.layer_kinds:
+            attention = latent
         recurrent = (retention if RETENTION in cfg.layer_kinds
                      else delta if DELTA in cfg.layer_kinds else state_space)
-        x = params["embed"][tokens].astype(jnp.float32)
+        x = _streams_in(params["embed"][tokens].astype(jnp.float32), cfg)
         x, kp, vp, counts = _over_layers(
             params, cfg, x, kp, vp, attention, recurrent, live=active)
         if counts is not None:      # the decode steps' counts: the V pool's
@@ -1177,6 +1332,16 @@ def build_prefill_chunk(cfg, page_size, chunk):
             att = _attend(q[None], kc, vc, seen[None], cfg)
             return _attention_out(att[0], u, lp, cfg), kp, vp
 
+        def latent(u, lp, kp, vp, li):
+            q_nope, q_rope, rows = _latent.latent_inputs(u, lp, cfg, idx)
+            kp, vp = _latent.write_rows(kp, vp, li, wp, ws, rows)
+            att = _latent.chunk_attention(q_nope, q_rope, kp, vp, li,
+                                          page_row, idx, pos0 + n_valid, lp,
+                                          cfg)
+            return _mm(att, lp["wo"]), kp, vp
+
+        if LATENT in cfg.layer_kinds:
+            attention = latent
         chunk_of = _chunk_fn(cfg, pos0)
 
         def recurrent(u, lp, kp, vp, li):
@@ -1188,7 +1353,7 @@ def build_prefill_chunk(cfg, page_size, chunk):
                                   cfg)
             return out, kp, vp
 
-        x = params["embed"][tokens].astype(jnp.float32)
+        x = _streams_in(params["embed"][tokens].astype(jnp.float32), cfg)
         x, kp, vp, counts = _over_layers(params, cfg, x, kp, vp, attention,
                                          recurrent, live=valid)
         if counts is not None:      # the prefill chunks' counts: the K pool's
@@ -1205,10 +1370,20 @@ def build_prefill_chunk(cfg, page_size, chunk):
 # ---------------------------------------------------------------------------
 # the gluon block
 # ---------------------------------------------------------------------------
-def _run_shapes(cfg, kind):
+def _hc_shapes(cfg, name, logits):
+    """The leaves of one mix of the residual streams: ``phi`` (logits, n
+    units), ``alpha`` and ``b``."""
+    three = 3 if logits > cfg.streams else 1
+    return {name + "_phi": (logits, cfg.streams * cfg.units),
+            name + "_alpha": (three,), name + "_bias": (logits,)}
+
+
+def _run_shapes(cfg, kind, dense=None):
     """{leaf: shape of one layer} of a layer of ``kind``, gluon's (out, in)
     convention for the matrices (the routed experts' (in, out), as the
-    grouped product takes them: :data:`.routed.EXPERT_LEAVES`)."""
+    grouped product takes them: :data:`.routed.EXPERT_LEAVES`).  ``dense``:
+    the run's feed-forward part is the dense mlp (by default where the
+    model routes nothing)."""
     C, F, di = cfg.units, cfg.hidden_size, cfg.d_inner
     N, R = cfg.d_state, cfg.dt_rank
     kvu = cfg.num_kv_heads * cfg.head_dim
@@ -1229,13 +1404,27 @@ def _run_shapes(cfg, kind):
                  "wv": (kvu, C), "wo": (C, cfg.num_heads * d),
                  "w_decay": (KVH, C), "b_decay": (KVH,),
                  "q_norm": (d,), "k_norm": (d,)}
+    elif kind == LATENT:
+        sp, H = cfg.latent, cfg.num_heads
+        mixer = {"wq_a": (sp.q_rank, C), "q_norm": (sp.q_rank,),
+                 "wq_b": (H * (sp.nope + sp.rope), sp.q_rank),
+                 "wkv_a": (sp.kv_rank + sp.rope, C),
+                 "kv_norm": (sp.kv_rank,),
+                 "wkv_b": (H * (sp.nope + sp.v_dim), sp.kv_rank),
+                 "wo": (C, H * sp.v_dim)}
     else:
         mixer = {"w_in": (2 * di, C), "conv_w": (di, cfg.d_conv),
                  "conv_b": (di,), "w_x": (R + 2 * N, di), "norm_dt": (R,),
                  "norm_b": (N,), "norm_c": (N,), "w_dt": (di, R),
                  "b_dt": (di,), "a_log": (di, N), "d": (di,),
                  "w_out": (C, di)}
-    if not cfg.n_experts:
+    if cfg.streams > 1:
+        n = cfg.streams
+        for name in ("hc_attn", "hc_ffn"):
+            mixer.update(_hc_shapes(cfg, name, n * (n + 2)))
+    if dense is None:
+        dense = not cfg.n_experts
+    if dense:
         return dict(mixer, norm1=(C,), norm2=(C,), w_gate=(F, C),
                     w_up=(F, C), w_down=(C, F))
     E, Fs, held = cfg.expert_hidden, cfg.shared_hidden, cfg.experts_held[1]
@@ -1249,10 +1438,11 @@ class _Run(HybridBlock):
     """Parameter container of one run of layers of one kind, every leaf
     stacked on a leading axis of the run's length."""
 
-    def __init__(self, cfg, kind, n, dtype):
+    def __init__(self, cfg, kind, n, dtype, dense=None):
         super().__init__()
-        self.leaves = sorted(_run_shapes(cfg, kind))
-        for name, shape in _run_shapes(cfg, kind).items():
+        shapes = _run_shapes(cfg, kind, dense)
+        self.leaves = sorted(shapes)
+        for name, shape in shapes.items():
             setattr(self, name, Parameter(name, shape=(n,) + shape,
                                           dtype=dtype, grad_req="null"))
 
@@ -1275,7 +1465,12 @@ class HybridLM(HybridBlock):
     ``n_experts`` > 0 makes every
     layer's feed-forward part the routed layer of :mod:`.routed`, of which
     this model holds the ``experts_held`` experts of share ``expert_share``
-    (``[experts_held * expert_share, experts_held * (expert_share + 1))``).
+    (``[experts_held * expert_share, experts_held * (expert_share + 1))``),
+    but for the first ``dense_layers`` layers, which keep the mlp.
+    ``latent`` (a :class:`.latent.Latent`) makes every layer the latent
+    attention mixer on ``num_heads`` heads (no other mixer); ``streams``
+    > 1 gives the model that many residual streams, mixed as
+    ``sinkhorn_iters``, ``hc_eps`` and ``hc_clamp`` say (mHC).
     ``kv_dtype`` is the dtype the model's keys and values are cached in
     where that is not the weights' (``config.kv_dtype``)."""
 
@@ -1289,7 +1484,9 @@ class HybridLM(HybridBlock):
                  delta_head_dim=0, delta_rank=0, n_experts=0,
                  experts_held=None, expert_share=0, experts_per_token=0,
                  expert_hidden=0, shared_hidden=0, norm_topk=True,
-                 routed_scale=1.0, kv_dtype=None, rope_theta=0.0):
+                 routed_scale=1.0, kv_dtype=None, rope_theta=0.0,
+                 latent=None, dense_layers=0, streams=1, sinkhorn_iters=0,
+                 hc_eps=0.0, hc_clamp=0.0):
         super().__init__()
         assert num_heads % num_kv_heads == 0
         assert head_dim is not None or units % num_heads == 0
@@ -1301,10 +1498,13 @@ class HybridLM(HybridBlock):
             attention_layers = []
         else:                           # only the retention mixer rotates
             assert not rope_theta
+        if latent is not None:          # every layer latent attention
+            attention_layers = list(range(int(num_layers)))
         if attention_layers is None:
             attention_layers = [i for i in range(int(num_layers))
                                 if i % attn_layer_period == attn_layer_offset]
-        kinds = tuple(ATTENTION if i in set(attention_layers) else recurrent
+        mixer = ATTENTION if latent is None else LATENT
+        kinds = tuple(mixer if i in set(attention_layers) else recurrent
                       for i in range(int(num_layers)))
         held = int(n_experts if experts_held is None else experts_held)
         if n_experts:
@@ -1328,7 +1528,11 @@ class HybridLM(HybridBlock):
             experts_per_token=int(experts_per_token),
             expert_hidden=int(expert_hidden),
             shared_hidden=int(shared_hidden), norm_topk=bool(norm_topk),
-            routed_scale=float(routed_scale), rope_theta=float(rope_theta))
+            routed_scale=float(routed_scale), rope_theta=float(rope_theta),
+            latent=latent if latent is not None else (),
+            dense_layers=int(dense_layers), streams=int(streams),
+            sinkhorn_iters=int(sinkhorn_iters), hc_eps=float(hc_eps),
+            hc_clamp=float(hc_clamp))
         self.eos_id = eos_id
         self.dtype = jnp.dtype(dtype)
         self.embed = Parameter("embed", shape=(vocab_size, units),
@@ -1338,7 +1542,15 @@ class HybridLM(HybridBlock):
                                   dtype=self.dtype, grad_req="null")
         self.norm_f = Parameter("norm_f", shape=(units,), dtype=self.dtype,
                                 grad_req="null")
-        self.runs = [_Run(self._cfg, kind, hi - lo, self.dtype)
+        #: the last mix of the residual streams, into one
+        self.hc_leaves = sorted(_hc_shapes(self._cfg, "hc_head", streams)
+                                if streams > 1 else ())
+        for name, shape in (_hc_shapes(self._cfg, "hc_head", streams).items()
+                            if streams > 1 else ()):
+            setattr(self, name, Parameter(name, shape=shape,
+                                          dtype=self.dtype, grad_req="null"))
+        self.runs = [_Run(self._cfg, kind, hi - lo, self.dtype,
+                          dense=not n_experts or lo < self._cfg.dense_layers)
                      for kind, lo, hi in layer_runs(self._cfg)]
         for i, run in enumerate(self.runs):
             setattr(self, "run%d" % i, run)     # registers the children
@@ -1361,6 +1573,8 @@ class HybridLM(HybridBlock):
                           for k in run.leaves} for run in self.runs]}
             if not self._cfg.tied_head:
                 self._jax_params["head"] = self.head.data()._data
+            for name in self.hc_leaves:
+                self._jax_params[name] = getattr(self, name).data()._data
         return self._jax_params
 
     def forward(self, tokens):
@@ -1394,6 +1608,15 @@ def _initial(name, p, cfg, normal):
         value = jnp.linspace(3.5, 6.5, cfg.num_kv_heads, dtype=f32)
     elif name == "w_decay":     # normal(0, 0.01): W u spreads by some 0.7
         return normal(p.shape) * jnp.asarray(0.5, p.dtype)
+    elif name.endswith("_alpha"):   # the streams' mixes: alpha 0.1
+        value = jnp.asarray(0.1, f32)
+    elif name.endswith("_bias") and name.startswith("hc_"):
+        # H_pre, H_post at sigmoid(0); H_res's logits 2 on the diagonal
+        n = cfg.streams
+        value = jnp.zeros(p.shape[-1], f32)
+        if p.shape[-1] > n:
+            value = value.at[2 * n:].set(2.0 * jnp.eye(n, dtype=f32)
+                                         .reshape(-1))
     elif len(p.shape) >= 3:                     # a run of matrices
         return normal(p.shape)
     else:
@@ -1439,4 +1662,11 @@ def hybrid_lm(seed=0, **kw):
     if not cfg.tied_head:
         net.head.set_data(_normal(jax.random.fold_in(root, 1),
                                   tuple(net.head.shape), dtype))
+    for name in net.hc_leaves:
+        p = getattr(net, name)
+        if name.endswith("_phi"):
+            p.set_data(_normal(jax.random.fold_in(root, 2),
+                               tuple(p.shape), dtype))
+        else:
+            p.set_data(_initial(name, p, cfg, normal))
     return net

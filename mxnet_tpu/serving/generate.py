@@ -337,14 +337,20 @@ class DecodeEngine:
             else _config.get("MXNET_GEN_SESSION_TTL"))
 
         cfg = self.cfg
-        kv_layers = (cfg.layer_kinds.count("attention") if self.hybrid
-                     else cfg.num_layers)
-        elems = 2 * kv_layers * cfg.num_kv_heads * cfg.head_dim
+        # a token's rows over both pools: keys and values, or latent rows
+        elems = (_hybrid.token_row_elems(cfg) if self.hybrid
+                 else 2 * cfg.num_layers * cfg.num_kv_heads * cfg.head_dim)
         #: bytes of one page's state entries (0 without recurrent layers)
         self.state_entry_bytes = (_hybrid.state_entry_bytes(cfg)
                                   if self.hybrid else 0)
         #: bytes of state entries the staged launches read and write
         self._state_bytes = {"decode": 0, "prefill": 0}
+        #: bytes of one token's latent rows over the latent layers (0
+        #: without them), and those the staged launches read
+        self.latent_row_bytes = (
+            elems * jnp.dtype(self.kv_dtype).itemsize
+            if self.hybrid and _hybrid.LATENT in cfg.layer_kinds else 0)
+        self._latent_bytes = {"decode": 0, "prefill": 0}
         self.alloc = PageAllocator(
             total, self.page_size, kv_dtype=self.kv_dtype,
             page_bytes=elems * self.page_size
@@ -499,8 +505,9 @@ class DecodeEngine:
         #: a model that routes: the token-expert pairs a token makes over
         #: its routed layers, and what the programs counted so far (the
         #: pools' ``counts``, folded in whenever ``stats()`` is asked)
-        self._pairs_per_token = (cfg.experts_per_token * cfg.num_layers
-                                 if getattr(cfg, "n_experts", 0) else 0)
+        self._pairs_per_token = (
+            cfg.experts_per_token * (cfg.num_layers - cfg.dense_layers)
+            if getattr(cfg, "n_experts", 0) else 0)
         self._expert_counts = {
             phase: {"seen": onp.zeros(len(_routed.COUNTS), onp.uint32),
                     "total": [0] * len(_routed.COUNTS)}
@@ -1365,7 +1372,7 @@ class DecodeEngine:
             self.metrics.count(self.name, "prefix_hits_total")
             self.metrics.count(self.name, "prefix_tokens_saved_total",
                                base)
-            if self.hybrid:
+            if self.state_entry_bytes:
                 # whole pages only: each brings its state entry along
                 self.metrics.count(self.name,
                                    "state_prefix_pages_shared_total",
@@ -1578,7 +1585,7 @@ class DecodeEngine:
         # from a model with recurrent layers
         touched = (pages_for(slot.pos + n, self.page_size)
                    - slot.pos // self.page_size)
-        if self.hybrid:
+        if self.state_entry_bytes:
             self.metrics.count(self.name, "state_entries_written_total",
                                touched)
             if slot.pos == 0:
@@ -1586,9 +1593,12 @@ class DecodeEngine:
         # the entries it writes, and the one before pos0 that it reads
         state_bytes = self._count_state("prefill",
                                         touched + (slot.pos > 0))
+        # the latent rows its attention reads: the sequence's up to the
+        # chunk's end
+        self._count_latent("prefill", slot.pos + n)
         with span("engine.prefill_launch", rid=rid, slot=slot.idx,
                   tokens=n, pos=slot.pos,
-                  state_pages=touched if self.hybrid else 0,
+                  state_pages=touched if self.state_entry_bytes else 0,
                   expert_pairs=n * self._pairs_per_token,
                   state_bytes=state_bytes):
             chunk = slot.prompt[slot.done:slot.done + n]
@@ -1619,7 +1629,7 @@ class DecodeEngine:
             # only: a page's state entry is the state after its last
             # token, and the owner keeps writing the trailing page's.
             whole = (len(slot.history) // self.page_size * self.page_size
-                     if self.hybrid else len(slot.history))
+                     if self.state_entry_bytes else len(slot.history))
             self.prefix_cache.insert(list(slot.history[:whole]),
                                      self.alloc.pages(slot.owner))
         tok = self._device_wait("first_token", int, next_tok,
@@ -1703,6 +1713,16 @@ class DecodeEngine:
             self.metrics.count(self.name, "state_bytes_%s_total" % phase, n)
         return n
 
+    def _count_latent(self, phase, tokens):
+        """Bytes of the latent rows of ``tokens`` positions that a staged
+        ``phase`` launch's attention reads, over the latent layers;
+        counted as ``latent_bytes_<phase>_total`` where the model has
+        latent attention layers."""
+        n = int(tokens) * self.latent_row_bytes
+        if n:
+            self._latent_bytes[phase] += n
+            self.metrics.count(self.name, "latent_bytes_%s_total" % phase, n)
+
     def _count_decode_pages(self, positions, active):
         """What the staged launch's attention has to read: the pages
         under ``position + 1`` of each active lane, beside the whole
@@ -1710,6 +1730,7 @@ class DecodeEngine:
         live = -(-(positions[active] + 1) // self.page_size)
         self.metrics.count(self.name, "decode_pages_live_total",
                            int(live.sum()))
+        self._count_latent("decode", (positions[active] + 1).sum())
         self.metrics.count(self.name, "decode_pages_table_total",
                            self.slots * self.pages_per_seq)
 
@@ -2666,7 +2687,12 @@ class DecodeEngine:
             out["speculative"] = self._spec.stats()
         if self._pairs_per_token:
             out["experts"] = self._expert_stats()
-        if self.hybrid:
+        if self.latent_row_bytes:
+            out["latent"] = {
+                "row_bytes": self.latent_row_bytes,
+                "bytes_decode_total": self._latent_bytes["decode"],
+                "bytes_prefill_total": self._latent_bytes["prefill"]}
+        if self.state_entry_bytes:
             # the recurrent state paged beside the KV rows: one entry a
             # page (scratch page included) and recurrent layer
             out["state"] = {

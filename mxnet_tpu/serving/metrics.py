@@ -108,6 +108,9 @@ class ModelMetrics:
                 # bytes of state entries the staged decode and prefill
                 # launches read and write (PR 37), known to the host
                 "state_bytes_decode_total", "state_bytes_prefill_total",
+                # bytes of latent rows the staged decode and prefill
+                # launches' attention reads (latent attention layers)
+                "latent_bytes_decode_total", "latent_bytes_prefill_total",
                 # a model that routes (PR 35), from the programs' own
                 # counts, folded in when the engine's stats are asked:
                 # token-expert pairs on experts held here and on other

@@ -41,11 +41,13 @@ CONFIG_OF = {w["name"]: w["config"] for w in BENCH["workloads"]}
 def engine_kind(config):
     """Which of the tiny engines below stands for a configuration's: the
     classic block's, one with state-space layers, one that routes over
-    delta-rule layers, or one of retention layers.  A metric reads the
-    facts of every kind of engine among the cells that report it."""
+    delta-rule layers, one of retention layers, or one of latent attention
+    layers that routes.  A metric reads the facts of every kind of engine
+    among the cells that report it."""
     builder = harness.load("configs", config + ".json").get("builder", "")
     return ("routed" if "routed_delta_lm" in builder
             else "retention" if "retention_lm" in builder
+            else "latent" if "latent_routed_lm" in builder
             else "hybrid" if "hybrid_lm" in builder else "classic")
 
 
@@ -88,7 +90,16 @@ def facts():
             "retention": served_facts(decoder.retention_lm(
                 seed=0, vocab_size=128, num_layers=2, units=32,
                 hidden_size=48, num_heads=4, num_kv_heads=2, head_dim=8,
-                rope_theta=1e4, max_length=128), page_size=64)}
+                rope_theta=1e4, max_length=128), page_size=64),
+            "latent": served_facts(decoder.latent_routed_lm(
+                seed=0, vocab_size=128, num_layers=2, units=32,
+                hidden_size=48, num_heads=4, num_kv_heads=4, q_lora_rank=16,
+                kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
+                v_head_dim=8, rope_theta=1e4, rope_scaling=None,
+                dense_layers=1, n_experts=8, experts_per_token=2,
+                expert_hidden=16, hc_mult=4, hc_sinkhorn_iters=2,
+                hc_eps=1e-6, hc_clamp_min=-30, hc_clamp_max=30,
+                max_length=128), page_size=16)}
 
 
 def test_every_per_layer_metric_has_its_file():

@@ -646,3 +646,85 @@ def test_retention_decode_step_compiled_for_the_chip_runs_the_state_kernel(
         "copy-done"}
     assert kernel.memory_analysis().temp_size_in_bytes < (
         xla.memory_analysis().temp_size_in_bytes)
+
+
+# ---------------------------------------------------------------------------
+# the latent attention block's two programs compiled for the chip (the
+# xing4_0 block: models/latent.py, ops/pallas/latent_attention.py)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_latent_programs_compiled_for_the_chip_move_no_pool(one_chip,
+                                                            monkeypatch,
+                                                            program):
+    """Two layers (one dense, one routed over 8 experts) at the published
+    latent widths and ``xing4-29b-l5-serve``'s pool (769 pages of 256
+    positions, half a page a pool: ``(2, 769, 128, 640)`` float32), six
+    slots, chunks of 512, four residual streams, with the decode kernel
+    selected as on the chip, through the v5e's compiler: the decode step
+    calls the kernel once a layer with the pools whole; in both programs
+    nothing with a pool's shape is made but in place (the rows' scatters,
+    parameters, tuple elements), and neither keeps a temporary as large as
+    one layer of a pool."""
+    from mxnet_tpu.models import hybrid, latent
+    from mxnet_tpu.ops.pallas import latent_attention
+    monkeypatch.delenv("MXNET_PAGED_ATTENTION", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    decoder._fn_cache.clear()
+    layers, S, B, T, pps = 2, 256, 6, 512, 128
+    spec = latent.Latent(q_rank=768, kv_rank=512, nope=128, rope=64,
+                         v_dim=128, theta=1e4, factor=64, original=4096,
+                         beta_fast=32, beta_slow=1, mscale=1,
+                         mscale_all_dim=1)
+    net = hybrid.HybridLM(vocab_size=1024, num_layers=layers, units=3584,
+                          hidden_size=9216, num_heads=32, num_kv_heads=32,
+                          head_dim=128, latent=spec, dense_layers=1,
+                          n_experts=8, experts_per_token=4,
+                          expert_hidden=1024, shared_hidden=1024,
+                          routed_scale=2.0, streams=4, sinkhorn_iters=20,
+                          hc_eps=1e-6, hc_clamp=30.0, tied_head=False,
+                          max_length=S * pps, kv_dtype="float32")
+    cfg = net.config
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+    params = {"embed": sds(net.embed.shape, net.dtype),
+              "norm_f": sds(net.norm_f.shape, net.dtype),
+              "head": sds(net.head.shape, net.dtype),
+              "runs": [{k: sds(getattr(r, k).shape, net.dtype)
+                        for k in r.leaves} for r in net.runs]}
+    params.update({k: sds(getattr(net, k).shape, net.dtype)
+                   for k in net.hc_leaves})
+    total = B * pps + 1
+    pool = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        lambda: decoder.fresh_pool(cfg, total, S)))
+    assert pool.rows.shape == (layers, total, S // 2, 640)
+
+    def i32(*shape):
+        return sds(shape, jnp.int32)
+    if program == "decode":
+        fn, rest = decoder.make_decode_step(cfg, S), (
+            i32(B), i32(B), i32(B, pps), sds((B,), jnp.bool_))
+    else:
+        fn, rest = decoder.make_prefill_chunk(cfg, S, T), (
+            i32(T), i32(), i32(), i32(pps))
+    compiled = fn.lower(params, pool, pool, *rest).compile()
+    decoder._fn_cache.clear()
+    hlo = compiled.as_text()
+    if program == "decode":
+        assert latent_attention.last_path == "pallas"
+        assert len(re.findall(r"%paged_latent_rows[.\d]* = \S+ custom-call\(",
+                              hlo)) == layers
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", hlo))
+    made = [m for comp in re.split(r"\n(?=\S)", hlo)
+            if comp.split(" ", 2)[int(comp.startswith("ENTRY"))].lstrip("%")
+            not in fused
+            for m in re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = "
+                                r"f32\[([\d,]+)\]\S* ([\w\-]+)\(", comp,
+                                re.M)]
+    whole = "%d,%d,128,640" % (layers, total)
+    in_place = {"parameter", "get-tuple-element", "bitcast", "fusion",
+                "scatter", "custom-call", "tuple"}
+    seen = [(name, op) for name, dims, op in made if dims == whole]
+    assert seen and {op for _, op in seen} <= in_place, seen
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        total * 128 * 640 * 4)
